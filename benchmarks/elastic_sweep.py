@@ -206,13 +206,14 @@ def bench(ticks=60, peak=3, max_new=8, slots=8, slo_max_load=16,
     from repro.configs import make_run_config
     from repro.core.autoscaler import AutoscaleConfig
     from repro.models.model import build_model
+    from repro.core.pool import token_devices
     from repro.serve import ServeFleet
 
     run = make_run_config("qwen3-0.6b", "decode_32k", smoke=True)
     model = build_model(run)
     params = model.init(jax.random.key(0))
     vocab = run.model.vocab_size
-    kw = dict(num_devices=8, slots=slots, max_len=256, paged=True,
+    kw = dict(devices=token_devices(8), slots=slots, max_len=256, paged=True,
               page_size=16, slo_max_load=slo_max_load)
     static = ServeFleet(run, params, num_engines=1,
                         workdir=tempfile.mkdtemp(prefix="svff_el_s_"),

@@ -62,8 +62,9 @@ def make_request(rng, vocab, rid, max_new):
 
 
 def make_fleet(run, params, *, slots, slo_max_load):
+    from repro.core.pool import token_devices
     from repro.serve import ServeFleet
-    return ServeFleet(run, params, num_engines=2, num_devices=4,
+    return ServeFleet(run, params, num_engines=2, devices=token_devices(4),
                       slots=slots, max_len=256, paged=True, page_size=16,
                       slo_max_load=slo_max_load,
                       workdir=tempfile.mkdtemp(prefix="svff_mig_"))

@@ -119,8 +119,9 @@ def run_fleet(run, params, reqs, *, slots, max_len, page_size,
     The no-pause variant is the steady-state baseline for the p95
     inter-token comparison (same fleet loop, same overheads)."""
     import tempfile
+    from repro.core.pool import token_devices
     from repro.serve import ServeFleet
-    fleet = ServeFleet(run, params, num_engines=1, num_devices=2,
+    fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(2),
                        slots=slots, max_len=max_len, paged=True,
                        page_size=page_size,
                        workdir=tempfile.mkdtemp(prefix="svff_bench_"))

@@ -249,7 +249,12 @@ class RunConfig:
     optimizer: OptimizerConfig = OptimizerConfig()
     sharding: ShardingConfig = ShardingConfig()
     precision: PrecisionConfig = PrecisionConfig()
-    kernel_backend: str = "reference"   # reference | pallas | auto
+    # attention / scan / sampling implementation: "pallas" (the kernels),
+    # "reference" (plain jnp), or "auto" — see ``kernels``
+    kernel_backend: str = "auto"
+    # run the Pallas kernels in the Pallas interpreter (CPU tests). Only
+    # ever set explicitly: nothing derives it from the backend
+    interpret: bool = False
     microbatch: int = 1                 # grad-accum microbatches
     seed: int = 0
     # VF placement policy the SVFFManager's scheduler uses for this tenant
@@ -258,6 +263,21 @@ class RunConfig:
 
     def replace(self, **kw) -> "RunConfig":
         return dataclasses.replace(self, **kw)
+
+    @property
+    def kernels(self) -> str:
+        """The implementation this run uses, "pallas" or "reference".
+        ``auto`` takes the kernels on a TPU backend (where they compile)
+        or when interpret mode is asked for, and the reference elsewhere;
+        on a TPU it never falls back."""
+        if self.kernel_backend not in ("auto", "pallas", "reference"):
+            raise ValueError(f"unknown kernel_backend "
+                             f"{self.kernel_backend!r}")
+        if self.kernel_backend != "auto":
+            return self.kernel_backend
+        import jax
+        return ("pallas" if self.interpret or jax.default_backend() == "tpu"
+                else "reference")
 
 
 # ---------------------------------------------------------------------------

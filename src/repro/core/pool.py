@@ -36,6 +36,13 @@ def _default_mesh_shape(n: int) -> tuple:
     return best
 
 
+def token_devices(n: int) -> tuple:
+    """``n`` placeholder device tokens. A pool over them carves and tracks
+    VFs but places nothing: tests and the CPU benchmarks use them where a
+    real pool would take ``jax.devices()``."""
+    return tuple(f"d{i}" for i in range(n))
+
+
 class DevicePool:
     def __init__(self, devices: Optional[Sequence] = None,
                  pf_id: str = "0000:03:00.0", max_vfs: int = 252):

@@ -7,10 +7,15 @@ and each sequence names its pages through a prefetched block table
 (B, NP) — the k/v BlockSpec index_map reads ``table[b, pi]`` so the DMA
 engine fetches exactly the pages a sequence owns, in logical order. The
 per-sequence valid length is a second prefetched scalar vector: tiles past
-``pos[b]`` are skipped with ``pl.when``, so decode cost is proportional to
-the tokens a sequence has actually written — not to the pool size and not
-to a dense per-slot ring allocation. ``pos[b] < 0`` (an inactive batch
+``pos[b]`` are skipped with ``pl.when``, and their index_map repeats the
+last valid page so the pipeline issues no DMA for them either — decode
+cost is proportional to the tokens a sequence has actually written, not
+to the pool size or the table width. ``pos[b] < 0`` (an inactive batch
 slot) skips every tile and yields an exactly-zero output row.
+
+A block is one whole page, all K kv-heads wide: the pool is viewed as
+(P, page, K*hd), and the kernel body (``flash_decode.decode_kernel``)
+loops over the kv-heads.
 """
 from __future__ import annotations
 
@@ -22,91 +27,47 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.flash_decode import decode_kernel, decode_scratch
 
 
-def _kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-            acc_scr, *, scale: float, page: int):
-    b = pl.program_id(0)
-    pi = pl.program_id(2)
-    npg = pl.num_programs(2)
-    pos = pos_ref[b]
-    start = pi * page
+def _paged_call(q, k_pages, v_pages, scales, tables, pos, interpret):
+    B, _, H, hd = q.shape
+    P, page, K, _ = k_pages.shape
+    NP = tables.shape[1]
+    quant = scales is not None
+    kern = functools.partial(decode_kernel, scale=1.0 / math.sqrt(hd),
+                             kv_heads=K, block=page, paged=True,
+                             quant=quant)
 
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def page_of(b, pi, tbl_ref, pos_ref):
+        last = jnp.maximum(pos_ref[b], 0) // page
+        return tbl_ref[b, jnp.minimum(pi, last)]
 
-    @pl.when(start <= pos)
-    def compute():
-        q = q_ref[0, 0, 0, :].astype(jnp.float32) * scale    # (hd,)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (page, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # (page, hd)
-        s = jax.lax.dot_general(q[None], k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        kpos = start + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        s = jnp.where(kpos <= pos, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(kpos <= pos, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, -1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
-    @pl.when(pi == npg - 1)
-    def _finish():
-        o_ref[0, 0, 0, :] = (
-            acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        )[0].astype(o_ref.dtype)
-
-
-def _kernel_quant(tbl_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  o_ref, m_scr, l_scr, acc_scr, *, scale: float, page: int):
-    b = pl.program_id(0)
-    pi = pl.program_id(2)
-    npg = pl.num_programs(2)
-    pos = pos_ref[b]
-    start = pi * page
-
-    @pl.when(pi == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(start <= pos)
-    def compute():
-        q = q_ref[0, 0, 0, :].astype(jnp.float32) * scale    # (hd,)
-        # int8 page tile + its per-row scales, dequantized in-register:
-        # the HBM traffic this kernel pays is the int8 bytes, not fp32
-        ks = ks_ref[0, :, 0].astype(jnp.float32)             # (page,)
-        vs = vs_ref[0, :, 0].astype(jnp.float32)             # (page,)
-        k = k_ref[0, :, 0, :].astype(jnp.float32) * ks[:, None]
-        v = v_ref[0, :, 0, :].astype(jnp.float32) * vs[:, None]
-        s = jax.lax.dot_general(q[None], k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        kpos = start + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        s = jnp.where(kpos <= pos, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(kpos <= pos, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, -1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
-    @pl.when(pi == npg - 1)
-    def _finish():
-        o_ref[0, 0, 0, :] = (
-            acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        )[0].astype(o_ref.dtype)
+    kv_spec = pl.BlockSpec((1, page, K * hd),
+                           lambda b, pi, t, p: (page_of(b, pi, t, p), 0, 0))
+    sc_spec = pl.BlockSpec((1, page, K),
+                           lambda b, pi, t, p: (page_of(b, pi, t, p), 0, 0))
+    in_specs = [pl.BlockSpec((1, H, hd), lambda b, pi, t, p: (b, 0, 0)),
+                kv_spec, kv_spec] + ([sc_spec, sc_spec] if quant else [])
+    args = [k_pages.reshape(P, page, K * hd),
+            v_pages.reshape(P, page, K * hd)] + (list(scales) if quant
+                                                 else [])
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, NP),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, H, hd),
+                                   lambda b, pi, t, p: (b, 0, 0)),
+            scratch_shapes=decode_scratch(H, hd),
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        interpret=interpret,
+    )(jnp.asarray(tables, jnp.int32),
+      jnp.asarray(pos, jnp.int32).reshape((B,)),
+      q.reshape(B, H, hd), *args)
+    return out.reshape(B, 1, H, hd)
 
 
 def paged_decode_quant(q, k_pages, v_pages, k_scale, v_scale, tables, pos, *,
@@ -116,84 +77,12 @@ def paged_decode_quant(q, k_pages, v_pages, k_scale, v_scale, tables, pos, *,
     symmetric scales; everything else as paged_decode. Pages are fetched
     at int8 width and dequantized in-tile, halving the kernel's HBM
     bytes per token."""
-    B, _, H, hd = q.shape
-    page, K = k_pages.shape[1], k_pages.shape[2]
-    NP = tables.shape[1]
-    G = H // K
-    grid = (B, H, NP)
-    kern = functools.partial(_kernel_quant, scale=1.0 / math.sqrt(hd),
-                             page=page)
-    tbl = jnp.asarray(tables, jnp.int32)
-    pos_arr = jnp.asarray(pos, jnp.int32).reshape((B,))
-    kv_spec = pl.BlockSpec((1, page, 1, hd),
-                           lambda b, h, pi, tbl_ref, pos_ref:
-                           (tbl_ref[b, pi], 0, h // G, 0))
-    sc_spec = pl.BlockSpec((1, page, 1),
-                           lambda b, h, pi, tbl_ref, pos_ref:
-                           (tbl_ref[b, pi], 0, h // G))
-    return pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, 1, hd),
-                             lambda b, h, pi, tbl_ref, pos_ref: (b, 0, h, 0)),
-                kv_spec,
-                kv_spec,
-                sc_spec,
-                sc_spec,
-            ],
-            out_specs=pl.BlockSpec((1, 1, 1, hd),
-                                   lambda b, h, pi, tbl_ref, pos_ref:
-                                   (b, 0, h, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((1, 1), jnp.float32),
-                pltpu.VMEM((1, 1), jnp.float32),
-                pltpu.VMEM((1, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, 1, H, hd), q.dtype),
-        interpret=interpret,
-    )(tbl, pos_arr, q, k_pages, v_pages, k_scale, v_scale)
+    return _paged_call(q, k_pages, v_pages, (k_scale, v_scale), tables, pos,
+                       interpret)
 
 
 def paged_decode(q, k_pages, v_pages, tables, pos, *,
                  interpret: bool = False):
     """q: (B,1,H,hd); k_pages,v_pages: (P,page,K,hd); tables: (B,NP) int32;
     pos: (B,) int32 — attend to logical indices <= pos[b] (< 0: none)."""
-    B, _, H, hd = q.shape
-    page, K = k_pages.shape[1], k_pages.shape[2]
-    NP = tables.shape[1]
-    G = H // K
-    grid = (B, H, NP)
-    kern = functools.partial(_kernel, scale=1.0 / math.sqrt(hd), page=page)
-    tbl = jnp.asarray(tables, jnp.int32)
-    pos_arr = jnp.asarray(pos, jnp.int32).reshape((B,))
-    return pl.pallas_call(
-        kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, 1, hd),
-                             lambda b, h, pi, tbl_ref, pos_ref: (b, 0, h, 0)),
-                pl.BlockSpec((1, page, 1, hd),
-                             lambda b, h, pi, tbl_ref, pos_ref:
-                             (tbl_ref[b, pi], 0, h // G, 0)),
-                pl.BlockSpec((1, page, 1, hd),
-                             lambda b, h, pi, tbl_ref, pos_ref:
-                             (tbl_ref[b, pi], 0, h // G, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, 1, hd),
-                                   lambda b, h, pi, tbl_ref, pos_ref:
-                                   (b, 0, h, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((1, 1), jnp.float32),
-                pltpu.VMEM((1, 1), jnp.float32),
-                pltpu.VMEM((1, hd), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, 1, H, hd), q.dtype),
-        interpret=interpret,
-    )(tbl, pos_arr, q, k_pages, v_pages)
+    return _paged_call(q, k_pages, v_pages, None, tables, pos, interpret)

@@ -102,8 +102,13 @@ def _digest_kernel(v_ref, out_ref, *, lanes: int):
            jnp.uint32(base))
     w1 = idx * jnp.uint32(2654435761) + jnp.uint32(0x9E3779B1)
     w2 = idx * jnp.uint32(0x85EBCA6B) + jnp.uint32(0xC2B2AE35)
-    out_ref[0, 0] = jnp.sum(v * w1)
-    out_ref[0, 1] = jnp.sum(v * w2)
+    # lane-dense partials (one per lane); the wrapper finishes the sum.
+    # Summed as int32: the TPU reduces no unsigned type, and wrapping
+    # addition gives the same bits either way
+    for r, w in ((0, w1), (1, w2)):
+        out_ref[0, r:r + 1, :] = jnp.sum(
+            jax.lax.bitcast_convert_type(v * w, jnp.int32), axis=0,
+            keepdims=True)
 
 
 def _bytes_view(x):
@@ -132,11 +137,12 @@ def qdma_digest(x, *, rows_per_tile: int = 512, lanes: int = 128,
         kern,
         grid=grid,
         in_specs=[pl.BlockSpec((rows_per_tile, lanes), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 2), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], 2), jnp.uint32),
+        out_specs=pl.BlockSpec((1, 2, lanes), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid[0], 2, lanes), jnp.int32),
         interpret=interpret,
     )(v)
-    return jnp.sum(parts, axis=0, dtype=jnp.uint32)
+    return jnp.sum(jax.lax.bitcast_convert_type(parts, jnp.uint32),
+                   axis=(0, 2), dtype=jnp.uint32)
 
 
 def qdma_unpack(q, scale, *, dtype="float32", rows_per_tile: int = 256,

@@ -15,8 +15,10 @@ import jax.numpy as jnp
 # ---------------------------------------------------------------------------
 # flash_attention
 # ---------------------------------------------------------------------------
-def flash_attention_ref(q, k, v, causal: bool = True):
-    """q: (B,S,H,hd); k,v: (B,T,K,hd) with H % K == 0. fp32 softmax."""
+def flash_attention_ref(q, k, v, causal: bool = True, q_offset=0):
+    """q: (B,S,H,hd); k,v: (B,T,K,hd) with H % K == 0. fp32 softmax.
+    q_offset: absolute position of q[:, 0] (causal: row s sees keys
+    t <= s + q_offset)."""
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
@@ -24,7 +26,8 @@ def flash_attention_ref(q, k, v, causal: bool = True):
     logits = jnp.einsum("bskgh,btkh->bkgst", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) / math.sqrt(hd)
     if causal:
-        mask = jnp.tril(jnp.ones((S, T), bool), k=T - S)
+        mask = (jnp.arange(T)[None, :] <=
+                jnp.arange(S)[:, None] + q_offset)
         logits = jnp.where(mask[None, None, None], logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("bkgst,btkh->bskgh", p, v.astype(jnp.float32))
@@ -35,14 +38,16 @@ def flash_attention_ref(q, k, v, causal: bool = True):
 # flash_decode
 # ---------------------------------------------------------------------------
 def flash_decode_ref(q, k, v, pos):
-    """q: (B,1,H,hd); k,v: (B,T,K,hd); attend to indices <= pos."""
+    """q: (B,1,H,hd); k,v: (B,T,K,hd); attend to indices <= pos (a
+    scalar, or (B,) per row)."""
     B, _, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
     qg = q.reshape(B, K, G, hd)
     logits = jnp.einsum("bkgh,btkh->bkgt", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) / math.sqrt(hd)
-    valid = (jnp.arange(T) <= pos)[None, None, None, :]
+    valid = (jnp.arange(T)[None, :] <=
+             jnp.reshape(pos, (-1, 1)))[:, None, None, :]
     logits = jnp.where(valid, logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("bkgt,btkh->bkgh", p, v.astype(jnp.float32))

@@ -98,7 +98,8 @@ def _gumbel(base_u32, idx_u32, xp, to_i32, to_f32):
     base_u32: uint32 scalar/array broadcastable against idx_u32 (uint32
     vocab indices). Returns float32 of idx's shape."""
     h = _mix(base_u32 ^ idx_u32, xp)
-    u = (((h >> 8)).astype(xp.float32) + _HALF) * _U24    # (0,1) exclusive
+    # h >> 8 < 2^24, so its int32 view converts to the same float32
+    u = (to_i32(h >> 8).astype(xp.float32) + _HALF) * _U24   # (0,1) open
     return -_log32(-_log32(u, xp, to_i32, to_f32), xp, to_i32, to_f32)
 
 
@@ -174,86 +175,105 @@ def prepare_rows(logits, temp, top_k, *, vocab_size: int):
     # np.partition threshold); k outside (0, V) disables the filter
     top_k = jnp.asarray(top_k, jnp.int32)
     use_k = noisy & (top_k > 0) & (top_k < vocab_size)
-    srt = -jnp.sort(-z, axis=-1)                    # descending
-    kidx = jnp.clip(top_k - 1, 0, Vp - 1)
-    kth = jnp.take_along_axis(srt, kidx[:, None], axis=-1)[:, 0]
+    kth = _kth_largest(z, jnp.clip(top_k, 1, Vp))
     thr = jnp.where(use_k, kth, -jnp.inf)
     z = jnp.where(z >= thr[:, None], z, -jnp.inf)
     return z, noisy
 
 
+def _kth_largest(z, k):
+    """Per-row k-th largest value of z (B, V) float32, k (B,) in [1, V].
+
+    Exact, like a sort, but without one: the float bits are mapped to a
+    uint32 key that orders as the floats do, and the key of the k-th
+    largest element — the largest r with #{key >= r} >= k — is built one
+    bit at a time from the top, 32 counting passes over the row."""
+    bits = jax.lax.bitcast_convert_type(z, jnp.int32)
+    key = jax.lax.bitcast_convert_type(
+        jnp.where(bits < 0, ~bits, bits ^ jnp.int32(-2 ** 31)), jnp.uint32)
+
+    def bit(i, r):
+        cand = r | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        n = jnp.sum(key >= cand[:, None], axis=-1, dtype=jnp.int32)
+        return jnp.where(n >= k, cand, r)
+
+    r = jax.lax.fori_loop(0, 32, bit, jnp.zeros(z.shape[:1], jnp.uint32))
+    rb = jax.lax.bitcast_convert_type(r, jnp.int32)
+    return jax.lax.bitcast_convert_type(
+        jnp.where(rb < 0, rb ^ jnp.int32(-2 ** 31), ~rb), jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # the Pallas kernel: tiled noise + online first-index argmax
 # ---------------------------------------------------------------------------
-def _kernel(keys_ref, z_ref, o_ref, val_scr, idx_scr, *, vtile: int):
-    b = pl.program_id(0)
-    ti = pl.program_id(1)
-    nt = pl.num_programs(1)
+_MAX_VTILE = 8192
+
+
+def _kernel(base_ref, noisy_ref, z_ref, o_ref, val_scr, idx_scr, *,
+            vtile: int):
+    """One vocab tile of every row: base_ref (B,1) int32 (the rows'
+    uint32 stream keys), noisy_ref (B,1) int32, z_ref (B, vtile)."""
+    ti = pl.program_id(0)
+    B = z_ref.shape[0]
 
     @pl.when(ti == 0)
     def _init():
-        val_scr[0] = NEG_INF
-        idx_scr[0] = 0
+        val_scr[...] = jnp.full_like(val_scr, NEG_INF)
+        idx_scr[...] = jnp.zeros_like(idx_scr)
 
-    seed = keys_ref[b, 0]
-    rid = keys_ref[b, 1]
-    ctr = keys_ref[b, 2]
-    noisy = keys_ref[b, 3]
-    base = _base_key(seed.reshape(1, 1), rid.reshape(1, 1),
-                     ctr.reshape(1, 1), jnp)
-    col = ti * vtile + jax.lax.broadcasted_iota(jnp.int32, (1, vtile), 1)
-    g = _gumbel(base, col.astype(jnp.uint32), jnp, _jnp_to_i32, _jnp_to_f32)
-    z = z_ref[0, :].reshape(1, vtile)
-    y = jnp.where(noisy != 0, z + g, z)
+    col = ti * vtile + jax.lax.broadcasted_iota(jnp.int32, (B, vtile), 1)
+    base = jax.lax.bitcast_convert_type(base_ref[...], jnp.uint32)
+    g = _gumbel(base, jax.lax.bitcast_convert_type(col, jnp.uint32), jnp,
+                _jnp_to_i32, _jnp_to_f32)
+    z = z_ref[...]
+    y = jnp.where(noisy_ref[...] != 0, z + g, z)
     # -inf rows (vocab padding / top-k filtered) can never win: noise is
     # finite, so -inf + g stays -inf < any finite running best
-    tmax = jnp.max(y)
-    targ = jnp.argmax(y[0, :]).astype(jnp.int32) + ti * vtile
-    better = tmax > val_scr[0]
-    val_scr[0] = jnp.where(better, tmax, val_scr[0])
-    idx_scr[0] = jnp.where(better, targ, idx_scr[0])
+    tmax = jnp.max(y, axis=1, keepdims=True)                   # (B, 1)
+    # first index attaining the tile max (argmax's tie rule)
+    targ = jnp.min(jnp.where(y == tmax, col, jnp.iinfo(jnp.int32).max),
+                   axis=1, keepdims=True)
+    better = tmax > val_scr[...]
+    val_scr[...] = jnp.where(better, tmax, val_scr[...])
+    idx_scr[...] = jnp.where(better, targ, idx_scr[...])
 
-    @pl.when(ti == nt - 1)
+    @pl.when(ti == pl.num_programs(0) - 1)
     def _finish():
-        o_ref[0] = idx_scr[0]
+        o_ref[...] = idx_scr[...]
 
 
 def fused_sample(logits, temp, top_k, keys, *, vocab_size: int,
                  interpret: bool = False):
     """logits: (B, Vp); temp: (B,) float32; top_k: (B,) int32; keys:
     (B, 3) int32 (seed, rid, token_counter). Returns (B,) int32 sampled
-    token ids, bit-identical to ``ServeEngine._sample`` row by row."""
+    token ids, bit-identical to ``ServeEngine._sample`` row by row.
+
+    A block is every row of one vocab tile (B, vtile), so the kernel
+    walks the vocabulary once for the whole batch."""
     B, Vp = logits.shape
     z, noisy = prepare_rows(logits, temp, top_k, vocab_size=vocab_size)
-    vtile = min(512, 1 << max(0, (Vp - 1).bit_length()))
+    vtile = min(_MAX_VTILE, -(-Vp // 128) * 128)
     pad = (-Vp) % vtile
     if pad:
         z = jnp.pad(z, ((0, 0), (0, pad)), constant_values=-jnp.inf)
     nt = (Vp + pad) // vtile
-    keys4 = jnp.concatenate(
-        [jnp.asarray(keys, jnp.int32),
-         noisy.astype(jnp.int32)[:, None]], axis=1)
+    keys = jnp.asarray(keys, jnp.int32)
+    base = jax.lax.bitcast_convert_type(
+        _base_key(keys[:, 0], keys[:, 1], keys[:, 2], jnp), jnp.int32)
     # replace -inf with a finite floor: the kernel adds noise to every
     # lane and -inf + finite is -inf (fine), but NEG_INF keeps the
     # scratch compare total-ordered even if a row is entirely masked
     z = jnp.maximum(z, NEG_INF)
-    return pl.pallas_call(
+    row_spec = pl.BlockSpec((B, 1), lambda ti: (0, 0))
+    out = pl.pallas_call(
         functools.partial(_kernel, vtile=vtile),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, nt),
-            in_specs=[
-                pl.BlockSpec((1, vtile),
-                             lambda b, ti, keys_ref: (b, ti)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1,), lambda b, ti, keys_ref: (b,),
-                memory_space=pltpu.SMEM),
-            scratch_shapes=[
-                pltpu.SMEM((1,), jnp.float32),
-                pltpu.SMEM((1,), jnp.int32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.int32),
+        grid=(nt,),
+        in_specs=[row_spec, row_spec,
+                  pl.BlockSpec((B, vtile), lambda ti: (0, ti))],
+        out_specs=row_spec,
+        scratch_shapes=[pltpu.VMEM((B, 1), jnp.float32),
+                        pltpu.VMEM((B, 1), jnp.int32)],
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
         interpret=interpret,
-    )(keys4, z)
+    )(base[:, None], noisy.astype(jnp.int32)[:, None], z)
+    return out[:, 0]

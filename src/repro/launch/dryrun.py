@@ -26,7 +26,6 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import (SHAPES, get_model_config, list_archs,
                            make_run_config, shape_applicable)
 from repro.launch.mesh import make_production_mesh, mesh_config
@@ -174,7 +173,7 @@ def _inner_scan_correction(model_cfg, shape_cfg, kind: str) -> dict:
 
 def _cost_of(lowered) -> dict:
     compiled = lowered.compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     coll = collective_stats(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),
@@ -260,7 +259,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         t_compile = time.perf_counter() - t0
 
         mem = compiled.memory_analysis()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis() or {}
         hlo = compiled.as_text()
         coll = collective_stats(hlo)
 

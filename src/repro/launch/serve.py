@@ -18,6 +18,7 @@ import jax
 import numpy as np
 
 from repro.configs import SHAPES, list_archs, make_run_config
+from repro.launch.cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.serve.engine import Request, ServeEngine
 
@@ -48,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--slo-max-load", type=int, default=64)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     run = make_run_config(args.arch, args.shape, smoke=args.smoke)
     model = build_model(run)
     params = model.init(jax.random.key(run.seed))
@@ -90,9 +92,9 @@ def _serve_fleet(run, params, args) -> int:
         autoscale = AutoscaleConfig(
             hysteresis=1, cooldown=2,
             max_engines=args.fleet + args.spares, pinned=("serve0",))
+    # one VF per chip: the host needs a device for every engine and spare
     fleet = ServeFleet(
-        run, params, num_engines=args.fleet,
-        num_devices=max(2 * (args.fleet + args.spares), 4),
+        run, params, num_engines=args.fleet, devices=jax.devices(),
         num_vfs=args.fleet + (args.spares if args.autoscale else 0),
         slots=args.slots, max_len=args.max_len, paged=args.paged,
         page_size=args.page_size, prefill_chunk=args.prefill_chunk,

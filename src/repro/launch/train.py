@@ -25,6 +25,7 @@ from repro.checkpoint.store import CheckpointStore
 from repro.configs import (OptimizerConfig, SHAPES, list_archs,
                            make_run_config)
 from repro.data.pipeline import Prefetcher, SyntheticSource
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import local_mesh_config, make_mesh_from_config
 from repro.runtime.partitioning import ShardingRules
 from repro.train.step import init_train_state, make_train_step
@@ -124,6 +125,7 @@ def main(argv=None):
     ap.add_argument("--crash-at", type=int, default=0,
                     help="simulate a hard crash after N steps (testing)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     last = train(args)
     print(json.dumps(last))
     return 0

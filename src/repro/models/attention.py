@@ -1,8 +1,9 @@
 """Attention: GQA reference implementation + kernel dispatch.
 
-``kernel_backend='reference'`` is pure jnp (used by CPU tests and by the
-dry-run so ``cost_analysis`` counts true attention FLOPs). ``'pallas'``
-routes to the Pallas TPU kernels (validated in interpret mode on CPU).
+``backend='reference'`` is pure jnp (the dry-run uses it so
+``cost_analysis`` counts true attention FLOPs); ``'pallas'`` routes to
+the Pallas TPU kernels, compiled for the chip or — when the caller asks
+for it with ``interpret`` — run by the Pallas interpreter (CPU tests).
 """
 from __future__ import annotations
 
@@ -55,13 +56,12 @@ def attention_ref(q, k, v, *, causal: bool, q_offset=0,
 
 def attention(q, k, v, *, causal: bool, backend: str = "reference",
               q_offset=0, kv_len=None, interpret: bool = False) -> jax.Array:
-    # q_offset may be a traced offset (chunked prefill) — only a static 0
-    # may take the fused kernel, and a tracer must not be bool()'d
-    if (backend == "pallas" and kv_len is None
-            and isinstance(q_offset, int) and q_offset == 0):
+    # q_offset may be a traced offset (chunked prefill): the kernel takes
+    # it as a prefetched scalar
+    if backend == "pallas" and kv_len is None:
         from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, causal=causal,
-                                    interpret=interpret)
+        return kops.flash_attention(q, k, v, q_offset, causal=causal,
+                                    backend="pallas", interpret=interpret)
     return attention_ref(q, k, v, causal=causal, q_offset=q_offset,
                          kv_len=kv_len)
 
@@ -72,9 +72,9 @@ def decode_attention(q, k_cache, v_cache, pos, *, backend: str = "reference",
     the index the current token was just written to (attend to <= pos).
     Per-slot pos (B,) is the continuous-batching shape; pos[b] < 0 marks an
     inactive slot (kv_len 0 — its output is meaningless and discarded)."""
-    if backend == "pallas" and jnp.asarray(pos).ndim == 0:
+    if backend == "pallas":
         from repro.kernels import ops as kops
-        return kops.flash_decode(q, k_cache, v_cache, pos,
+        return kops.flash_decode(q, k_cache, v_cache, pos, backend="pallas",
                                  interpret=interpret)
     return attention_ref(q, k_cache, v_cache, causal=False, kv_len=pos + 1)
 
@@ -90,15 +90,10 @@ def paged_decode_attention(q, k_pages, v_pages, tables, pos, *,
     ((P,page,K) fp32) the pools are int8 and the quantized kernel
     dequantizes in-tile."""
     from repro.kernels import ops as kops
+    impl = "pallas" if backend == "pallas" else "ref"
     if k_scale is not None:
-        if backend == "pallas":
-            return kops.paged_decode_quant(q, k_pages, v_pages, k_scale,
-                                           v_scale, tables, pos,
-                                           interpret=interpret)
         return kops.paged_decode_quant(q, k_pages, v_pages, k_scale,
-                                       v_scale, tables, pos, backend="ref")
-    if backend == "pallas":
-        return kops.paged_decode(q, k_pages, v_pages, tables, pos,
-                                 interpret=interpret)
-    return kops.paged_decode(q, k_pages, v_pages, tables, pos,
-                             backend="ref")
+                                       v_scale, tables, pos, backend=impl,
+                                       interpret=interpret)
+    return kops.paged_decode(q, k_pages, v_pages, tables, pos, backend=impl,
+                             interpret=interpret)

@@ -192,8 +192,10 @@ def _apply_block(cfg, run: RunConfig, kind: str, p, x, mode, cache_j,
                  positions, pos, memory, causal=True, cross=False,
                  tables=None, active=None):
     cdt = _dt(run.precision.compute)
-    backend = run.kernel_backend
-    interpret = backend == "pallas" and jax.default_backend() != "tpu"
+    # the kernels are forward-only (no VJP): training differentiates the
+    # reference, serving (prefill / chunk / decode) runs the kernels
+    backend = "reference" if mode == "train" else run.kernels
+    interpret = run.interpret
     new_cache = {}
     if kind == ATTN:
         out, nc = _attn_mixer(cfg, p, x, cdt, mode, cache_j, positions, pos,

@@ -138,6 +138,7 @@ def mamba_block(cfg: ModelConfig, p: dict, x, cdt, mode: str = "train",
             from repro.kernels import ops as kops
             y, new_state = kops.ssm_scan(xdt, Bv, Cv, log_a,
                                          chunk=cfg.ssm.chunk,
+                                         backend="pallas",
                                          interpret=interpret)
         else:
             y, new_state = ssd_chunked(xdt, Bv, Cv, log_a,

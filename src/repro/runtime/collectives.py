@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
-
 
 def _pack(x, block):
     from repro.kernels import ops as kops
@@ -40,7 +38,7 @@ def compressed_psum_mean(x: jax.Array, axis: str, *, block: int = 256):
     Call INSIDE shard_map. x: any shape; flattened internally to
     (n_dev, -1) rows padded to a block multiple.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     flat = x.astype(jnp.float32).reshape(-1)
     per = -(-flat.size // n)                    # ceil
     per = -(-per // block) * block              # block multiple
@@ -80,5 +78,5 @@ def compressed_grad_allreduce(stacked_grads, mesh: Mesh,
 
     in_specs = (jax.tree.map(lambda _: P(axis), stacked_grads),)
     out_specs = jax.tree.map(lambda _: P(), stacked_grads)
-    return shard_map(inner, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)(stacked_grads)
+    return jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(stacked_grads)

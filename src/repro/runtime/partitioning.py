@@ -18,7 +18,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import keystr
 from repro.configs.base import MeshConfig, RunConfig
 
 _TLS = threading.local()
@@ -204,7 +203,7 @@ class ShardingRules:
 
     def param_specs(self, tree) -> dict:
         def one(path, leaf):
-            p = keystr(path, simple=True, separator="/")
+            p = jax.tree_util.keystr(path, simple=True, separator="/")
             return self.param_spec(p, leaf.shape)
         return jax.tree_util.tree_map_with_path(one, tree)
 

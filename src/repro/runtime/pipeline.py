@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def bubble_fraction(num_microbatches: int, num_stages: int) -> float:
     return (num_stages - 1) / (num_microbatches + num_stages - 1)
@@ -134,6 +132,6 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, mesh: Mesh,
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
     others = tuple(None for _ in range(x.ndim - 1))
     xspec = P(*((None,) + others))
-    fn = shard_map(per_stage, mesh=mesh, in_specs=(pspec, xspec),
-                   out_specs=xspec, check_vma=False)
+    fn = jax.shard_map(per_stage, mesh=mesh, in_specs=(pspec, xspec),
+                       out_specs=xspec, check_vma=False)
     return fn(stage_params, x)

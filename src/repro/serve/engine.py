@@ -178,6 +178,19 @@ class ServeEngine:
             run, rules, paged=paged, fused=self.fused_sampling))
         self._chunk = jax.jit(make_prefill_chunk(run, rules))
         self._cache = None                              # lazy batched cache
+        #: the chip this engine's params and KV cache live on (its VF's
+        #: device, see ``place``); None: wherever jax puts them
+        self.device = None
+
+    def place(self, device) -> None:
+        """Pin params and KV cache to ``device``; every step then runs
+        there (the step's host-made inputs follow the committed params)."""
+        self.device = device
+        if device is None:
+            return
+        self.params = jax.device_put(self.params, device)
+        if self._cache is not None:
+            self._cache = jax.device_put(self._cache, device)
 
     # -- cache plumbing -------------------------------------------------------
     def _ensure_cache(self):
@@ -191,6 +204,8 @@ class ServeEngine:
                                                kv_dtype=self.kv_dtype)
             else:
                 self._cache = self.model.init_cache(shape)
+            if self.device is not None:
+                self._cache = jax.device_put(self._cache, self.device)
 
     def _insert(self, slot: int, req_cache):
         """Write a (1, prefill_len, ...) request cache into batch slot."""

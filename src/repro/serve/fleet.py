@@ -56,6 +56,14 @@ from repro.serve.paged import CacheExhausted, RequestRejected
 from repro.serve.telemetry import MetricsBus
 
 
+def _chip(vf: VirtualFunction):
+    """The device an engine on ``vf`` runs on: the VF's first device, or
+    None for a pool of simulation tokens. An engine is single-device; a
+    VF with several devices hosts it on the first."""
+    dev = vf.devices[0] if vf.devices else None
+    return dev if isinstance(dev, jax.Device) else None
+
+
 class EngineTenant:
     """Tenant-protocol adapter around a ServeEngine (the guest's 'VM')."""
 
@@ -82,6 +90,7 @@ class EngineTenant:
              flash: bool = True) -> float:
         if state is not None:
             self.engine.import_state(state)
+        self.engine.place(_chip(vf))
         key = (tuple(vf.mesh_shape), tuple(str(d) for d in vf.devices))
         self._exec_cache.setdefault(key, True)
         self.vf_id = vf.vf_id
@@ -121,7 +130,12 @@ class EngineTenant:
         return {}
 
     def shardings_for(self, vf: VirtualFunction):
-        return None
+        """Restore every state leaf straight onto the VF's chip."""
+        chip = _chip(vf)
+        if chip is None:
+            return None
+        sh = jax.sharding.SingleDeviceSharding(chip)
+        return jax.tree.map(lambda _: sh, self.state_template())
 
     def state_template(self):
         if self._template is None:
@@ -287,15 +301,15 @@ class StageShellTenant:
 class ServeFleet:
     """Run ``num_engines`` ServeEngines as SVFF tenants over one pool."""
 
-    def __init__(self, run, params, *, num_engines: int = 2,
-                 num_devices: int = 8, policy: str = "first_fit",
+    def __init__(self, run, params, *, devices, num_engines: int = 2,
+                 policy: str = "first_fit",
                  slots: int = 4, max_len: int = 256, paged: bool = True,
                  page_size: int = 16, num_pages: Optional[int] = None,
                  prefill_chunk: int = 0, share_prefix: bool = False,
                  kv_dtype: Optional[str] = None,
                  fused_sampling: bool = False,
                  slo_max_load: int = 64,
-                 workdir: str = "/tmp/svff_fleet", devices=None,
+                 workdir: str = "/tmp/svff_fleet",
                  autoscale: Optional[AutoscaleConfig] = None,
                  spare_engines: int = 0, num_vfs: Optional[int] = None,
                  stages: int = 1, max_stages: Optional[int] = None,
@@ -312,8 +326,10 @@ class ServeFleet:
         self.stages = max(1, int(stages))
         self.max_stages = max_stages
         self.microbatches = microbatches
-        devices = (tuple(devices) if devices is not None else
-                   tuple(f"fleetdev{i}" for i in range(num_devices)))
+        # ``devices``: the chips the fleet's VFs carve up (jax.devices());
+        # tests and the CPU benchmarks pass tokens
+        # (``repro.core.pool.token_devices``), which place nothing
+        devices = tuple(devices)
         # the VF cap is the DEVICE budget (>= 1 device per VF), not the
         # initial engine count — capping at num_engines made every later
         # reconfiguration to more VFs silently impossible

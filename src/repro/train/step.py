@@ -158,13 +158,10 @@ def make_decode_step(run: RunConfig,
 
     def _sample_on_device(logits, temp, topk, keys):
         from repro.kernels import ops as kops
-        backend = run.kernel_backend
-        interpret = (backend == "pallas"
-                     and jax.default_backend() != "tpu")
         return kops.fused_sample(
             logits, temp, topk, keys, vocab_size=run.model.vocab_size,
-            interpret=interpret,
-            backend="auto" if backend == "pallas" else "ref")
+            interpret=run.interpret,
+            backend="pallas" if run.kernels == "pallas" else "ref")
 
     if paged and fused:
         def decode(params, cache, tokens, pos, tables, active,

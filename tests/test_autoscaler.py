@@ -6,6 +6,7 @@ import tempfile
 import numpy as np
 import pytest
 
+from repro.core.pool import token_devices
 from repro.core.autoscaler import (Autoscaler, AutoscaleAction,
                                    AutoscaleConfig, EngineStats,
                                    TelemetrySnapshot, justify_action)
@@ -211,7 +212,8 @@ def test_fleet_vf_cap_follows_device_budget_and_scales_out(setup):
     engine (grow path: the full reconf cycle carves one more VF)."""
     from repro.serve import Request, ServeFleet
     run, model, params = setup
-    fleet = ServeFleet(run, params, num_engines=1, num_devices=4, slots=2,
+    fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(4),
+                       slots=2,
                        max_len=48, workdir=tempfile.mkdtemp())
     assert fleet.pool.max_vfs == 4                  # device budget, not 1
     tid = fleet.scale_out()                         # past the initial size
@@ -233,7 +235,8 @@ def test_fleet_precarved_vfs_make_scale_out_pause_free(setup):
     scale-out is a plain attach: no engine is ever paused for it."""
     from repro.serve import ServeFleet
     run, model, params = setup
-    fleet = ServeFleet(run, params, num_engines=1, num_devices=4, slots=2,
+    fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(4),
+                       slots=2,
                        max_len=48, num_vfs=2, workdir=tempfile.mkdtemp())
     assert len(fleet.pool.vfs) == 2
     fleet.scale_out()
@@ -248,7 +251,8 @@ def test_fleet_scale_in_refuses_inflight_prefill_then_parks(setup):
     from repro.core import ManagerError
     from repro.serve import Request, ServeFleet
     run, model, params = setup
-    fleet = ServeFleet(run, params, num_engines=1, num_devices=2, slots=2,
+    fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(2),
+                       slots=2,
                        max_len=48, prefill_chunk=3,
                        workdir=tempfile.mkdtemp())
     eng = fleet.tenants["serve0"].engine
@@ -275,7 +279,7 @@ def test_fleet_rebalance_moves_queue_and_keeps_tokens(setup):
     run, model, params = setup
 
     def serve(rebalance):
-        fleet = ServeFleet(run, params, num_engines=2, num_devices=4,
+        fleet = ServeFleet(run, params, num_engines=2, devices=token_devices(4),
                            slots=1, max_len=48,
                            workdir=tempfile.mkdtemp())
         reqs = [Request(rid=i, prompt=(np.arange(4) * (i + 2)) % 100,

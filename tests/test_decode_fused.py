@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.pool import token_devices
 from repro.configs import make_run_config
 from repro.models.model import build_model
 from repro.serve import (Request, ServeEngine, ServeFleet,
@@ -245,7 +246,7 @@ def test_i10_int8_fused_prefix_sharing_through_pause_live(setup):
               kv_dtype="int8", fused_sampling=True, share_prefix=True)
 
     def fleet_serve(pause):
-        fleet = ServeFleet(run, params, num_engines=1, num_devices=2,
+        fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(2),
                            workdir=tempfile.mkdtemp(), **kw)
         reqs = reqs_fn()
         for r in reqs:

@@ -21,7 +21,7 @@ from repro.core.autoscaler import (AutoscaleAction, AutoscaleConfig,
                                    EngineStats, TelemetrySnapshot,
                                    justify_action)
 from repro.core import ManagerError, SVFFManager
-from repro.core.pool import DevicePool
+from repro.core.pool import DevicePool, token_devices
 from repro.core.staging import StagingEngine
 from repro.models.model import build_model
 from repro.serve.engine import Request, ServeEngine
@@ -41,7 +41,7 @@ def setup():
 
 def _fleet(run, params, **kw):
     kw.setdefault("num_engines", 2)
-    kw.setdefault("num_devices", 4)
+    kw.setdefault("devices", token_devices(4))
     kw.setdefault("slots", 2)
     kw.setdefault("max_len", 48)
     kw.setdefault("paged", True)

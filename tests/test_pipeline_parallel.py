@@ -13,6 +13,7 @@ import tempfile
 import numpy as np
 import pytest
 
+from repro.core.pool import token_devices
 from repro.runtime.pipeline import (bubble_fraction, schedule_stats,
                                     serve_schedule)
 from repro.serve.stages import (build_templates, check_partition,
@@ -268,7 +269,7 @@ def test_fleet_vf_loss_fallback_and_stage_telemetry(qsetup):
     oracle = ServeEngine(run, params, slots=2, max_len=48, paged=True)
     want = _drive(oracle, _mkreqs(n=3))
     with tempfile.TemporaryDirectory() as wd:
-        fleet = ServeFleet(run, params, num_engines=1, num_devices=4,
+        fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(4),
                            stages=2, slots=2, max_len=48, workdir=wd)
         tn = fleet.tenants["serve0"]
         assert tn.stage_width == 2
@@ -306,14 +307,14 @@ def test_fleet_scale_out_gang_budget(qsetup):
     from repro.serve.fleet import ServeFleet
     run, params = qsetup
     with tempfile.TemporaryDirectory() as wd:
-        fleet = ServeFleet(run, params, num_engines=1, num_devices=3,
+        fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(3),
                            stages=2, slots=2, max_len=48, workdir=wd)
         with pytest.raises(ManagerError, match="device budget"):
             fleet.scale_out()
         assert len(fleet.pool.vfs) == 2         # partition untouched
         assert sorted(fleet.tenants) == ["serve0"]   # no leaked tenant
     with tempfile.TemporaryDirectory() as wd:
-        fleet = ServeFleet(run, params, num_engines=1, num_devices=4,
+        fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(4),
                            stages=2, slots=2, max_len=48, workdir=wd)
         tid = fleet.scale_out()
         tn = fleet.tenants[tid]

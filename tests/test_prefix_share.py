@@ -10,6 +10,7 @@ import pytest
 
 from repro.configs import make_run_config
 from repro.core import DevicePool, SVFFManager, StagingEngine
+from repro.core.pool import token_devices
 from repro.models.model import build_model
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.paged import (BlockAllocator, CacheExhausted,
@@ -394,7 +395,7 @@ def test_fleet_exposes_cache_pressure_to_autoscaler(setup):
     EngineStats fields the autoscaler policy reads."""
     from repro.serve.fleet import ServeFleet
     run, _, params = setup
-    fleet = ServeFleet(run, params, num_engines=1, num_devices=2,
+    fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(2),
                        slots=2, max_len=48, paged=True, page_size=16,
                        share_prefix=True,
                        workdir=tempfile.mkdtemp())

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.pool import token_devices
 from repro.configs import make_run_config
 from repro.models.model import build_model
 from repro.serve.engine import DrainResult, Request, ServeEngine
@@ -341,7 +342,7 @@ def test_mid_run_pause_roundtrip_token_identical(setup):
 # fleet: engines as tenants under the SVFF manager
 # ===========================================================================
 def _fleet(run, params, policy, **kw):
-    return ServeFleet(run, params, num_engines=2, num_devices=4,
+    return ServeFleet(run, params, num_engines=2, devices=token_devices(4),
                       policy=policy, slots=2, max_len=48, paged=True,
                       page_size=8, workdir=tempfile.mkdtemp(), **kw)
 
@@ -374,7 +375,7 @@ def test_chunked_prefill_works_with_pallas_backend(setup):
     """Regression: attention()'s kernel-dispatch guard bool()'d the traced
     chunk offset (TracerBoolConversionError) under kernel_backend=pallas."""
     run, model, params = setup
-    prun = run.replace(kernel_backend="pallas")
+    prun = run.replace(kernel_backend="pallas", interpret=True)
     eng = ServeEngine(prun, params, slots=1, max_len=48, prefill_chunk=3)
     req = Request(rid=0, prompt=np.arange(7) % 100, max_new_tokens=2)
     eng.submit(req)
@@ -412,7 +413,7 @@ def test_pause_mid_chunked_prefill_requeues_jobs_token_identical(setup):
     run, model, params = setup
 
     def serve(pause_mid_prefill):
-        fleet = ServeFleet(run, params, num_engines=1, num_devices=2,
+        fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(2),
                            slots=2, max_len=48, paged=True, page_size=8,
                            prefill_chunk=3, workdir=tempfile.mkdtemp())
         eng = fleet.tenants["serve0"].engine
@@ -443,7 +444,8 @@ def test_fleet_slo_rejection_then_retry_completes(setup):
     the request — tracked fleet-side only — and the retry must serve
     normally."""
     run, model, params = setup
-    fleet = ServeFleet(run, params, num_engines=1, num_devices=2, slots=1,
+    fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(2),
+                       slots=1,
                        max_len=48, slo_max_load=1,
                        workdir=tempfile.mkdtemp())
     fleet.submit(Request(rid=0, prompt=np.arange(4), max_new_tokens=2))
@@ -470,7 +472,7 @@ def test_fleet_tie_break_is_creation_order_not_lexicographic(setup):
     serve2, ... — placement must follow engine creation index (this
     matters once the autoscaler spawns tenants dynamically)."""
     run, model, params = setup
-    fleet = ServeFleet(run, params, num_engines=12, num_devices=12,
+    fleet = ServeFleet(run, params, num_engines=12, devices=token_devices(12),
                        slots=1, max_len=48, workdir=tempfile.mkdtemp())
     placements = [fleet.submit(Request(rid=i, prompt=np.arange(4) % 50,
                                        max_new_tokens=1))

@@ -81,7 +81,7 @@ def test_dryrun_entrypoint_reduced_mesh(tmp_path):
         "import jax\n"
         "B.SINGLE_POD_MESH = B.MeshConfig((4, 2), ('data', 'model'))\n"
         "M.make_production_mesh = "
-        "lambda *, multi_pod=False: jax.make_mesh((4, 2), "
+        "lambda *, multi_pod=False: M.auto_mesh((4, 2), "
         "('data', 'model'))\n"
         "from repro.launch.dryrun import run_cell\n"
         f"r = run_cell('qwen3-0.6b', 'train_4k', False, "
