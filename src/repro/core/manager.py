@@ -112,65 +112,67 @@ class SVFFManager:
     def attach(self, tenant: Tenant, vf_id: Optional[str] = None,
                state=None) -> PhaseTimings:
         """Full attach path: record validation + bind + record write."""
-        t = PhaseTimings()
-        t0 = time.perf_counter()
-        sched = self._scheduler_for(tenant)
-        req = PlacementRequest(tenant_id=tenant.tid)
-        if vf_id:
-            # explicit placement still goes through admission control —
-            # e.g. a double attach must not leak the tenant's current VF
-            sched.admit(self.pool, self.tenants, req)
-            vf = self.pool.find(vf_id)
-        else:
-            vf = sched.select(self.pool, self.tenants, req)
-        if vf.state != VFState.DETACHED:
-            # validate BEFORE any mutation: a late VFTransitionError would
-            # leave owner/tenant state half-updated
-            raise PoolError(
-                f"cannot attach {tenant.tid}: {vf.vf_id} is "
-                f"{vf.state.value}, not detached")
-        try:   # attach re-validates any existing record (QDMA-manager checks)
-            self.records.validate(tenant.tid, self.pool)
-        except Exception:
-            pass
-        t.add("validate", time.perf_counter() - t0)
+        t = PhaseTimings(op="attach", tenant=tenant.tid)
+        with t.phase("validate"):
+            sched = self._scheduler_for(tenant)
+            req = PlacementRequest(tenant_id=tenant.tid)
+            if vf_id:
+                # explicit placement still goes through admission control
+                # — e.g. a double attach must not leak the tenant's
+                # current VF
+                sched.admit(self.pool, self.tenants, req)
+                vf = self.pool.find(vf_id)
+            else:
+                vf = sched.select(self.pool, self.tenants, req)
+            if vf.state != VFState.DETACHED:
+                # validate BEFORE any mutation: a late VFTransitionError
+                # would leave owner/tenant state half-updated
+                raise PoolError(
+                    f"cannot attach {tenant.tid}: {vf.vf_id} is "
+                    f"{vf.state.value}, not detached")
+            try:   # attach re-validates any existing record (QDMA checks)
+                self.records.validate(tenant.tid, self.pool)
+            except Exception:
+                pass
 
-        t0 = time.perf_counter()
-        if state is None:
-            store = CheckpointStore(self.detach_store_dir)
-            step = self._detached_steps(store).get(tenant.tid)
-            if step is not None:
-                # restore from the disk snapshot the detach wrote (read-
-                # only preparation: a corrupt snapshot must fail BEFORE
-                # the WAL entry exists, so the failure stays a clean,
-                # I8-preserving rejection)
-                shardings = tenant.shardings_for(vf)
-                like = tenant.state_template()
-                state = store.restore(step, like, shardings)
-                meta = store.metadata(step)
-                tenant.steps_done = meta.get("steps_done",
-                                             tenant.steps_done)
-        # WAL: every check passed — log the intent before the first mutation
-        entry = self.journal.begin("attach", tenant.tid, vf_id=vf.vf_id)
+        entry = None
         try:
-            compile_s = tenant.bind(vf, state=state)
-            vf.owner = tenant.tid
-            vf.transition(VFState.ATTACHED)
-            self.tenants[tenant.tid] = tenant
-            t.add("bind", time.perf_counter() - t0)
+            with t.phase("bind"):
+                if state is None:
+                    store = CheckpointStore(self.detach_store_dir)
+                    step = self._detached_steps(store).get(tenant.tid)
+                    if step is not None:
+                        # restore from the disk snapshot the detach wrote
+                        # (read-only preparation: a corrupt snapshot must
+                        # fail BEFORE the WAL entry exists, so the failure
+                        # stays a clean, I8-preserving rejection)
+                        shardings = tenant.shardings_for(vf)
+                        like = tenant.state_template()
+                        state = store.restore(step, like, shardings)
+                        meta = store.metadata(step)
+                        tenant.steps_done = meta.get("steps_done",
+                                                     tenant.steps_done)
+                # WAL: every check passed — log the intent before the
+                # first mutation
+                entry = self.journal.begin("attach", tenant.tid,
+                                           vf_id=vf.vf_id)
+                compile_s = tenant.bind(vf, state=state)
+                vf.owner = tenant.tid
+                vf.transition(VFState.ATTACHED)
+                self.tenants[tenant.tid] = tenant
             t.add("compile", compile_s)
-
-            t0 = time.perf_counter()
-            self.records.write(tenant.tid, vf.describe(),
-                               tenant.run.model.name)
-            t.add("record", time.perf_counter() - t0)
+            with t.phase("record"):
+                self.records.write(tenant.tid, vf.describe(),
+                                   tenant.run.model.name)
             self.journal.commit(entry)
         except InjectedCrash:
             raise                      # a crash leaves the intent pending
         except Exception:
             # clean failure (e.g. compile error): self-heal the intent —
-            # rolled back if bind never completed, forward otherwise
-            self._resolve_failed(entry)
+            # rolled back if bind never completed, forward otherwise. A
+            # failure before the intent was logged changed nothing.
+            if entry is not None:
+                self._resolve_failed(entry)
             raise
         return t
 
@@ -188,7 +190,7 @@ class SVFFManager:
     def detach(self, tenant: Tenant) -> PhaseTimings:
         """Standard SR-IOV detach: snapshot to DISK, unbind, free devices.
         The guest loses the device (tenant.status = detached)."""
-        t = PhaseTimings()
+        t = PhaseTimings(op="detach", tenant=tenant.tid)
         vf = self.pool.find(tenant.vf_id)
         if vf.state != VFState.ATTACHED or vf.owner != tenant.tid:
             # validate BEFORE the disk snapshot / unbind: detaching e.g. a
@@ -201,40 +203,40 @@ class SVFFManager:
         entry = self.journal.begin("detach", tenant.tid, vf_id=vf.vf_id,
                                    step=self._detach_counter + 1)
         try:
-            t0 = time.perf_counter()
-            state = tenant.export_state()
-            payload = self.staging.save(state, tenant=tenant.tid)
-            self._detach_counter += 1
-            store = CheckpointStore(self.detach_store_dir, keep=0)
-            store.save(self._detach_counter, payload,
-                       metadata={"tenant_id": tenant.tid,
-                                 "steps_done": tenant.steps_done})
-            t.add("snapshot_disk", time.perf_counter() - t0)
+            with t.phase("snapshot_disk"):
+                state = tenant.export_state()
+                payload = self.staging.save(state, tenant=tenant.tid)
+                self._detach_counter += 1
+                store = CheckpointStore(self.detach_store_dir, keep=0)
+                store.save(self._detach_counter, payload,
+                           metadata={"tenant_id": tenant.tid,
+                                     "steps_done": tenant.steps_done})
             # crash window: disk snapshot written, guest still bound —
             # recovery rolls BACK (delete the orphan, tenant keeps running)
             crashpoint("after_detach_snapshot")
 
-            t0 = time.perf_counter()
-            for leaf in jax.tree.leaves(state):
-                try:
-                    leaf.delete()
-                except Exception:
-                    pass
-            tenant.detach()
-            vf.owner = None
-            vf.emulated.clear()
-            # NOTE: unlike pause, detach does NOT release devices — the VF
-            # still exists on the bus with its resources (SR-IOV
-            # semantics); only set_num_vfs / pause change device ownership.
-            vf.transition(VFState.DETACHED)
-            # crash window: unbind complete but the attach record still on
-            # disk — recovery rolls FORWARD (remove the record, commit)
-            crashpoint("after_unbind")
-            self.records.remove(tenant.tid)
-            # the staging memo's device refs are dead after unbind; drop
-            # them so the memo stays bounded across tenant churn
-            self.staging.clear(tenant.tid)
-            t.add("unbind", time.perf_counter() - t0)
+            with t.phase("unbind"):
+                for leaf in jax.tree.leaves(state):
+                    try:
+                        leaf.delete()
+                    except Exception:
+                        pass
+                tenant.detach()
+                vf.owner = None
+                vf.emulated.clear()
+                # NOTE: unlike pause, detach does NOT release devices —
+                # the VF still exists on the bus with its resources
+                # (SR-IOV semantics); only set_num_vfs / pause change
+                # device ownership.
+                vf.transition(VFState.DETACHED)
+                # crash window: unbind complete but the attach record
+                # still on disk — recovery rolls FORWARD (remove the
+                # record, commit)
+                crashpoint("after_unbind")
+                self.records.remove(tenant.tid)
+                # the staging memo's device refs are dead after unbind;
+                # drop them so the memo stays bounded across tenant churn
+                self.staging.clear(tenant.tid)
             self.journal.commit(entry)
         except InjectedCrash:
             raise                      # a crash leaves the intent pending
@@ -316,14 +318,11 @@ class SVFFManager:
     # ------------------------------------------------------------------ init
     def init(self, num_vfs: int, tenants: Sequence[Tenant],
              devices_per_vf: Optional[int] = None) -> PhaseTimings:
-        t = PhaseTimings()
-        t0 = time.perf_counter()
-        self.pool.rescan()
-        t.add("rescan", time.perf_counter() - t0)
-
-        t0 = time.perf_counter()
-        self.pool.set_num_vfs(num_vfs, devices_per_vf)
-        t.add("change_num_vf", time.perf_counter() - t0)
+        t = PhaseTimings(op="init")
+        with t.phase("rescan"):
+            self.pool.rescan()
+        with t.phase("change_num_vf"):
+            self.pool.set_num_vfs(num_vfs, devices_per_vf)
 
         for tn in tenants:
             # a gang lead (an engine spanning K VFs) attaches its whole
@@ -342,50 +341,46 @@ class SVFFManager:
         """The paper's reconfiguration cycle. Returns Table-II style timings
         (seconds): {rescan, remove_vf, change_num_vf, add_vf, total}."""
         use_pause = self.pause_enabled if use_pause is None else use_pause
-        timings = {}
+        t = PhaseTimings(op="reconf")
 
         # 1. rescan — be sure every PF/VF on the bus is discovered
-        t0 = time.perf_counter()
-        self.pool.rescan()
-        timings["rescan"] = time.perf_counter() - t0
+        with t.phase("rescan"):
+            self.pool.rescan()
 
         # 2. remove VF — pause (live guests keep their device) or detach
-        t0 = time.perf_counter()
-        live = [tn for tn in self.tenants.values()
-                if tn.status == "running"]
-        for tn in live:
-            if use_pause:
-                self.pause(tn)
-            else:
-                self.detach(tn)
-        timings["remove_vf"] = time.perf_counter() - t0
+        with t.phase("remove_vf"):
+            live = [tn for tn in self.tenants.values()
+                    if tn.status == "running"]
+            for tn in live:
+                if use_pause:
+                    self.pause(tn)
+                else:
+                    self.detach(tn)
 
         # 3. change #VF on the PF
-        t0 = time.perf_counter()
-        self.pool.set_num_vfs(num_vfs, devices_per_vf)
-        timings["change_num_vf"] = time.perf_counter() - t0
+        with t.phase("change_num_vf"):
+            self.pool.set_num_vfs(num_vfs, devices_per_vf)
 
         # 4. add VF — unpause previously-paused tenants; attach new ones
-        t0 = time.perf_counter()
-        for tn in live:
-            if use_pause:
-                # paused VFs kept their identity; give them devices again
-                vf = self.pool.find(tn.vf_id)
-                if not vf.devices:
-                    self.pool.allocate(
-                        vf, devices_per_vf
-                        or max(1, self.pool.num_devices // max(num_vfs, 1)))
-                self.unpause(tn)
-            else:
-                self.attach(tn)
-        for tn in new_tenants:
-            if getattr(tn, "gang_shells", None):
-                self.attach_group(tn)
-            else:
-                self.attach(tn)
-        timings["add_vf"] = time.perf_counter() - t0
-        timings["total"] = sum(timings.values())
-        return timings
+        with t.phase("add_vf"):
+            for tn in live:
+                if use_pause:
+                    # paused VFs kept their identity; give them devices
+                    vf = self.pool.find(tn.vf_id)
+                    if not vf.devices:
+                        self.pool.allocate(
+                            vf, devices_per_vf
+                            or max(1, self.pool.num_devices
+                                   // max(num_vfs, 1)))
+                    self.unpause(tn)
+                else:
+                    self.attach(tn)
+            for tn in new_tenants:
+                if getattr(tn, "gang_shells", None):
+                    self.attach_group(tn)
+                else:
+                    self.attach(tn)
+        return dict(t.phases, total=t.total)
 
     # --------------------------------------------------------- fault tolerance
     def migrate(self, tenant: Tenant) -> dict:
@@ -534,7 +529,7 @@ class SVFFManager:
                           for m in members])
         entry = self.journal.begin("attach_group", lead.tid, k=k,
                                    members=[m.tid for m in members])
-        t = PhaseTimings()
+        t = PhaseTimings(op="attach_group", tenant=lead.tid)
         try:
             for i, m in enumerate(members):
                 tm = self.attach(m)
@@ -569,7 +564,7 @@ class SVFFManager:
                    if getattr(s, "status", None) == "running"] + [lead]
         entry = self.journal.begin("detach_group", lead.tid,
                                    members=[m.tid for m in members])
-        t = PhaseTimings()
+        t = PhaseTimings(op="detach_group", tenant=lead.tid)
         try:
             for m in members:
                 tm = self.detach(m)

@@ -28,6 +28,7 @@ pre-copy rounds); for plain ``pause_vf`` the two coincide.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Callable, Optional
@@ -40,6 +41,7 @@ from repro.core.snapshot import ConfigSpaceSnapshot, serialize_specs
 from repro.core.staging import StagingEngine
 from repro.core.tenant import Tenant
 from repro.core.vf import VFState, VirtualFunction
+from repro.runtime.spans import span
 
 
 @dataclasses.dataclass
@@ -47,11 +49,25 @@ class PhaseTimings:
     phases: dict = dataclasses.field(default_factory=dict)
     #: phases NOT visible to the tenant (pre-copy rounds run while it steps)
     background: set = dataclasses.field(default_factory=set)
+    #: the operation these phases belong to: each ``phase`` is the span
+    #: ``<op>.<phase>`` (``pause.save_config_space``), stat ``tenant``
+    op: str = "op"
+    tenant: str = ""
 
     def add(self, name: str, seconds: float, *, stop: bool = True):
         self.phases[name] = self.phases.get(name, 0.0) + seconds
         if not stop:
             self.background.add(name)
+
+    @contextlib.contextmanager
+    def phase(self, name: str, *, stop: bool = True):
+        """Time the body as phase ``name`` and mark it as a program span:
+        the phase and the span are the same measurement. A body that
+        raises records no phase."""
+        with span(f"{self.op}.{name}", tenant=self.tenant):
+            t0 = time.perf_counter()
+            yield
+            self.add(name, time.perf_counter() - t0, stop=stop)
 
     @property
     def total(self) -> float:
@@ -94,45 +110,43 @@ def _stop_and_copy(vf: VirtualFunction, tenant: Tenant,
     always be rolled forward from it (crash-consistency; see
     ``SVFFManager.recover``)."""
     # -- step 1: save config space (+ MSI state) ---------------------------
-    t0 = time.perf_counter()
-    state = tenant.export_state()
-    payload = staging.save(state, tenant=tenant.tid,
-                           incremental=incremental)
-    specs = tenant.export_specs()
-    snap = ConfigSpaceSnapshot(
-        tenant_id=tenant.tid, steps_done=tenant.steps_done, payload=payload,
-        sharding_desc=serialize_specs(specs),
-        mesh_shape=tuple(vf.mesh_shape), mesh_axes=tuple(vf.mesh_axes),
-        exec_keys=list(tenant._exec_cache.keys()),
-        stats=staging.last_stats, compressed=staging.compression != "none",
-        precopy_rounds=precopy_rounds)
-    if sink is not None:
-        sink[tenant.tid] = snap
-    t.add("save_config_space", time.perf_counter() - t0)
+    with t.phase("save_config_space"):
+        state = tenant.export_state()
+        payload = staging.save(state, tenant=tenant.tid,
+                               incremental=incremental)
+        specs = tenant.export_specs()
+        snap = ConfigSpaceSnapshot(
+            tenant_id=tenant.tid, steps_done=tenant.steps_done,
+            payload=payload, sharding_desc=serialize_specs(specs),
+            mesh_shape=tuple(vf.mesh_shape), mesh_axes=tuple(vf.mesh_axes),
+            exec_keys=list(tenant._exec_cache.keys()),
+            stats=staging.last_stats,
+            compressed=staging.compression != "none",
+            precopy_rounds=precopy_rounds)
+        if sink is not None:
+            sink[tenant.tid] = snap
     # crash window: snapshot registered, tenant still running untouched —
     # recovery rolls the pause BACK (drop the snapshot, nothing else moved)
     crashpoint("after_snapshot_register")
 
     # -- step 2: unregister PCI ops (guest keeps emulated view) -------------
-    t0 = time.perf_counter()
-    tenant.suspend()
-    vf.emulated["status"] = "paused"
-    vf.emulated["steps_done"] = tenant.steps_done
-    t.add("unregister_pci", time.perf_counter() - t0)
+    with t.phase("unregister_pci"):
+        tenant.suspend()
+        vf.emulated["status"] = "paused"
+        vf.emulated["steps_done"] = tenant.steps_done
     # crash window: tenant suspended but the VF still ATTACHED holding its
     # devices — recovery rolls the pause FORWARD from the registered snap
     crashpoint("after_suspend")
 
     # -- step 3: unregister VFIO / exit IOMMU group --------------------------
-    t0 = time.perf_counter()
-    for leaf in jax.tree.leaves(state):
-        try:
-            leaf.delete()
-        except Exception:
-            pass
-    vf.transition(VFState.PAUSED)
-    vf.release_devices()
-    t.add("unregister_vfio", time.perf_counter() - t0)
+    with t.phase("unregister_vfio"):
+        for leaf in jax.tree.leaves(state):
+            try:
+                leaf.delete()
+            except Exception:
+                pass
+        vf.transition(VFState.PAUSED)
+        vf.release_devices()
     # the memo's device refs die with the VF; host copies live in the snap
     staging.clear(tenant.tid)
     return snap
@@ -142,7 +156,7 @@ def pause_vf(pool: DevicePool, vf: VirtualFunction, tenant: Tenant,
              staging: StagingEngine,
              sink: Optional[dict] = None) -> tuple[ConfigSpaceSnapshot,
                                                    PhaseTimings]:
-    t = PhaseTimings()
+    t = PhaseTimings(op="pause", tenant=tenant.tid)
     validate_pausable(vf, tenant)
     snap = _stop_and_copy(vf, tenant, staging, t, sink=sink)
     return snap, t
@@ -161,14 +175,13 @@ def pause_vf_live(pool: DevicePool, vf: VirtualFunction, tenant: Tenant,
     ``rounds`` is clamped to >= 1: a live pause with no background round
     is just ``pause_vf``, and would trip invariant I7's
     "live pause ran no background pre-copy" check."""
-    t = PhaseTimings()
+    t = PhaseTimings(op="pause", tenant=tenant.tid)
     validate_pausable(vf, tenant)
     rounds = max(1, rounds)
     for r in range(rounds):
-        t0 = time.perf_counter()
-        staging.save(tenant.export_state(), tenant=tenant.tid,
-                     incremental=True)
-        t.add(f"precopy_{r}", time.perf_counter() - t0, stop=False)
+        with t.phase(f"precopy_{r}", stop=False):
+            staging.save(tenant.export_state(), tenant=tenant.tid,
+                         incremental=True)
         # crash window: a pre-copy round landed in the memo, nothing
         # guest-visible moved — recovery discards the memo and rolls back
         crashpoint("mid_precopy_round")
@@ -182,31 +195,31 @@ def pause_vf_live(pool: DevicePool, vf: VirtualFunction, tenant: Tenant,
 def unpause_vf(pool: DevicePool, vf: VirtualFunction, tenant: Tenant,
                snap: ConfigSpaceSnapshot, staging: StagingEngine,
                num_devices: int | None = None) -> PhaseTimings:
-    t = PhaseTimings()
+    t = PhaseTimings(op="unpause", tenant=tenant.tid)
     if vf.state != VFState.PAUSED:
         raise PauseError(f"{vf.vf_id} is not paused")
 
     # -- step 1: restore I/O connections --------------------------------------
-    t0 = time.perf_counter()
-    if not vf.devices:
-        import math
-        pool.allocate(vf, num_devices or math.prod(snap.mesh_shape))
-    # crash window: devices (re)allocated but nothing restored — recovery
-    # rolls BACK (release the devices, keep the snapshot, stay paused)
-    crashpoint("before_unpause_restore")
-    shardings = tenant.shardings_for(vf)
-    state = staging.restore(snap.payload, shardings)
-    jax.block_until_ready(state)
-    vf.transition(VFState.ATTACHED)
-    # crash window: VF back to ATTACHED but the tenant not yet resumed —
-    # recovery rolls FORWARD (redo the restore from the retained snapshot)
-    crashpoint("after_unpause_restore")
-    t.add("restore_io", time.perf_counter() - t0)
+    with t.phase("restore_io"):
+        if not vf.devices:
+            import math
+            pool.allocate(vf, num_devices or math.prod(snap.mesh_shape))
+        # crash window: devices (re)allocated but nothing restored —
+        # recovery rolls BACK (release the devices, keep the snapshot,
+        # stay paused)
+        crashpoint("before_unpause_restore")
+        shardings = tenant.shardings_for(vf)
+        state = staging.restore(snap.payload, shardings)
+        jax.block_until_ready(state)
+        vf.transition(VFState.ATTACHED)
+        # crash window: VF back to ATTACHED but the tenant not yet resumed
+        # — recovery rolls FORWARD (redo the restore from the retained
+        # snapshot)
+        crashpoint("after_unpause_restore")
 
     # -- step 2: restore config registers --------------------------------------
-    t0 = time.perf_counter()
-    tenant.steps_done = snap.steps_done
-    tenant.resume(state, vf)
-    vf.emulated["status"] = "running"
-    t.add("restore_config", time.perf_counter() - t0)
+    with t.phase("restore_config"):
+        tenant.steps_done = snap.steps_done
+        tenant.resume(state, vf)
+        vf.emulated["status"] = "running"
     return t

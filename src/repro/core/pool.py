@@ -15,7 +15,6 @@ Invariants (property-tested):
 from __future__ import annotations
 
 import math
-import time
 from typing import Optional, Sequence
 
 import jax
@@ -55,7 +54,6 @@ class DevicePool:
 
     # -- discovery ("pci rescan", Table II step 1) -----------------------------
     def rescan(self) -> int:
-        t0 = time.perf_counter()
         if self._devices is None:
             self._devices = tuple(jax.devices())
         # validation sweep: confirm every device answers (a cheap put/get,
@@ -66,7 +64,6 @@ class DevicePool:
             if isinstance(d, jax.Device):
                 jax.device_put(0, d).block_until_ready()
         self._rescanned = True
-        self.last_rescan_s = time.perf_counter() - t0
         return len(self._devices)
 
     @property

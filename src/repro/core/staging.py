@@ -60,6 +60,7 @@ import jax
 import numpy as np
 
 from repro.core.fault import crashpoint
+from repro.runtime.spans import span
 
 _GLOBAL = "__global__"
 
@@ -74,10 +75,6 @@ class TransferStats:
     skipped_bytes: int = 0      # host-repr bytes reused from the memo
     num_descriptors: int = 0
     transport: str = "borrow"
-
-    @property
-    def bandwidth_gbps(self) -> float:
-        return self.bytes_moved / max(self.seconds, 1e-9) / 1e9
 
 
 @dataclasses.dataclass
@@ -274,8 +271,13 @@ class StagingEngine:
     # -- device -> host (pause / checkpoint) -----------------------------------
     def save(self, tree: Any, tenant: Optional[str] = None,
              incremental: Optional[bool] = None) -> Any:
-        if not self.pipeline:
-            return self._save_legacy(tree, tenant, incremental)
+        with span("staging.save"):
+            if not self.pipeline:
+                return self._save_legacy(tree, tenant, incremental)
+            return self._save_pipelined(tree, tenant, incremental)
+
+    def _save_pipelined(self, tree: Any, tenant: Optional[str],
+                        incremental: Optional[bool]) -> Any:
         from repro.kernels import ops as kops
         incremental = self.incremental if incremental is None else incremental
         transport = self._transport_mode()
@@ -298,31 +300,34 @@ class StagingEngine:
         # stage 0, overlapping the first D2H bursts)
         pending: dict[int, Any] = {}
         if incremental and self.dirty == "digest":
-            for i, (path, x) in enumerate(flat_p):
-                if isinstance(x, jax.Array):
-                    e = memo.get(jax.tree_util.keystr(path))
-                    if e is None or e.ref is not x:
-                        pending[i] = self._digest_dispatch(x)
+            with span("staging.digest"):
+                for i, (path, x) in enumerate(flat_p):
+                    if isinstance(x, jax.Array):
+                        e = memo.get(jax.tree_util.keystr(path))
+                        if e is None or e.ref is not x:
+                            pending[i] = self._digest_dispatch(x)
 
         # -- stage 0: dirty filter + stage 1: descriptor dispatch (async) ----
-        for i, (path, x) in enumerate(flat_p):
-            key = jax.tree_util.keystr(path)
-            logical += x.nbytes if isinstance(x, jax.Array) else _nbytes(x)
-            hit, digests[i] = self._memo_hit(memo, key, x, incremental,
-                                             digest=pending.get(i))
-            if hit is not None:
-                host_flat[i] = hit
-                skipped += _nbytes(hit)
-                continue
-            if not isinstance(x, jax.Array):
-                # materialize a real copy: a pause snapshot is the
-                # tenant's ONLY state copy, so it must not alias a host
-                # buffer the tenant may later mutate in place
-                host = np.array(x)
-                host_flat[i] = host
-                memo_puts.append((key, x, host, digests[i]))
-                continue
-            descs.extend(self._dispatch_leaf(i, x, transport, kops))
+        with span("staging.dispatch"):
+            for i, (path, x) in enumerate(flat_p):
+                key = jax.tree_util.keystr(path)
+                logical += (x.nbytes if isinstance(x, jax.Array)
+                            else _nbytes(x))
+                hit, digests[i] = self._memo_hit(memo, key, x, incremental,
+                                                 digest=pending.get(i))
+                if hit is not None:
+                    host_flat[i] = hit
+                    skipped += _nbytes(hit)
+                    continue
+                if not isinstance(x, jax.Array):
+                    # materialize a real copy: a pause snapshot is the
+                    # tenant's ONLY state copy, so it must not alias a
+                    # host buffer the tenant may later mutate in place
+                    host = np.array(x)
+                    host_flat[i] = host
+                    memo_puts.append((key, x, host, digests[i]))
+                    continue
+                descs.extend(self._dispatch_leaf(i, x, transport, kops))
 
         # -- stage 2: D2H descriptor queues (burst-batched device_get) --------
         bursts = self._balance(descs, max(1, min(self.num_queues,
@@ -335,7 +340,10 @@ class StagingEngine:
         crashpoint("mid_pipeline_chunk")
 
         def fetch(burst):
-            got = jax.device_get([d.dev for d in burst])
+            dev = [d.dev for d in burst]
+            with span("staging.d2h", bytes=sum(
+                    a.nbytes for a in jax.tree.leaves(dev))):
+                got = jax.device_get(dev)
             for d, h in zip(burst, got):
                 d.host = h
         if len(bursts) <= 1:
@@ -348,12 +356,13 @@ class StagingEngine:
         by_leaf: dict[int, list[_Descriptor]] = {}
         for d in descs:
             by_leaf.setdefault(d.leaf, []).append(d)
-        for i, ds in by_leaf.items():
-            path, x = flat_p[i]
-            host = self._assemble(x, sorted(ds, key=lambda d: d.chunk))
-            host_flat[i] = host
-            memo_puts.append((jax.tree_util.keystr(path), x, host,
-                              digests[i]))
+        with span("staging.assemble"):
+            for i, ds in by_leaf.items():
+                path, x = flat_p[i]
+                host = self._assemble(x, sorted(ds, key=lambda d: d.chunk))
+                host_flat[i] = host
+                memo_puts.append((jax.tree_util.keystr(path), x, host,
+                                  digests[i]))
 
         # -- publish: the snapshot is complete, commit the memo updates ------
         for key, x, host, dg in memo_puts:
@@ -422,8 +431,12 @@ class StagingEngine:
 
     # -- host -> device (unpause / restore) -------------------------------------
     def restore(self, staged: Any, shardings: Any = None) -> Any:
-        if not self.pipeline:
-            return self._restore_legacy(staged, shardings)
+        with span("staging.restore"):
+            if not self.pipeline:
+                return self._restore_legacy(staged, shardings)
+            return self._restore_pipelined(staged, shardings)
+
+    def _restore_pipelined(self, staged: Any, shardings: Any) -> Any:
         from repro.kernels import ops as kops
         t0 = time.perf_counter()
         flat, treedef = jax.tree_util.tree_flatten(
@@ -441,7 +454,8 @@ class StagingEngine:
         # asynchronously, overlapping the plain bursts below (stage overlap
         # on restore mirrors the save pipeline in reverse)
         for i, x, sh in packed:
-            dev_flat[i] = self._restore_packed(x.leaf, sh, kops)
+            with span("staging.h2d", bytes=_nbytes(x)):
+                dev_flat[i] = self._restore_packed(x.leaf, sh, kops)
 
         # plain leaves: burst-batched device_put per queue
         nq = max(1, min(self.num_queues, len(plain) or 1))
@@ -450,22 +464,25 @@ class StagingEngine:
         def put(burst):
             nosh = [(i, x) for i, x, sh in burst if sh is None]
             withsh = [(i, x, sh) for i, x, sh in burst if sh is not None]
-            if nosh:
-                res = jax.device_put([x for _, x in nosh])
-                for (i, _), r in zip(nosh, res):
-                    dev_flat[i] = r
-            if withsh:
-                res = jax.device_put([x for _, x, _ in withsh],
-                                     [sh for _, _, sh in withsh])
-                for (i, _, _), r in zip(withsh, res):
-                    dev_flat[i] = r
+            with span("staging.h2d",
+                      bytes=sum(_nbytes(x) for _, x, _ in burst)):
+                if nosh:
+                    res = jax.device_put([x for _, x in nosh])
+                    for (i, _), r in zip(nosh, res):
+                        dev_flat[i] = r
+                if withsh:
+                    res = jax.device_put([x for _, x, _ in withsh],
+                                         [sh for _, _, sh in withsh])
+                    for (i, _, _), r in zip(withsh, res):
+                        dev_flat[i] = r
         if len(bursts) <= 1:
             for b in bursts:
                 put(b)
         else:
             list(self._executor().map(put, bursts))
 
-        jax.block_until_ready([d for d in dev_flat if d is not None])
+        with span("staging.ready"):
+            jax.block_until_ready([d for d in dev_flat if d is not None])
         dt = time.perf_counter() - t0
         self.last_stats = TransferStats(
             bytes_moved=sum(_nbytes(x) for x in flat),

@@ -10,6 +10,7 @@ actual I/O operations until the device is unpaused".
 """
 from __future__ import annotations
 
+import collections
 import time
 from typing import Optional
 
@@ -46,7 +47,8 @@ class Tenant:
         self._seq = seq_len
         self._source = SyntheticSource(self.run, batch_override=local_batch,
                                        seq_override=seq_len)
-        self.step_times: list[float] = []
+        #: the last 64 step times (the straggler monitor reads the last)
+        self.step_times: collections.deque = collections.deque(maxlen=64)
         self._fail_next = False        # fault-injection hook (tests)
 
     # ------------------------------------------------------------------ utils

@@ -130,5 +130,6 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset=0,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Sp, H * hd), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(jnp.asarray(q_offset, jnp.int32).reshape((1,)), q2, k2, v2)
     return out[:, :S].reshape(B, S, H, hd)

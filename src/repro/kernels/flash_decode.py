@@ -128,6 +128,7 @@ def flash_decode(q, k, v, pos, *, block_k: int = 256,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         interpret=interpret,
+        name="flash_decode",
     )(pos_arr, q.reshape(B, H, hd), k.reshape(B, T, K * hd),
       v.reshape(B, T, K * hd))
     return out.reshape(B, 1, H, hd)

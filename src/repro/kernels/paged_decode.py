@@ -64,6 +64,7 @@ def _paged_call(q, k_pages, v_pages, scales, tables, pos, interpret):
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(jnp.asarray(tables, jnp.int32),
       jnp.asarray(pos, jnp.int32).reshape((B,)),
       q.reshape(B, H, hd), *args)

@@ -51,9 +51,9 @@ def _as2d(x):
 
 
 def qdma_pack(x, *, block: int = 256, rows_per_tile: int = 256,
-              interpret: bool = False):
+              interpret: bool = False, name: str = "qdma_pack"):
     """x: any shape with shape[-1] % block == 0. Returns (q, scale) shaped
-    like ref.qdma_pack_ref."""
+    like ref.qdma_pack_ref. ``name`` is the kernel's name in a trace."""
     shape = x.shape
     x2 = _as2d(x)
     M, L = x2.shape
@@ -71,6 +71,7 @@ def qdma_pack(x, *, block: int = 256, rows_per_tile: int = 256,
         out_shape=[jax.ShapeDtypeStruct((M, L), jnp.int8),
                    jax.ShapeDtypeStruct((M, L // block), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(x2)
     return (q.reshape(shape),
             scale.reshape(shape[:-1] + (L // block,)))
@@ -86,7 +87,7 @@ def qdma_pack_rows(x, lo, *, rows: int, block: int = 256,
     x2 = _as2d(x)
     chunk = jax.lax.dynamic_slice_in_dim(x2, lo, rows, axis=0)
     return qdma_pack(chunk, block=block, rows_per_tile=rows_per_tile,
-                     interpret=interpret)
+                     interpret=interpret, name="qdma_pack_rows")
 
 
 def _digest_kernel(v_ref, out_ref, *, lanes: int):
@@ -140,6 +141,7 @@ def qdma_digest(x, *, rows_per_tile: int = 512, lanes: int = 128,
         out_specs=pl.BlockSpec((1, 2, lanes), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((grid[0], 2, lanes), jnp.int32),
         interpret=interpret,
+        name="qdma_digest",
     )(v)
     return jnp.sum(jax.lax.bitcast_convert_type(parts, jnp.uint32),
                    axis=(0, 2), dtype=jnp.uint32)
@@ -165,5 +167,6 @@ def qdma_unpack(q, scale, *, dtype="float32", rows_per_tile: int = 256,
         out_specs=pl.BlockSpec((rows, L), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, L), jnp.dtype(dtype)),
         interpret=interpret,
+        name="qdma_unpack",
     )(q2, s2)
     return x.reshape(shape)

@@ -275,5 +275,6 @@ def fused_sample(logits, temp, top_k, keys, *, vocab_size: int,
                         pltpu.VMEM((B, 1), jnp.int32)],
         out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
         interpret=interpret,
+        name="fused_sample",
     )(base[:, None], noisy.astype(jnp.int32)[:, None], z)
     return out[:, 0]

@@ -111,5 +111,6 @@ def ssm_scan(xdt, Bv, Cv, log_a, *, chunk: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((H, hd, N), jnp.float32)],
         interpret=interpret,
+        name="ssm_scan",
     )(xdt.reshape(B, S, H * hd), Bv, Cv, log_a)
     return y.reshape(B, S, H, hd), hfinal

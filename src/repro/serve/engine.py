@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.configs.base import RunConfig
 from repro.models.model import Model, build_model
+from repro.runtime.spans import span
 from repro.serve.paged import (BlockAllocator, CacheExhausted,
                                RequestRejected, admit_kv, apply_page_moves,
                                copy_page, extract_kv, init_paged_cache,
@@ -126,7 +127,11 @@ class ServeEngine:
         self._migrating: dict[int, int] = {}
         #: cache-pressure / sharing counters, pumped into the MetricsBus
         #: by ServeFleet so the autoscaler sees cache pressure, not just
-        #: queue depth. Cumulative over the engine's lifetime.
+        #: queue depth; and the host nanoseconds spent in each part of a
+        #: step (``step_ns``, ``admit_ns``, ``prefill_ns``, ``place_ns``,
+        #: ``prepare_ns``, ``decode_ns``, ``readback_ns``, ``bookkeep_ns``:
+        #: the ``engine.*`` spans, nested ones counted in their parents
+        #: too). Cumulative over the engine's lifetime.
         self.stats = collections.Counter()
         # per-step dirty set: which export_state keys changed since the
         # last export. Informational for drivers (and asserted in tests);
@@ -322,16 +327,20 @@ class ServeEngine:
         (request entered the decode batch), False if it finished at
         prefill (slot stays free — nothing was written into it)."""
         plen = len(req.prompt)
-        batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None]}
-        cfg = self.run.model
-        if cfg.frontend.kind == "vision":
-            batch["patches"] = jnp.zeros(
-                (1, cfg.frontend.num_patches, cfg.d_model), jnp.bfloat16)
-        if cfg.is_encoder_decoder:
-            Te = max(1, plen // cfg.frontend.frame_ratio)
-            batch["frames"] = jnp.zeros((1, Te, cfg.d_model), jnp.bfloat16)
-        req_cache, last_logits = self._prefill(self.params, batch)
-        tok = self._emit(req, np.asarray(last_logits[0]))
+        with span("engine.prefill", self.stats, rid=req.rid, plen=plen):
+            batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None]}
+            cfg = self.run.model
+            if cfg.frontend.kind == "vision":
+                batch["patches"] = jnp.zeros(
+                    (1, cfg.frontend.num_patches, cfg.d_model), jnp.bfloat16)
+            if cfg.is_encoder_decoder:
+                Te = max(1, plen // cfg.frontend.frame_ratio)
+                batch["frames"] = jnp.zeros((1, Te, cfg.d_model),
+                                            jnp.bfloat16)
+            req_cache, last_logits = self._prefill(self.params, batch)
+            with span("engine.readback", self.stats, rid=req.rid):
+                row = np.asarray(last_logits[0])
+            tok = self._emit(req, row)
         if req.done:
             if pages is not None:
                 self.alloc.free(req.rid)
@@ -359,23 +368,27 @@ class ServeEngine:
         slot, job = next(iter(self._jobs.items()))
         C = self.prefill_chunk
         req = job.req
-        real = min(C, job.plen - job.offset)
-        chunk = np.zeros((C,), np.int32)
-        chunk[:real] = np.asarray(req.prompt[job.offset:job.offset + real],
-                                  np.int32)
-        job.cache, logits = self._chunk(self.params, job.cache,
-                                        jnp.asarray(chunk)[None],
-                                        jnp.int32(job.offset))
-        job.offset += real
-        if job.offset < job.plen:
-            return
-        del self._jobs[slot]
-        tok = self._emit(req, np.asarray(logits[0, real - 1]))
-        if req.done:                         # finished at prefill
-            if job.pages is not None:
-                self.alloc.free(req.rid)
-            return
-        req_cache = self._slice_kv(job.cache, job.plen)
+        with span("engine.prefill", self.stats, rid=req.rid, plen=job.plen,
+                  offset=job.offset):
+            real = min(C, job.plen - job.offset)
+            chunk = np.zeros((C,), np.int32)
+            chunk[:real] = np.asarray(
+                req.prompt[job.offset:job.offset + real], np.int32)
+            job.cache, logits = self._chunk(self.params, job.cache,
+                                            jnp.asarray(chunk)[None],
+                                            jnp.int32(job.offset))
+            job.offset += real
+            if job.offset < job.plen:
+                return
+            del self._jobs[slot]
+            with span("engine.readback", self.stats, rid=req.rid):
+                row = np.asarray(logits[0, real - 1])
+            tok = self._emit(req, row)
+            if req.done:                         # finished at prefill
+                if job.pages is not None:
+                    self.alloc.free(req.rid)
+                return
+            req_cache = self._slice_kv(job.cache, job.plen)
         self._place(slot, req, req_cache, job.plen, job.pages)
         self.last_token[slot] = tok
 
@@ -391,23 +404,25 @@ class ServeEngine:
         """Copy-on-admit: move a prefilled request's cache into the batch
         (paged: into its allocated pages, skipping the trie-shared chain
         head; dense: into its slot ring)."""
-        if self.paged:
-            shared = self.alloc.shared_count(req.rid)
-            self.stats["shared_page_hits"] += shared
-            self._cache = admit_kv(self._cache, req_cache, pages,
-                                   self.page_size, slot,
-                                   skip_pages=shared)
-            row = self.tables[slot]
-            row[:] = 0
-            row[:len(pages)] = pages
-            self._dirty.add("tables")
-            # offer this prompt's pages for sharing only now that their
-            # bytes are written (registration at allocate time would let
-            # a sibling map onto a still-unwritten chunked prefill)
-            if self.share_prefix:
-                self.alloc.register_prefix(req.rid)
-        else:
-            self._insert(slot, req_cache)
+        with span("engine.place", self.stats, rid=req.rid):
+            if self.paged:
+                shared = self.alloc.shared_count(req.rid)
+                self.stats["shared_page_hits"] += shared
+                self._cache = admit_kv(self._cache, req_cache, pages,
+                                       self.page_size, slot,
+                                       skip_pages=shared)
+                row = self.tables[slot]
+                row[:] = 0
+                row[:len(pages)] = pages
+                self._dirty.add("tables")
+                # offer this prompt's pages for sharing only now that
+                # their bytes are written (registration at allocate time
+                # would let a sibling map onto a still-unwritten chunked
+                # prefill)
+                if self.share_prefix:
+                    self.alloc.register_prefix(req.rid)
+            else:
+                self._insert(slot, req_cache)
         self.active[slot] = req
         self.pos[slot] = logical_len - 1
         self._dirty |= {"cache", "pos", "last_token"}
@@ -462,10 +477,16 @@ class ServeEngine:
         """One engine iteration: admit + one prefill chunk + one batched
         decode over the ACTIVE slots (inactive slots are masked out: their
         cache bytes stay untouched and they add no attention work).
-        Returns number of active slots (0 = idle). No-op while paused."""
+        Returns number of active slots (0 = idle). No-op while paused.
+        Each part is an ``engine.*`` span (``runtime/spans``)."""
         if self.paused:
             return 0
-        self._admit()
+        with span("engine.step", self.stats):
+            return self._step()
+
+    def _step(self) -> int:
+        with span("engine.admit", self.stats):
+            self._admit()
         self._advance_prefill()
         frozen = set(self._migrating.values())
         if frozen:
@@ -478,60 +499,63 @@ class ServeEngine:
                if self.active[s] is not None and s not in frozen]
         if not act:
             return 0
-        self._ensure_cache()
-        if self.paged:
-            # the decode kernel writes each slot's new KV row through its
-            # block table, so every write target must be private and
-            # allocated BEFORE the batched call: lazily grow the chain
-            # (prompt pages were all admission reserved) and CoW-split
-            # shared pages; a slot the pool cannot serve is preempted
-            act = [s for s in act if self._ensure_writable(s)]
-            if not act:
-                return 0
-        act_mask = np.zeros((self.slots,), bool)
-        act_mask[act] = True
-        pos_new = np.where(act_mask, self.pos + 1, -1).astype(np.int32)
-        tokens = jnp.asarray(np.where(act_mask, self.last_token, 0),
-                             jnp.int32)[:, None]
-        args = [self.params, self._cache, tokens, jnp.asarray(pos_new)]
-        if self.paged:
-            W = self._table_width(pos_new)
-            args.append(jnp.asarray(self.tables[:, :W]))
-        args.append(jnp.asarray(act_mask))
-        if self.fused_sampling:
-            # per-slot sampling params ride into the jitted step; only
-            # (slots,) int32 token ids come back — the (B, V) logits
-            # never leave the device
-            temp = np.zeros((self.slots,), np.float32)
-            topk = np.zeros((self.slots,), np.int32)
-            keys = np.zeros((self.slots, 3), np.int32)
+        with span("engine.prepare", self.stats):
+            self._ensure_cache()
+            if self.paged:
+                # the decode kernel writes each slot's new KV row through
+                # its block table, so every write target must be private
+                # and allocated BEFORE the batched call: lazily grow the
+                # chain (prompt pages were all admission reserved) and
+                # CoW-split shared pages; a slot the pool cannot serve is
+                # preempted
+                act = [s for s in act if self._ensure_writable(s)]
+                if not act:
+                    return 0
+            act_mask = np.zeros((self.slots,), bool)
+            act_mask[act] = True
+            pos_new = np.where(act_mask, self.pos + 1, -1).astype(np.int32)
+            tokens = jnp.asarray(np.where(act_mask, self.last_token, 0),
+                                 jnp.int32)[:, None]
+            args = [self.params, self._cache, tokens, jnp.asarray(pos_new)]
+            W = 0
+            if self.paged:
+                W = self._table_width(pos_new)
+                args.append(jnp.asarray(self.tables[:, :W]))
+            args.append(jnp.asarray(act_mask))
+            if self.fused_sampling:
+                # per-slot sampling params ride into the jitted step; only
+                # (slots,) int32 token ids come back — the (B, V) logits
+                # never leave the device
+                temp = np.zeros((self.slots,), np.float32)
+                topk = np.zeros((self.slots,), np.int32)
+                keys = np.zeros((self.slots, 3), np.int32)
+                for s in act:
+                    req = self.active[s]
+                    temp[s] = np.float32(req.temperature)
+                    topk[s] = req.top_k
+                    keys[s] = (req.seed, req.rid, len(req.out))
+                args += [jnp.asarray(temp), jnp.asarray(topk),
+                         jnp.asarray(keys)]
+        with span("engine.decode", self.stats, slots=len(act), width=W):
+            out, self._cache = self._decode(*args)
+        with span("engine.readback", self.stats):
+            out = np.asarray(out)     # token ids (fused) or logits
+        with span("engine.bookkeep", self.stats):
+            self._dirty |= {"cache", "pos", "last_token"}
             for s in act:
                 req = self.active[s]
-                temp[s] = np.float32(req.temperature)
-                topk[s] = req.top_k
-                keys[s] = (req.seed, req.rid, len(req.out))
-            toks, self._cache = self._decode(
-                *args, jnp.asarray(temp), jnp.asarray(topk),
-                jnp.asarray(keys))
-            sampled = np.asarray(toks)
-        else:
-            logits, self._cache = self._decode(*args)
-            lg = np.asarray(logits)
-        self._dirty |= {"cache", "pos", "last_token"}
-        for s in act:
-            req = self.active[s]
-            self.pos[s] += 1
-            if self.fused_sampling:
-                tok = self._finish_token(req, int(sampled[s]))
-            else:
-                tok = self._emit(req, lg[s])
-            self.last_token[s] = tok
-            if not req.done and self.pos[s] + 1 >= self.max_len:
-                req.done = True
-                self._finished.append(req)
-            if req.done:
-                self.active[s] = None
-                self._reset_slot(s, rid=req.rid)
+                self.pos[s] += 1
+                if self.fused_sampling:
+                    tok = self._finish_token(req, int(out[s]))
+                else:
+                    tok = self._emit(req, out[s])
+                self.last_token[s] = tok
+                if not req.done and self.pos[s] + 1 >= self.max_len:
+                    req.done = True
+                    self._finished.append(req)
+                if req.done:
+                    self.active[s] = None
+                    self._reset_slot(s, rid=req.rid)
         return len(act)
 
     def _ensure_writable(self, slot: int) -> bool:

@@ -51,6 +51,7 @@ from repro.core.manager import ManagerError, SVFFManager
 from repro.core.pool import DevicePool
 from repro.core.tenant import DevicePausedError
 from repro.core.vf import VFState, VirtualFunction
+from repro.runtime.spans import span
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.paged import CacheExhausted, RequestRejected
 from repro.serve.telemetry import MetricsBus
@@ -436,6 +437,10 @@ class ServeFleet:
     def step(self) -> int:
         """One fleet iteration: every RUNNING engine advances one step.
         Paused engines hold their queues (the guest keeps its device)."""
+        with span("fleet.step"):
+            return self._step()
+
+    def _step(self) -> int:
         active = 0
         for tn in self.tenants.values():
             if tn.status == "running":

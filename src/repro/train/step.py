@@ -131,11 +131,11 @@ def make_serve_steps(run: RunConfig, rules: Optional[ShardingRules] = None):
     model = build_model(run)
 
     def prefill(params, batch):
-        with sharding_scope(rules):
+        with sharding_scope(rules), jax.named_scope("prefill"):
             return model.prefill(params, batch)
 
     def decode(params, cache, tokens, pos):
-        with sharding_scope(rules):
+        with sharding_scope(rules), jax.named_scope("decode"):
             return model.decode_step(params, cache, tokens, pos)
 
     return prefill, decode
@@ -166,25 +166,25 @@ def make_decode_step(run: RunConfig,
     if paged and fused:
         def decode(params, cache, tokens, pos, tables, active,
                    temp, topk, keys):
-            with sharding_scope(rules):
+            with sharding_scope(rules), jax.named_scope("decode"):
                 logits, cache = model.decode_step(params, cache, tokens,
                                                   pos, tables=tables,
                                                   active=active)
                 return _sample_on_device(logits, temp, topk, keys), cache
     elif paged:
         def decode(params, cache, tokens, pos, tables, active):
-            with sharding_scope(rules):
+            with sharding_scope(rules), jax.named_scope("decode"):
                 return model.decode_step(params, cache, tokens, pos,
                                          tables=tables, active=active)
     elif fused:
         def decode(params, cache, tokens, pos, active, temp, topk, keys):
-            with sharding_scope(rules):
+            with sharding_scope(rules), jax.named_scope("decode"):
                 logits, cache = model.decode_step(params, cache, tokens,
                                                   pos, active=active)
                 return _sample_on_device(logits, temp, topk, keys), cache
     else:
         def decode(params, cache, tokens, pos, active):
-            with sharding_scope(rules):
+            with sharding_scope(rules), jax.named_scope("decode"):
                 return model.decode_step(params, cache, tokens, pos,
                                          active=active)
     return decode
@@ -196,7 +196,7 @@ def make_prefill_chunk(run: RunConfig,
     model = build_model(run)
 
     def chunk(params, cache, tokens, offset):
-        with sharding_scope(rules):
+        with sharding_scope(rules), jax.named_scope("prefill_chunk"):
             return model.prefill_chunk(params, cache, tokens, offset)
 
     return chunk

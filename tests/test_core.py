@@ -67,6 +67,18 @@ def test_max_vfs_limit():
         pool.set_num_vfs(5)
 
 
+def test_tenant_step_times_keep_the_last_64():
+    """The straggler monitor reads the last step time; the tenant keeps a
+    bounded window of them, however long it runs."""
+    from repro.core import Tenant
+    tn = Tenant("vm0", make_run_config("svff-bench", "train_4k", smoke=True),
+                local_batch=2, seq_len=16)
+    for i in range(100):
+        tn.step_times.append(float(i))
+    assert len(tn.step_times) == 64
+    assert tn.step_times[-1] == 99.0 and tn.step_times[0] == 36.0
+
+
 # ---------------------------------------------------------------------------
 # multi-device behaviour (subprocess with 8 CPU devices)
 # ---------------------------------------------------------------------------
