@@ -17,6 +17,17 @@ D2H completes (jax dispatch is asynchronous), which is the double-buffering
 of the QDMA descriptor ring: the device prepares the next descriptor while
 the previous one crosses the link.
 
+Host assembly (stage 3) of a leaf split into several plain descriptors
+writes into one buffer, ``np.empty(shape, dtype)``, allocated when the
+leaf is dispatched. Each fetched chunk's raw bytes, viewed as unsigned
+integers of the leaf's item size, are copied into its row range on the
+``qdma`` thread that fetched it, right after its ``device_get``. So the
+copies, and the first touch of the fresh buffer's pages that bounds a
+single thread's copy, spread over the queues and overlap the other
+queues' D2H; each chunk is dropped once placed. A single-descriptor leaf
+keeps the fetched buffer itself; packed chunks are concatenated after
+the bursts.
+
 Transports (``transport=``):
   borrow   host-device grids (CPU backend): ``device_get`` BORROWS the
            device buffer zero-copy, so non-packed descriptors of one leaf
@@ -75,6 +86,7 @@ class TransferStats:
     skipped_bytes: int = 0      # host-repr bytes reused from the memo
     num_descriptors: int = 0
     transport: str = "borrow"
+    assembled_bytes: int = 0    # bytes stage 3 copied into host leaf buffers
 
 
 @dataclasses.dataclass
@@ -102,6 +114,16 @@ def _nbytes(x) -> int:
     return np.asarray(x).nbytes
 
 
+_UINT = {1: np.uint8, 2: np.uint16, 4: np.uint32, 8: np.uint64}
+
+
+def _raw(a: np.ndarray) -> np.ndarray:
+    """``a`` viewed as unsigned integers of its item size (bytes where
+    there is none), so that a copy moves plain integers whatever the
+    leaf's own dtype, e.g. an ``ml_dtypes`` bfloat16."""
+    return a.view(_UINT.get(a.dtype.itemsize, np.uint8))
+
+
 @dataclasses.dataclass
 class _Memo:
     ref: Any            # device array object (identity check) or None
@@ -119,6 +141,7 @@ class _Descriptor:
     packed: bool
     dev: Any = None     # device array / (q, scale) awaiting D2H
     host: Any = None    # fetched host buffer(s)
+    dest: Any = None    # the leaf's host buffer, when chunks are placed
 
 
 class StagingEngine:
@@ -286,7 +309,7 @@ class StagingEngine:
         memo = self._memo_for(tenant)
         n = len(flat_p)
         host_flat: list = [None] * n
-        logical = skipped = 0
+        logical = skipped = placed = 0
         descs: list[_Descriptor] = []
         digests: dict[int, Any] = {}    # leaf idx -> digest computed at miss
         # transactional publication: memo writes are BUFFERED here and
@@ -327,7 +350,13 @@ class StagingEngine:
                     host_flat[i] = host
                     memo_puts.append((key, x, host, digests[i]))
                     continue
-                descs.extend(self._dispatch_leaf(i, x, transport, kops))
+                ds = self._dispatch_leaf(i, x, transport, kops)
+                descs.extend(ds)
+                if ds[0].dest is not None:
+                    # filled by stage 2's placement; published with the rest
+                    host_flat[i] = ds[0].dest
+                    placed += x.nbytes
+                    memo_puts.append((key, x, host_flat[i], digests[i]))
 
         # -- stage 2: D2H descriptor queues (burst-batched device_get) --------
         bursts = self._balance(descs, max(1, min(self.num_queues,
@@ -346,23 +375,37 @@ class StagingEngine:
                 got = jax.device_get(dev)
             for d, h in zip(burst, got):
                 d.host = h
+            del got             # a placed chunk is freed once it is placed
+            # stage 3 for the placed chunks, on this queue's thread
+            mine = [d for d in burst if d.dest is not None]
+            if mine:
+                with span("staging.assemble",
+                          bytes=sum(d.host.nbytes for d in mine)):
+                    for d in mine:
+                        self._place(d)
         if len(bursts) <= 1:
             for b in bursts:
                 fetch(b)
         else:
             list(self._executor().map(fetch, bursts))
 
-        # -- stage 3: host assemble ------------------------------------------
+        # -- stage 3 for the other leaves: packed chunks, single chunks ------
         by_leaf: dict[int, list[_Descriptor]] = {}
         for d in descs:
-            by_leaf.setdefault(d.leaf, []).append(d)
-        with span("staging.assemble"):
-            for i, ds in by_leaf.items():
-                path, x = flat_p[i]
-                host = self._assemble(x, sorted(ds, key=lambda d: d.chunk))
-                host_flat[i] = host
-                memo_puts.append((jax.tree_util.keystr(path), x, host,
-                                  digests[i]))
+            if d.dest is None:
+                by_leaf.setdefault(d.leaf, []).append(d)
+        # only packed leaves are left with several chunks, each (q, scale)
+        concat = sum(a.nbytes for ds in by_leaf.values() if len(ds) > 1
+                     for d in ds for a in d.host)
+        if by_leaf:
+            with span("staging.assemble", bytes=concat):
+                for i, ds in by_leaf.items():
+                    path, x = flat_p[i]
+                    host = self._assemble(x, sorted(ds,
+                                                    key=lambda d: d.chunk))
+                    host_flat[i] = host
+                    memo_puts.append((jax.tree_util.keystr(path), x, host,
+                                      digests[i]))
 
         # -- publish: the snapshot is complete, commit the memo updates ------
         for key, x, host, dg in memo_puts:
@@ -373,7 +416,8 @@ class StagingEngine:
         self.last_stats = TransferStats(
             bytes_moved=moved, logical_bytes=logical, seconds=dt,
             num_leaves=n, queues=self.num_queues, skipped_bytes=skipped,
-            num_descriptors=len(descs), transport=transport)
+            num_descriptors=len(descs), transport=transport,
+            assembled_bytes=placed + concat)
         return jax.tree_util.tree_unflatten(treedef, [
             _Opaque(h) if isinstance(h, QuantizedLeaf) else h
             for h in host_flat])
@@ -394,13 +438,14 @@ class StagingEngine:
         chunkable = packed or transport == "stream"
         ranges = self._row_chunks(x.nbytes, R) if chunkable else [(0, R)]
         out = []
-        x2 = None
+        x2 = dest = None
         if len(ranges) > 1 and not packed:
             x2 = x.reshape(R, L)
+            dest = np.empty(x.shape, x.dtype)
         per_chunk = max(1, x.nbytes // len(ranges))
         for c, (lo, hi) in enumerate(ranges):
             d = _Descriptor(leaf=i, chunk=c, lo=lo, rows=hi - lo,
-                            nbytes=per_chunk, packed=packed)
+                            nbytes=per_chunk, packed=packed, dest=dest)
             if packed:
                 d.dev = kops.qdma_pack_rows(x, lo, rows=d.rows,
                                             block=self.block)
@@ -412,9 +457,18 @@ class StagingEngine:
             out.append(d)
         return out
 
+    @staticmethod
+    def _place(d: _Descriptor) -> None:
+        """Stage 3 of a plain chunk: copy its raw bytes into its rows of
+        the leaf's buffer (bit-exact: row-chunking commutes with reshape),
+        then drop the fetched chunk."""
+        rows = d.dest.reshape(-1, d.dest.shape[-1])
+        _raw(rows)[d.lo:d.lo + d.rows] = _raw(np.asarray(d.host))
+        d.host = None
+
     def _assemble(self, x, ds: list[_Descriptor]):
-        """Stage 3: combine a leaf's fetched descriptor chunks back into
-        one host buffer (bit-exact: row-chunking commutes with reshape)."""
+        """Stage 3 of a packed or single-descriptor leaf: combine its
+        fetched chunks into the leaf's host representation."""
         if ds[0].packed:
             q2 = np.concatenate([np.asarray(d.host[0]) for d in ds], axis=0) \
                 if len(ds) > 1 else np.asarray(ds[0].host[0])
@@ -424,10 +478,7 @@ class StagingEngine:
                 q=q2.reshape(x.shape),
                 scale=s2.reshape(x.shape[:-1] + (s2.shape[-1],)),
                 dtype=str(x.dtype), block=self.block)
-        if len(ds) == 1:
-            return np.asarray(ds[0].host)
-        rows = np.concatenate([np.asarray(d.host) for d in ds], axis=0)
-        return rows.reshape(x.shape)
+        return np.asarray(ds[0].host)
 
     # -- host -> device (unpause / restore) -------------------------------------
     def restore(self, staged: Any, shardings: Any = None) -> Any:

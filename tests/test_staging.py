@@ -106,6 +106,130 @@ def test_identity_dirty_tracking_requires_same_object():
 
 
 # ---------------------------------------------------------------------------
+# stage 3: raw-byte placement of stream chunks into one buffer per leaf
+# ---------------------------------------------------------------------------
+def _leaf(dtype, shape, seed=0):
+    """A device leaf of ``dtype`` whose bytes are random (0/1 for bool)."""
+    dt = np.dtype(dtype)
+    rng = np.random.default_rng(seed)
+    if dt == np.bool_:
+        return jnp.asarray(rng.integers(0, 2, shape).astype(np.bool_))
+    raw = rng.integers(0, 256, shape + (dt.itemsize,), dtype=np.uint8)
+    return jnp.asarray(raw.view(dt).reshape(shape))
+
+
+class _Fetches:
+    """Wraps ``jax.device_get`` to keep every buffer it hands back."""
+
+    def __init__(self, monkeypatch):
+        self.got = []
+        real = jax.device_get
+
+        def device_get(x):
+            out = real(x)
+            self.got.extend(np.asarray(h) for h in jax.tree.leaves(out))
+            return out
+        monkeypatch.setattr(jax, "device_get", device_get)
+
+
+def _u8(a):
+    return np.ascontiguousarray(np.asarray(a)).reshape(-1).view(np.uint8)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32", "int8", "bool",
+                                   "float8_e4m3fn"])
+@pytest.mark.parametrize("shape,parts", [((13, 5, 24), 7),
+                                         ((13, 5, 24), 1),
+                                         ((1, 96), 4)],
+                         ids=["uneven_rows", "single_chunk", "one_row"])
+def test_stream_save_assembles_bit_identical_leaves(monkeypatch, dtype,
+                                                    shape, parts):
+    x = _leaf(dtype, shape)
+    eng = StagingEngine(num_queues=3, transport="stream",
+                        chunk_bytes=x.nbytes // parts + 1)
+    fetches = _Fetches(monkeypatch)
+    snap = eng.save({"x": x})["x"]
+    want = np.asarray(x)
+    assert snap.dtype == want.dtype and snap.shape == want.shape
+    assert snap.flags["C_CONTIGUOUS"]
+    np.testing.assert_array_equal(_u8(snap), _u8(want))
+    chunked = len(fetches.got) > 1
+    assert chunked == (parts > 1 and shape[0] > 1)
+    assert eng.last_stats.assembled_bytes == (x.nbytes if chunked else 0)
+    if chunked:
+        # one buffer that owns its bytes, apart from every fetched chunk
+        assert sum(h.shape[0] for h in fetches.got) == want.size // shape[-1]
+        assert not any(np.shares_memory(snap, h) for h in fetches.got)
+    else:
+        (only,) = fetches.got                    # kept as fetched, no copy
+        assert np.shares_memory(snap, only) and snap.shape == shape
+    back = eng.restore({"x": snap})["x"]
+    assert back.dtype == x.dtype and back.shape == x.shape
+    np.testing.assert_array_equal(_u8(back), _u8(want))
+
+
+def _chunked_tree(seed):
+    return {"kv": _leaf("bfloat16", (64, 128), seed),      # 16 KiB: 4 chunks
+            "small": _leaf("float32", (4, 8), seed + 1)}   # one chunk
+
+
+def test_assembled_bytes_counts_chunked_plain_leaves():
+    tree = _chunked_tree(0)
+    eng = StagingEngine(num_queues=2, transport="stream", chunk_bytes=4096,
+                        incremental=True)
+    eng.save(tree, tenant="t")
+    assert eng.last_stats.num_descriptors == 5
+    assert eng.last_stats.assembled_bytes == tree["kv"].nbytes
+    eng.save(tree, tenant="t")                   # every leaf hits the memo
+    assert eng.last_stats.skipped_bytes == (tree["kv"].nbytes
+                                            + tree["small"].nbytes)
+    assert eng.last_stats.assembled_bytes == 0
+    borrow = StagingEngine(num_queues=2, transport="borrow",
+                           chunk_bytes=4096)
+    borrow.save(tree)
+    assert borrow.last_stats.assembled_bytes == 0
+
+
+def _memo_state(eng, tenant):
+    return {k: (e.host, _u8(e.host).copy())
+            for k, e in eng._memo_for(tenant).items()}
+
+
+@pytest.mark.parametrize("fault", ["crashpoint", "placement"])
+def test_failed_save_leaves_memo_and_host_copies(monkeypatch, fault):
+    from repro.core.fault import InjectedCrash, crash_plane
+    eng = StagingEngine(num_queues=2, transport="stream", chunk_bytes=4096,
+                        incremental=True)
+    eng.save(_chunked_tree(0), tenant="t")
+    before = _memo_state(eng, "t")
+    if fault == "crashpoint":
+        crash_plane.arm("mid_pipeline_chunk")
+        err = InjectedCrash
+    else:
+        # a queue thread dies after it has placed part of the leaf
+        calls = [0]
+        place = StagingEngine._place
+
+        def flaky(d):
+            calls[0] += 1
+            if calls[0] == 3:
+                raise RuntimeError("host copy failed")
+            place(d)
+        monkeypatch.setattr(StagingEngine, "_place", staticmethod(flaky))
+        err = RuntimeError
+    try:
+        with pytest.raises(err):
+            eng.save(_chunked_tree(7), tenant="t")
+    finally:
+        crash_plane.disarm()
+    after = _memo_state(eng, "t")
+    assert eng.memo_size("t") == 2 and after.keys() == before.keys()
+    for k, (host, raw) in before.items():
+        assert after[k][0] is host
+        np.testing.assert_array_equal(after[k][1], raw)
+
+
+# ---------------------------------------------------------------------------
 # live pause (unit level; the sim covers it op-by-op)
 # ---------------------------------------------------------------------------
 def _attached_vf(tid, vid="0000:0a:00.1"):
