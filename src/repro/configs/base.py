@@ -31,10 +31,21 @@ VALID_BLOCKS = (ATTN, MAMBA, MLSTM, SLSTM)
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int               # the router's width (all experts)
     num_experts_per_token: int
     d_ff: int                      # per-expert hidden dim
     capacity_factor: float = 1.25
+    # dropless: every routed (token, held expert) pair is computed, grouped
+    # by expert (no capacity, no one-hot dispatch); a token's output then
+    # depends on that token alone, however it is batched or chunked
+    dropless: bool = False
+    # renormalise the top-k gate values to sum to one
+    norm_topk_prob: bool = True
+    # expert parallelism: this chip holds experts
+    # [held_offset, held_offset + experts_held) of num_experts (0: all) and
+    # computes their part of the layer; the absent experts add nothing
+    experts_held: int = 0
+    held_offset: int = 0
     # layers whose (global) index satisfies index % every == offset get MoE
     every: int = 1
     offset: int = 0
@@ -42,6 +53,42 @@ class MoEConfig:
     dense_residual: bool = False
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
+
+    def __post_init__(self):
+        if self.experts_held == 0:
+            object.__setattr__(self, "experts_held", self.num_experts)
+        assert 0 <= self.held_offset and (
+            self.held_offset + self.experts_held <= self.num_experts), (
+            f"held experts [{self.held_offset}, "
+            f"{self.held_offset + self.experts_held}) outside "
+            f"{self.num_experts}")
+        assert self.experts_held == self.num_experts or self.dropless, (
+            "an expert share needs the dropless layer")
+
+
+@dataclass(frozen=True)
+class RopeConfig:
+    """Rotary embedding of one attention kind: plain RoPE at ``theta``, or
+    YaRN (NTK-by-parts) when ``yarn_factor`` > 0: frequencies ramp from
+    extrapolated (high) to interpolated by ``yarn_factor`` (low) between
+    ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max_position``, and cos/sin are scaled by
+    ``attention_factor``."""
+    theta: float = 10000.0
+    yarn_factor: float = 0.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
+class AttnKind:
+    """One position of the block pattern's attention: ``window`` 0 is full
+    causal attention; ``window`` W > 0 lets position p attend to
+    [p - W + 1, p]. ``rope`` None uses the model's ``rope_theta``."""
+    window: int = 0
+    rope: Optional[RopeConfig] = None
 
 
 @dataclass(frozen=True)
@@ -91,6 +138,9 @@ class ModelConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     block_pattern: tuple = (ATTN,) # repeats to cover num_layers
+    # one AttnKind per pattern position (empty: every attention block is
+    # full causal with plain RoPE at rope_theta)
+    attn_kinds: tuple = ()
     moe: Optional[MoEConfig] = None
     ssm: SSMConfig = SSMConfig()
     xlstm: XLSTMConfig = XLSTMConfig()
@@ -108,6 +158,9 @@ class ModelConfig:
             f"num_layers {self.num_layers}")
         for b in self.block_pattern:
             assert b in VALID_BLOCKS, b
+        assert not self.attn_kinds or (
+            len(self.attn_kinds) == len(self.block_pattern)), (
+            f"{self.name}: one attention kind per pattern position")
         if self.num_heads and self.num_kv_heads:
             assert self.num_heads % self.num_kv_heads == 0
 
@@ -128,6 +181,20 @@ class ModelConfig:
 
     def layer_kind(self, i: int) -> str:
         return self.block_pattern[i % len(self.block_pattern)]
+
+    def attn_kind(self, j: int) -> AttnKind:
+        """The attention kind of pattern position ``j``, with its RoPE."""
+        kind = self.attn_kinds[j] if self.attn_kinds else AttnKind()
+        if kind.rope is None:
+            kind = dataclasses.replace(kind,
+                                       rope=RopeConfig(theta=self.rope_theta))
+        return kind
+
+    @property
+    def windows(self) -> dict:
+        """{"block<j>": window} of the pattern's sliding-window blocks."""
+        return {f"block{j}": k.window for j, k in enumerate(self.attn_kinds)
+                if k.window > 0}
 
     def layer_has_moe(self, i: int) -> bool:
         if self.moe is None:
@@ -307,7 +374,8 @@ def _ensure_imported():
     from repro.configs import (arctic_480b, olmoe_1b_7b, qwen3_0_6b,  # noqa
                                llama3_8b, deepseek_67b, phi3_mini_3_8b,
                                seamless_m4t_medium, xlstm_350m,
-                               jamba_1_5_large_398b, internvl2_1b, paper)
+                               jamba_1_5_large_398b, internvl2_1b,
+                               mellum2_12b_a2_5b, paper)
 
 
 def list_archs() -> list[str]:

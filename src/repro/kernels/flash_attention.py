@@ -14,6 +14,14 @@ fully-masked blocks are skipped via pl.when. ``q_offset`` (a prefetched
 scalar, so it may be traced) is the absolute position of q's first row:
 a chunked-prefill continuation attends its chunk against everything
 cached before it.
+
+Sliding window (``window`` W > 0): row p attends keys [p - W + 1, p]. The
+keys then carry their own absolute position, a second prefetched scalar
+``k_offset`` (the position of k[:, 0]; a window-sized ring view of the
+cache starts before the chunk), keys at negative positions are masked,
+and key blocks wholly before a query block's window are skipped, their
+index repeating the first block needed so that no DMA is issued for them.
+With W = 0 the kernel is the plain causal one.
 """
 from __future__ import annotations
 
@@ -30,7 +38,7 @@ NEG_INF = -1e30
 
 def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             causal: bool, scale: float, block_q: int, block_k: int,
-            kv_heads: int, hd: int, kv_len: int):
+            kv_heads: int, hd: int, kv_len: int, window: int = 0):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -50,10 +58,15 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         kpos = k_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         valid = kpos < kv_len
+        if window:
+            kpos = kpos + off_ref[1]
+            valid = valid & (kpos >= 0)
         if causal:
             qpos = q_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             valid = valid & (kpos <= qpos)
+        if window:
+            valid = valid & (kpos > qpos - window)
         for g in range(kv_heads):
             k = k_ref[0, :, g * hd:(g + 1) * hd].astype(jnp.float32)
             v = v_ref[0, :, g * hd:(g + 1) * hd].astype(jnp.float32)
@@ -73,7 +86,12 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                     p, v, preferred_element_type=jnp.float32)
                 m_scr[:, h:h + 1] = m_new
 
-    if causal:
+    if window:
+        # skip blocks entirely above the diagonal or before the window
+        k0 = off_ref[1] + k_start
+        pl.when((k0 <= q_start + block_q - 1)
+                & (k0 + block_k - 1 > q_start - window))(compute)
+    elif causal:
         # skip blocks entirely above the diagonal
         pl.when(k_start <= q_start + block_q - 1)(compute)
     else:
@@ -97,9 +115,12 @@ def _tile(n: int, block: int) -> tuple[int, int]:
 
 def flash_attention(q, k, v, *, causal: bool = True, q_offset=0,
                     block_q: int = 128, block_k: int = 128,
+                    window: int = 0, k_offset=0,
                     interpret: bool = False):
     """q: (B,S,H,hd); k,v: (B,T,K,hd); H % K == 0. Returns (B,S,H,hd).
-    q_offset: absolute position of q[:, 0] (int or traced scalar)."""
+    q_offset: absolute position of q[:, 0] (int or traced scalar).
+    window > 0 (causal only): a sliding window of that many positions;
+    k_offset is then the absolute position of k[:, 0]."""
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     block_q, Sp = _tile(S, block_q)
@@ -110,9 +131,20 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset=0,
     kern = functools.partial(_kernel, causal=causal,
                              scale=1.0 / math.sqrt(hd),
                              block_q=block_q, block_k=block_k,
-                             kv_heads=K, hd=hd, kv_len=T)
+                             kv_heads=K, hd=hd, kv_len=T, window=window)
     kv_spec = pl.BlockSpec((1, block_k, K * hd),
                            lambda b, qi, ki, off: (b, ki, 0))
+    offsets = jnp.asarray(q_offset, jnp.int32).reshape((1,))
+    if window:
+        assert causal, "a sliding window is causal"
+
+        def kv_block(b, qi, ki, off):
+            # the first key block any row of this query block can see
+            lo = off[0] + qi * block_q - window + 1 - off[1]
+            return (b, jnp.maximum(ki, jnp.maximum(lo, 0) // block_k), 0)
+        kv_spec = pl.BlockSpec((1, block_k, K * hd), kv_block)
+        offsets = jnp.concatenate(
+            [offsets, jnp.asarray(k_offset, jnp.int32).reshape((1,))])
     q_spec = pl.BlockSpec((1, block_q, H * hd),
                           lambda b, qi, ki, off: (b, qi, 0))
     out = pl.pallas_call(
@@ -131,5 +163,5 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset=0,
         out_shape=jax.ShapeDtypeStruct((B, Sp, H * hd), q.dtype),
         interpret=interpret,
         name="flash_attention",
-    )(jnp.asarray(q_offset, jnp.int32).reshape((1,)), q2, k2, v2)
+    )(offsets, q2, k2, v2)
     return out[:, :S].reshape(B, S, H, hd)

@@ -13,7 +13,10 @@ block are either the whole array's or (tile rows, K*hd) — the shapes the
 TPU compiler accepts. The kernel loops over the K kv-heads and attends
 the G = H/K query heads of each one with a (G, hd) x (hd, tile) matmul.
 ``decode_kernel`` is shared with ``paged_decode``, which only changes
-where a tile comes from.
+where a tile comes from. With a sliding ``window`` W > 0 a row attends
+only [pos - W + 1, pos]: tiles wholly before that are skipped too, or,
+with ``from_window``, the grid's tile axis starts at the window's first
+tile, so that it spans the window and not the whole cache.
 """
 from __future__ import annotations
 
@@ -29,7 +32,8 @@ NEG_INF = -1e30
 
 
 def decode_kernel(*refs, scale: float, kv_heads: int, block: int,
-                  paged: bool, quant: bool):
+                  paged: bool, quant: bool, window: int = 0,
+                  from_window: bool = False):
     """One (batch row, KV tile) grid step of single-token decode.
 
     refs: [tables] pos (B,) q k v [k_scale v_scale] out m l acc. q block
@@ -46,6 +50,8 @@ def decode_kernel(*refs, scale: float, kv_heads: int, block: int,
         o_ref, m_scr, l_scr, acc_scr = rest
     ti = pl.program_id(1)
     start = ti * block
+    if from_window:
+        start = start + jnp.maximum(pos - window + 1, 0) // block * block
 
     @pl.when(ti == 0)
     def _init():
@@ -53,13 +59,19 @@ def decode_kernel(*refs, scale: float, kv_heads: int, block: int,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(start <= pos)
+    live = start <= pos
+    if window:
+        live = live & (start + block > pos - window + 1)
+
+    @pl.when(live)
     def _compute():
         H, hd = q_ref.shape[1], q_ref.shape[2]
         G = H // kv_heads
         q_all = q_ref[0].astype(jnp.float32) * scale          # (H, hd)
         kpos = start + jax.lax.broadcasted_iota(jnp.int32, (G, block), 1)
         valid = kpos <= pos
+        if window:
+            valid = valid & (kpos > pos - window)
         for g in range(kv_heads):
             rows = slice(g * G, (g + 1) * G)
             lanes = slice(g * hd, (g + 1) * hd)
@@ -95,10 +107,11 @@ def decode_scratch(H: int, hd: int) -> list:
             pltpu.VMEM((H, hd), jnp.float32)]
 
 
-def flash_decode(q, k, v, pos, *, block_k: int = 256,
+def flash_decode(q, k, v, pos, *, block_k: int = 256, window: int = 0,
                  interpret: bool = False):
     """q: (B,1,H,hd); k,v: (B,T,K,hd); pos: int32 scalar or (B,) per-row
-    (attend <= pos; a row with pos < 0 attends nothing and is zero)."""
+    (attend <= pos; a row with pos < 0 attends nothing and is zero);
+    window > 0: attend only (pos - window, pos]."""
     B, _, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     if T > block_k and T % block_k:
@@ -108,7 +121,7 @@ def flash_decode(q, k, v, pos, *, block_k: int = 256,
     block_k = min(block_k, T)
     kern = functools.partial(decode_kernel, scale=1.0 / math.sqrt(hd),
                              kv_heads=K, block=block_k, paged=False,
-                             quant=False)
+                             quant=False, window=window)
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     kv_spec = pl.BlockSpec((1, block_k, K * hd),
                            lambda b, ki, pos_ref: (b, ki, 0))

@@ -17,6 +17,7 @@ import jax
 from repro.kernels import ref as _ref
 from repro.kernels import flash_attention as _fa
 from repro.kernels import flash_decode as _fd
+from repro.kernels import grouped_ffn as _gf
 from repro.kernels import paged_decode as _pd
 from repro.kernels import qdma_pack as _qp
 from repro.kernels import sampling as _sp
@@ -32,33 +33,52 @@ def _kernel(backend: str, interpret: bool) -> bool:
     return backend == "pallas"
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "interpret",
-                                             "backend"))
-def flash_attention(q, k, v, q_offset=0, *, causal: bool = True,
-                    interpret: bool = False, backend: str = "auto"):
+@functools.partial(jax.jit, static_argnames=("causal", "window",
+                                             "interpret", "backend"))
+def flash_attention(q, k, v, q_offset=0, k_offset=0, *, causal: bool = True,
+                    window: int = 0, interpret: bool = False,
+                    backend: str = "auto"):
+    """``window`` > 0: sliding-window attention, k[:, 0] at absolute
+    position ``k_offset``."""
     if not _kernel(backend, interpret):
         return _ref.flash_attention_ref(q, k, v, causal=causal,
-                                        q_offset=q_offset)
+                                        q_offset=q_offset, window=window,
+                                        k_offset=k_offset)
     return _fa.flash_attention(q, k, v, causal=causal, q_offset=q_offset,
+                               window=window, k_offset=k_offset,
                                interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "backend"))
-def flash_decode(q, k, v, pos, *, interpret: bool = False,
+@functools.partial(jax.jit, static_argnames=("window", "interpret",
+                                             "backend"))
+def flash_decode(q, k, v, pos, *, window: int = 0, interpret: bool = False,
                  backend: str = "auto"):
     if not _kernel(backend, interpret):
-        return _ref.flash_decode_ref(q, k, v, pos)
-    return _fd.flash_decode(q, k, v, pos, interpret=interpret)
+        return _ref.flash_decode_ref(q, k, v, pos, window=window)
+    return _fd.flash_decode(q, k, v, pos, window=window, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "interpret",
+                                             "backend"))
+def paged_decode(q, k_pages, v_pages, tables, pos, *, window: int = 0,
+                 interpret: bool = False, backend: str = "auto"):
+    """Block-table-indirected decode over the paged KV pool (serve plane);
+    ``window`` > 0 reads only the pages of each row's window."""
+    if not _kernel(backend, interpret):
+        return _ref.paged_decode_ref(q, k_pages, v_pages, tables, pos,
+                                     window=window)
+    return _pd.paged_decode(q, k_pages, v_pages, tables, pos, window=window,
+                            interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "backend"))
-def paged_decode(q, k_pages, v_pages, tables, pos, *,
-                 interpret: bool = False, backend: str = "auto"):
-    """Block-table-indirected decode over the paged KV pool (serve plane)."""
+def grouped_ffn(x, wg, wi, wo, group_sizes, *, interpret: bool = False,
+                backend: str = "auto"):
+    """Dropless expert FFN over rows sorted by expert: (M, D) float32,
+    rows past sum(group_sizes) undefined (kernel) or zero (oracle)."""
     if not _kernel(backend, interpret):
-        return _ref.paged_decode_ref(q, k_pages, v_pages, tables, pos)
-    return _pd.paged_decode(q, k_pages, v_pages, tables, pos,
-                            interpret=interpret)
+        return _ref.grouped_ffn_ref(x, wg, wi, wo, group_sizes)
+    return _gf.grouped_ffn(x, wg, wi, wo, group_sizes, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "backend"))

@@ -16,6 +16,13 @@ slot) skips every tile and yields an exactly-zero output row.
 A block is one whole page, all K kv-heads wide: the pool is viewed as
 (P, page, K*hd), and the kernel body (``flash_decode.decode_kernel``)
 loops over the kv-heads.
+
+A sliding ``window`` W > 0 bounds each row's reads from below as well:
+the grid's page axis spans the (W - 1) // page + 2 pages a window can
+touch, starting at the page of ``pos - W + 1``, so a windowed layer costs
+the same grid steps and reads whatever the context length. Its table may
+then name a per-slot ring of pages (``serve.paged``): only the pages of
+the window are ever read.
 """
 from __future__ import annotations
 
@@ -30,17 +37,22 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.flash_decode import decode_kernel, decode_scratch
 
 
-def _paged_call(q, k_pages, v_pages, scales, tables, pos, interpret):
+def _paged_call(q, k_pages, v_pages, scales, tables, pos, interpret,
+                window=0):
     B, _, H, hd = q.shape
     P, page, K, _ = k_pages.shape
     NP = tables.shape[1]
     quant = scales is not None
     kern = functools.partial(decode_kernel, scale=1.0 / math.sqrt(hd),
                              kv_heads=K, block=page, paged=True,
-                             quant=quant)
+                             quant=quant, window=window,
+                             from_window=bool(window))
+    steps = min(NP, (window - 1) // page + 2) if window else NP
 
     def page_of(b, pi, tbl_ref, pos_ref):
         last = jnp.maximum(pos_ref[b], 0) // page
+        if window:
+            pi = pi + jnp.maximum(pos_ref[b] - window + 1, 0) // page
         return tbl_ref[b, jnp.minimum(pi, last)]
 
     kv_spec = pl.BlockSpec((1, page, K * hd),
@@ -56,7 +68,7 @@ def _paged_call(q, k_pages, v_pages, scales, tables, pos, interpret):
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, NP),
+            grid=(B, steps),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, H, hd),
                                    lambda b, pi, t, p: (b, 0, 0)),
@@ -82,8 +94,10 @@ def paged_decode_quant(q, k_pages, v_pages, k_scale, v_scale, tables, pos, *,
                        interpret)
 
 
-def paged_decode(q, k_pages, v_pages, tables, pos, *,
+def paged_decode(q, k_pages, v_pages, tables, pos, *, window: int = 0,
                  interpret: bool = False):
     """q: (B,1,H,hd); k_pages,v_pages: (P,page,K,hd); tables: (B,NP) int32;
-    pos: (B,) int32 — attend to logical indices <= pos[b] (< 0: none)."""
-    return _paged_call(q, k_pages, v_pages, None, tables, pos, interpret)
+    pos: (B,) int32 — attend to logical indices <= pos[b] (< 0: none);
+    window > 0: and > pos[b] - window."""
+    return _paged_call(q, k_pages, v_pages, None, tables, pos, interpret,
+                       window)

@@ -15,10 +15,13 @@ import jax.numpy as jnp
 # ---------------------------------------------------------------------------
 # flash_attention
 # ---------------------------------------------------------------------------
-def flash_attention_ref(q, k, v, causal: bool = True, q_offset=0):
+def flash_attention_ref(q, k, v, causal: bool = True, q_offset=0,
+                        window: int = 0, k_offset=0):
     """q: (B,S,H,hd); k,v: (B,T,K,hd) with H % K == 0. fp32 softmax.
     q_offset: absolute position of q[:, 0] (causal: row s sees keys
-    t <= s + q_offset)."""
+    t <= s + q_offset). window > 0: keys at absolute positions
+    (p - window, p] only, k[:, 0] being at position k_offset (keys at
+    negative positions are masked)."""
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
@@ -26,8 +29,13 @@ def flash_attention_ref(q, k, v, causal: bool = True, q_offset=0):
     logits = jnp.einsum("bskgh,btkh->bkgst", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) / math.sqrt(hd)
     if causal:
-        mask = (jnp.arange(T)[None, :] <=
-                jnp.arange(S)[:, None] + q_offset)
+        qpos = jnp.arange(S)[:, None] + q_offset
+        kpos = jnp.arange(T)[None, :]
+        if window:
+            kpos = kpos + k_offset
+        mask = kpos <= qpos
+        if window:
+            mask = mask & (kpos > qpos - window) & (kpos >= 0)
         logits = jnp.where(mask[None, None, None], logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("bkgst,btkh->bskgh", p, v.astype(jnp.float32))
@@ -37,17 +45,21 @@ def flash_attention_ref(q, k, v, causal: bool = True, q_offset=0):
 # ---------------------------------------------------------------------------
 # flash_decode
 # ---------------------------------------------------------------------------
-def flash_decode_ref(q, k, v, pos):
+def flash_decode_ref(q, k, v, pos, window: int = 0):
     """q: (B,1,H,hd); k,v: (B,T,K,hd); attend to indices <= pos (a
-    scalar, or (B,) per row)."""
+    scalar, or (B,) per row), and > pos - window when window > 0."""
     B, _, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     G = H // K
     qg = q.reshape(B, K, G, hd)
     logits = jnp.einsum("bkgh,btkh->bkgt", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) / math.sqrt(hd)
-    valid = (jnp.arange(T)[None, :] <=
-             jnp.reshape(pos, (-1, 1)))[:, None, None, :]
+    t = jnp.arange(T)[None, :]
+    pcol = jnp.reshape(pos, (-1, 1))
+    valid = t <= pcol
+    if window:
+        valid = valid & (t > pcol - window)
+    valid = valid[:, None, None, :]
     logits = jnp.where(valid, logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1)
     o = jnp.einsum("bkgt,btkh->bkgh", p, v.astype(jnp.float32))
@@ -57,13 +69,14 @@ def flash_decode_ref(q, k, v, pos):
 # ---------------------------------------------------------------------------
 # paged_decode (flash_decode over a block-table-indirected paged KV pool)
 # ---------------------------------------------------------------------------
-def paged_decode_ref(q, k_pages, v_pages, tables, pos):
+def paged_decode_ref(q, k_pages, v_pages, tables, pos, window: int = 0):
     """q: (B,1,H,hd); k_pages/v_pages: (P,page,K,hd) — the shared page pool;
     tables: (B,NP) int32 page ids forming each sequence's logical
     (NP*page)-token view; pos: (B,) int32 — last valid logical index per
     sequence (attend to <= pos; pos < 0 means no valid tokens and the
     output row is exactly zero, matching the Pallas kernel's zero-init
-    accumulator when every tile is skipped)."""
+    accumulator when every tile is skipped); window > 0: and to
+    > pos - window."""
     B, _, H, hd = q.shape
     page, K = k_pages.shape[1], k_pages.shape[2]
     T = tables.shape[1] * page
@@ -73,13 +86,33 @@ def paged_decode_ref(q, k_pages, v_pages, tables, pos):
     qg = q.reshape(B, K, G, hd)
     logits = jnp.einsum("bkgh,btkh->bkgt", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) / math.sqrt(hd)
-    valid = (jnp.arange(T)[None, :] <= pos[:, None])[:, None, None, :]
+    t = jnp.arange(T)[None, :]
+    valid = t <= pos[:, None]
+    if window:
+        valid = valid & (t > pos[:, None] - window)
+    valid = valid[:, None, None, :]
     logits = jnp.where(valid, logits, -1e30)
     m = jnp.max(logits, axis=-1, keepdims=True)
     p = jnp.where(valid, jnp.exp(logits - m), 0.0)
     denom = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
     o = jnp.einsum("bkgt,btkh->bkgh", p / denom, v.astype(jnp.float32))
     return o.reshape(B, 1, H, hd).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# grouped_ffn (dropless expert FFN over rows grouped by expert)
+# ---------------------------------------------------------------------------
+def grouped_ffn_ref(x, wg, wi, wo, group_sizes):
+    """x: (M, D) rows sorted by group, group g owning the next
+    group_sizes[g] rows; wg/wi (G, D, F), wo (G, F, D). SwiGLU of each row
+    through its group's expert; rows past sum(group_sizes) are zero."""
+    g = jax.lax.ragged_dot(x, wg, group_sizes,
+                           preferred_element_type=jnp.float32)
+    u = jax.lax.ragged_dot(x, wi, group_sizes,
+                           preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    return jax.lax.ragged_dot(h, wo, group_sizes,
+                              preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------

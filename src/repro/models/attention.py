@@ -23,11 +23,13 @@ def _group(q, num_kv):
 
 
 def attention_ref(q, k, v, *, causal: bool, q_offset=0,
-                  kv_len=None) -> jax.Array:
+                  kv_len=None, window: int = 0, k_offset=0) -> jax.Array:
     """q: (B,Sq,H,hd); k,v: (B,T,K,hd). GQA-aware, fp32 softmax.
 
     q_offset: absolute position of q[0] (prefill continuation / decode).
     kv_len: optional valid KV length (int or scalar array) — masks t >= kv_len.
+    window > 0 (causal): keys at positions (p - window, p] only, k[0] being
+    at position k_offset; negative positions are masked.
     """
     B, Sq, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
@@ -38,7 +40,11 @@ def attention_ref(q, k, v, *, causal: bool, q_offset=0,
     if causal:
         s_pos = jnp.arange(Sq)[:, None] + q_offset
         t_pos = jnp.arange(T)[None, :]
+        if window:
+            t_pos = t_pos + k_offset
         mask = t_pos <= s_pos                                  # (Sq, T)
+        if window:
+            mask = mask & (t_pos > s_pos - window) & (t_pos >= 0)
     if mask is not None:
         logits = jnp.where(mask[None, None, None], logits, NEG_INF)
     if kv_len is not None:
@@ -55,32 +61,42 @@ def attention_ref(q, k, v, *, causal: bool, q_offset=0,
 
 
 def attention(q, k, v, *, causal: bool, backend: str = "reference",
-              q_offset=0, kv_len=None, interpret: bool = False) -> jax.Array:
+              q_offset=0, kv_len=None, window: int = 0, k_offset=0,
+              interpret: bool = False) -> jax.Array:
     # q_offset may be a traced offset (chunked prefill): the kernel takes
     # it as a prefetched scalar
     if backend == "pallas" and kv_len is None:
         from repro.kernels import ops as kops
+        if window:
+            return kops.flash_attention(q, k, v, q_offset, k_offset,
+                                        causal=causal, window=window,
+                                        backend="pallas",
+                                        interpret=interpret)
         return kops.flash_attention(q, k, v, q_offset, causal=causal,
                                     backend="pallas", interpret=interpret)
     return attention_ref(q, k, v, causal=causal, q_offset=q_offset,
-                         kv_len=kv_len)
+                         kv_len=kv_len, window=window, k_offset=k_offset)
 
 
 def decode_attention(q, k_cache, v_cache, pos, *, backend: str = "reference",
-                     interpret: bool = False) -> jax.Array:
+                     window: int = 0, interpret: bool = False) -> jax.Array:
     """Single-token decode. q: (B,1,H,hd); caches: (B,S,K,hd); pos: scalar —
-    the index the current token was just written to (attend to <= pos).
-    Per-slot pos (B,) is the continuous-batching shape; pos[b] < 0 marks an
-    inactive slot (kv_len 0 — its output is meaningless and discarded)."""
+    the index the current token was just written to (attend to <= pos,
+    and > pos - window when window > 0). Per-slot pos (B,) is the
+    continuous-batching shape; pos[b] < 0 marks an inactive slot (kv_len 0
+    — its output is meaningless and discarded)."""
+    from repro.kernels import ops as kops
     if backend == "pallas":
-        from repro.kernels import ops as kops
-        return kops.flash_decode(q, k_cache, v_cache, pos, backend="pallas",
-                                 interpret=interpret)
+        return kops.flash_decode(q, k_cache, v_cache, pos, window=window,
+                                 backend="pallas", interpret=interpret)
+    if window:
+        return kops.flash_decode(q, k_cache, v_cache, pos, window=window,
+                                 backend="ref")
     return attention_ref(q, k_cache, v_cache, causal=False, kv_len=pos + 1)
 
 
 def paged_decode_attention(q, k_pages, v_pages, tables, pos, *,
-                           k_scale=None, v_scale=None,
+                           k_scale=None, v_scale=None, window: int = 0,
                            backend: str = "reference",
                            interpret: bool = False) -> jax.Array:
     """Single-token decode over the paged KV pool. q: (B,1,H,hd);
@@ -88,12 +104,13 @@ def paged_decode_attention(q, k_pages, v_pages, tables, pos, *,
     (B,) int32 last valid logical index (attend <= pos; < 0 = inactive
     slot, output row exactly zero). With ``k_scale``/``v_scale``
     ((P,page,K) fp32) the pools are int8 and the quantized kernel
-    dequantizes in-tile."""
+    dequantizes in-tile. ``window`` > 0 reads only each row's window."""
     from repro.kernels import ops as kops
     impl = "pallas" if backend == "pallas" else "ref"
     if k_scale is not None:
+        assert not window, "the int8 pool has no windowed decode"
         return kops.paged_decode_quant(q, k_pages, v_pages, k_scale,
                                        v_scale, tables, pos, backend=impl,
                                        interpret=interpret)
-    return kops.paged_decode(q, k_pages, v_pages, tables, pos, backend=impl,
-                             interpret=interpret)
+    return kops.paged_decode(q, k_pages, v_pages, tables, pos, window=window,
+                             backend=impl, interpret=interpret)

@@ -1,6 +1,8 @@
 """Shared primitive layers (pure functions, explicit params)."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,18 +21,50 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
                             / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_freqs(head_dim: int, rope) -> np.ndarray:
+    """YaRN (NTK-by-parts) inverse frequencies of a ``RopeConfig``:
+    dimensions that turn more than ``beta_fast`` times over the original
+    context keep their frequency, those that turn fewer than ``beta_slow``
+    times are divided by ``yarn_factor``, with a linear ramp between (the
+    correction range floored and ceiled, as in the published recipe)."""
+    base, dim = float(rope.theta), head_dim
+
+    def corr(rot):
+        return dim * math.log(rope.original_max_position
+                              / (rot * 2 * math.pi)) / (2 * math.log(base))
+    low = max(math.floor(corr(rope.beta_fast)), 0)
+    high = min(math.ceil(corr(rope.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    pos = base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    extra = 1.0 - ramp                      # share kept extrapolated
+    inv = ((1.0 / (rope.yarn_factor * pos)) * (1.0 - extra)
+           + (1.0 / pos) * extra)
+    return inv.astype(np.float32)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               rope=None) -> jax.Array:
     """Rotary embedding, split-half (llama) convention.
 
-    x: (B, S, H, hd); positions: (S,) or (B, S) int.
+    x: (B, S, H, hd); positions: (S,) or (B, S) int. ``rope`` (a
+    ``RopeConfig`` with ``yarn_factor`` > 0) takes YaRN's frequencies and
+    scales cos and sin by its ``attention_factor``; otherwise plain RoPE
+    at ``theta``.
     """
     hd = x.shape[-1]
-    inv = rope_freqs(hd, theta)
+    yarn = rope is not None and rope.yarn_factor > 0
+    inv = yarn_freqs(hd, rope) if yarn else rope_freqs(hd, theta)
     ang = positions.astype(jnp.float32)[..., None] * inv      # (..., S, hd/2)
     if ang.ndim == 2:                                          # (S, hd/2)
         ang = ang[None]                                        # (1, S, hd/2)
     cos = jnp.cos(ang)[:, :, None, :]                          # (B|1,S,1,hd/2)
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn:
+        cos = cos * rope.attention_factor
+        sin = sin * rope.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

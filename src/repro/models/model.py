@@ -19,7 +19,7 @@ from repro.models import params as P
 from repro.models.attention import (attention, decode_attention,
                                     paged_decode_attention)
 from repro.models.layers import apply_rope, embed_lookup, rms_norm, swiglu
-from repro.models.moe import moe_ffn
+from repro.models.moe import EXPERT_COUNTERS, moe_ffn, moe_ffn_dropless
 from repro.models.ssm import mamba_block
 from repro.models.xlstm import mlstm_block, slstm_block
 from repro.runtime.partitioning import constrain
@@ -34,11 +34,46 @@ def _dt(name: str):
 # ===========================================================================
 # attention mixer
 # ===========================================================================
+def _ring_tables(tables, ring_pool_pages: int):
+    """Block tables of a sliding-window block's per-slot page ring: slot b
+    owns pages 1 + b*RP .. (b+1)*RP of its pool (page 0 is the garbage
+    page) and logical page i of its sequence lives in ring page i mod RP.
+    A pure function of the slot and the table width, so the host keeps no
+    table for the ring."""
+    B, W = tables.shape
+    rp = (ring_pool_pages - 1) // B
+    return (1 + jnp.arange(B, dtype=jnp.int32)[:, None] * rp
+            + jnp.arange(W, dtype=jnp.int32)[None, :] % rp)
+
+
+def _ring_chunk(cache, k, v, pos, window):
+    """Chunked prefill of a sliding-window block into its (1, R) staging
+    ring: the chunk's rows land at ``pos % R`` (R a multiple of the chunk,
+    at least window + chunk), and the ring is returned as a view in
+    position order, ending at the chunk's last row, with the position of
+    its first row."""
+    R, C = cache["k"].shape[1], k.shape[1]
+    assert R % C == 0 and R >= window + C, (R, C, window)
+    at = pos % R
+    kc = jax.lax.dynamic_update_slice(
+        cache["k"], k.astype(cache["k"].dtype), (0, at, 0, 0))
+    vc = jax.lax.dynamic_update_slice(
+        cache["v"], v.astype(cache["v"].dtype), (0, at, 0, 0))
+    start = (at + C) % R
+
+    def view(ring):
+        two = jnp.concatenate([ring, ring], axis=1)
+        return jax.lax.dynamic_slice_in_dim(two, start, R, axis=1)
+    return kc, vc, view(kc), view(vc), pos + C - R
+
+
 def _attn_mixer(cfg: ModelConfig, p: dict, x, cdt, mode, cache, positions,
                 pos, backend, interpret, causal=True, tables=None,
-                active=None):
+                active=None, akind=None):
     B, S, D = x.shape
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    akind = akind or cfg.attn_kind(0)
+    W = akind.window
     h = rms_norm(x, p["ln1"], cfg.norm_eps).astype(cdt)
     if mode != "decode":
         h = constrain(h, "hidden_full")   # SP: gather seq for TP qkv
@@ -48,8 +83,8 @@ def _attn_mixer(cfg: ModelConfig, p: dict, x, cdt, mode, cache, positions,
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, akind.rope.theta, akind.rope)
+    k = apply_rope(k, positions, akind.rope.theta, akind.rope)
     if mode != "decode":
         q = constrain(q, "attn_q")
         k = constrain(k, "attn_kv")
@@ -66,6 +101,8 @@ def _attn_mixer(cfg: ModelConfig, p: dict, x, cdt, mode, cache, positions,
         page = cache["k"].shape[1]
         posw = jnp.maximum(posa, 0)
         rows = jnp.arange(B)
+        if W:
+            tables = _ring_tables(tables, cache["k"].shape[0])
         pids = tables[rows, posw // page]
         offs = posw % page
         if active is not None:
@@ -90,7 +127,7 @@ def _attn_mixer(cfg: ModelConfig, p: dict, x, cdt, mode, cache, positions,
                 k[:, 0].astype(cache["k"].dtype))
             vc = cache["v"].at[pids, offs].set(
                 v[:, 0].astype(cache["v"].dtype))
-            o = paged_decode_attention(q, kc, vc, tables, posa,
+            o = paged_decode_attention(q, kc, vc, tables, posa, window=W,
                                        backend=backend, interpret=interpret)
             new_cache = {"k": kc, "v": vc}
     elif mode == "decode":
@@ -117,8 +154,13 @@ def _attn_mixer(cfg: ModelConfig, p: dict, x, cdt, mode, cache, positions,
             vc = cache["v"].at[rows, posw].set(vnew)
         kc = constrain(kc, "kv_cache")
         vc = constrain(vc, "kv_cache")
-        o = decode_attention(q, kc, vc, pos, backend=backend,
+        o = decode_attention(q, kc, vc, pos, backend=backend, window=W,
                              interpret=interpret)
+        new_cache = {"k": kc, "v": vc}
+    elif mode == "prefill_chunk" and W:
+        kc, vc, kv_k, kv_v, k0 = _ring_chunk(cache, k, v, pos, W)
+        o = attention(q, kv_k, kv_v, causal=True, q_offset=pos, window=W,
+                      k_offset=k0, backend=backend, interpret=interpret)
         new_cache = {"k": kc, "v": vc}
     elif mode == "prefill_chunk":
         # chunked-prefill continuation: append this chunk's KV at offset
@@ -133,7 +175,7 @@ def _attn_mixer(cfg: ModelConfig, p: dict, x, cdt, mode, cache, positions,
         new_cache = {"k": kc, "v": vc}
     else:
         o = attention(q, k, v, causal=causal, backend=backend,
-                      interpret=interpret)
+                      window=W if causal else 0, interpret=interpret)
         if mode == "prefill":
             new_cache = {"k": constrain(k, "kv_cache"),
                          "v": constrain(v, "kv_cache")}
@@ -169,7 +211,8 @@ def _cross_mixer(cfg: ModelConfig, p: dict, x, cdt, mode, cache, memory,
     return out, new_cache
 
 
-def _apply_ffn(cfg: ModelConfig, p: dict, x, cdt):
+def _apply_ffn(cfg: ModelConfig, p: dict, x, cdt, backend="reference",
+               interpret=False):
     aux = {}
     if "ln2" not in p:
         return x, aux
@@ -183,14 +226,18 @@ def _apply_ffn(cfg: ModelConfig, p: dict, x, cdt):
         y = y + swiglu(hf, p["ffn"]["wi"], p["ffn"]["wg"], p["ffn"]["wo"],
                        cdt)
     if "moe" in p:
-        ym, aux = moe_ffn(h, p["moe"], cfg.moe, cdt)
+        if cfg.moe.dropless:
+            ym, aux = moe_ffn_dropless(h, p["moe"], cfg.moe, cdt,
+                                       backend=backend, interpret=interpret)
+        else:
+            ym, aux = moe_ffn(h, p["moe"], cfg.moe, cdt)
         y = y + ym
     return x + y.astype(x.dtype), aux
 
 
 def _apply_block(cfg, run: RunConfig, kind: str, p, x, mode, cache_j,
                  positions, pos, memory, causal=True, cross=False,
-                 tables=None, active=None):
+                 tables=None, active=None, akind=None):
     cdt = _dt(run.precision.compute)
     # the kernels are forward-only (no VJP): training differentiates the
     # reference, serving (prefill / chunk / decode) runs the kernels
@@ -200,7 +247,7 @@ def _apply_block(cfg, run: RunConfig, kind: str, p, x, mode, cache_j,
     if kind == ATTN:
         out, nc = _attn_mixer(cfg, p, x, cdt, mode, cache_j, positions, pos,
                               backend, interpret, causal=causal,
-                              tables=tables, active=active)
+                              tables=tables, active=active, akind=akind)
         x = x + out
         if nc:
             new_cache.update(nc)
@@ -224,7 +271,7 @@ def _apply_block(cfg, run: RunConfig, kind: str, p, x, mode, cache_j,
         x = x + out
         if nc:
             new_cache.update(nc)
-    x, aux = _apply_ffn(cfg, p, x, cdt)
+    x, aux = _apply_ffn(cfg, p, x, cdt, backend, interpret)
     x = constrain(x, "hidden")
     return x, (new_cache or None), aux
 
@@ -233,6 +280,15 @@ def _apply_block(cfg, run: RunConfig, kind: str, p, x, mode, cache_j,
 # layer-stack scan
 # ===========================================================================
 ZERO_AUX = {"load_balance": 0.0, "router_z": 0.0}
+
+
+def zero_aux(cfg: ModelConfig) -> dict:
+    """The stack's aux accumulators: the losses, and the dropless expert
+    layer's counters where the config has one."""
+    aux = dict(ZERO_AUX)
+    if cfg.moe is not None and cfg.moe.dropless:
+        aux.update({k: 0.0 for k in EXPERT_COUNTERS})
+    return aux
 
 
 def run_stack(cfg: ModelConfig, run: RunConfig, layers: dict, x, mode,
@@ -261,7 +317,8 @@ def run_stack(cfg: ModelConfig, run: RunConfig, layers: dict, x, mode,
             x, nc, aux = _apply_block(
                 cfg, run, pattern[j], period_params[f"block{j}"], x, mode,
                 cj, positions, pos, memory, causal=causal, cross=cross,
-                tables=tables, active=active)
+                tables=tables, active=active,
+                akind=None if is_encoder else cfg.attn_kind(j))
             if nc is not None:
                 new_caches[f"block{j}"] = nc
             for k_, v_ in aux.items():
@@ -284,10 +341,10 @@ def run_stack(cfg: ModelConfig, run: RunConfig, layers: dict, x, mode,
             return (x, aux), ncache
         # None is an empty pytree, so (layers, None) is a valid xs when no
         # cache flows through the stack.
-        (x, aux), ncache = jax.lax.scan(body, (x, dict(ZERO_AUX)),
+        (x, aux), ncache = jax.lax.scan(body, (x, zero_aux(cfg)),
                                         (layers, cache))
     else:
-        aux = dict(ZERO_AUX)
+        aux = zero_aux(cfg)
         ncache = {} if with_cache else None
         for i in range(nper):
             pp = jax.tree.map(lambda l: l[i], layers)
@@ -381,7 +438,7 @@ class Model:
         ce = jnp.where(valid, lse - gold, 0.0)
         ntok = jnp.maximum(jnp.sum(valid), 1)
         ce_mean = jnp.sum(ce) / ntok
-        aux_total = sum(aux.values())
+        aux_total = sum(v for k, v in aux.items() if k in ZERO_AUX)
         loss = ce_mean + aux_total
         metrics = {"loss": loss, "ce": ce_mean, "ntok": ntok, **aux}
         return loss, metrics
@@ -393,7 +450,7 @@ class Model:
         return cache, logits[:, -1]
 
     def decode_step(self, params, cache, tokens, pos, *, tables=None,
-                    active=None):
+                    active=None, counters=False):
         """tokens: (B,1) int32; pos: scalar int32 (uniform) or (B,) int32
         (per-slot, continuous batching) — the slot the new token occupies
         (attends to <= pos). Returns (logits (B,V), new_cache).
@@ -406,7 +463,10 @@ class Model:
         tables: optional (B,NP) int32 paged-KV block tables. When given,
         attention-cache leaves are page pools (nper, P, page, K, hd) —
         see ``repro.serve.paged`` — and ``pos`` is per-slot logical
-        position; page 0 is reserved as the garbage page."""
+        position; page 0 is reserved as the garbage page.
+
+        counters: also return the stack's counters (``EXPERT_COUNTERS``
+        summed over layers), as a third value."""
         cfg, run = self.cfg, self.run
         cdt = _dt(run.precision.compute)
         x = self._embed(params, tokens, cdt)
@@ -417,11 +477,15 @@ class Model:
         else:
             # rope positions must be in-range even for inactive (-1) slots
             positions = jnp.maximum(posa, 0)[:, None]
-        x, _, cache = run_stack(cfg, run, params["decoder"]["layers"], x,
-                                "decode", cache=cache, positions=positions,
-                                pos=pos, tables=tables, active=active)
+        x, aux, cache = run_stack(cfg, run, params["decoder"]["layers"], x,
+                                  "decode", cache=cache,
+                                  positions=positions, pos=pos,
+                                  tables=tables, active=active)
         x = rms_norm(x, params["decoder"]["final_norm"], cfg.norm_eps)
         logits = self._logits(params, x)
+        if counters:
+            return logits[:, 0], cache, {k: aux[k] for k in EXPERT_COUNTERS
+                                         if k in aux}
         return logits[:, 0], cache
 
     def prefill_chunk(self, params, cache, tokens, offset):
@@ -474,8 +538,11 @@ class Model:
             specs["frames"] = sd((B, Te, cfg.d_model), cdt)
         return specs
 
-    def cache_specs(self, shape: Optional[ShapeConfig] = None) -> dict:
-        """Decode-cache ShapeDtypeStructs: (periods, B, ...) per block."""
+    def cache_specs(self, shape: Optional[ShapeConfig] = None,
+                    ring: int = 0) -> dict:
+        """Decode-cache ShapeDtypeStructs: (periods, B, ...) per block.
+        ``ring`` > 0: sliding-window blocks hold a ring of that many
+        positions (chunked prefill's staging) instead of ``seq_len``."""
         cfg = self.cfg
         shape = shape or self.run.shape
         B, S = shape.global_batch, shape.seq_len
@@ -491,7 +558,8 @@ class Model:
         tree = {}
         for j, kind in enumerate(cfg.block_pattern):
             if kind == ATTN:
-                c = {"k": sd((B, S, K, hd)), "v": sd((B, S, K, hd))}
+                T = ring if ring and cfg.attn_kind(j).window else S
+                c = {"k": sd((B, T, K, hd)), "v": sd((B, T, K, hd))}
                 if cfg.is_encoder_decoder:
                     Te = S // cfg.frontend.frame_ratio
                     c["xk"] = sd((B, Te, K, hd))
@@ -513,13 +581,15 @@ class Model:
             tree[f"block{j}"] = c
         return tree
 
-    def init_cache(self, shape: Optional[ShapeConfig] = None) -> dict:
+    def init_cache(self, shape: Optional[ShapeConfig] = None,
+                   ring: int = 0) -> dict:
         def one(path, s):
             name = path[-1].key if hasattr(path[-1], "key") else ""
             if name == "m":       # exp-gate stabilizers start at -inf-ish
                 return jnp.full(s.shape, -1e30, s.dtype)
             return jnp.zeros(s.shape, s.dtype)
-        return jax.tree_util.tree_map_with_path(one, self.cache_specs(shape))
+        return jax.tree_util.tree_map_with_path(
+            one, self.cache_specs(shape, ring=ring))
 
 
 def build_model(run: RunConfig) -> Model:

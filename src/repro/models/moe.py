@@ -1,10 +1,21 @@
-"""Mixture-of-Experts FFN — GShard-style top-k routing with capacity.
+"""Mixture-of-Experts FFN: two layers.
 
-SPMD mapping (see DESIGN.md): tokens are grouped into fixed-size groups so
-the dispatch/combine one-hots stay small — groups shard over the data axes,
+``moe_ffn`` — GShard-style top-k routing with capacity. SPMD mapping (see
+DESIGN.md): tokens are grouped into fixed-size groups so the
+dispatch/combine one-hots stay small — groups shard over the data axes,
 the expert dim shards over the model axis (EP). All communication is left
 to the XLA SPMD partitioner (all-to-all between the token layout and the
-expert layout, all-gather for FSDP expert weights).
+expert layout, all-gather for FSDP expert weights). Tokens over a group's
+capacity are dropped, so a token's output depends on its group.
+
+``moe_ffn_dropless`` (``MoEConfig.dropless``) — every routed (token, held
+expert) pair is computed: the router scores all ``num_experts`` and picks
+the top k, the pairs whose expert this chip holds are sorted by expert,
+and one grouped matmul per projection (``kernels/grouped_ffn``) runs them.
+No capacity and no one-hot dispatch: the work follows the pairs routed
+here, and a token's output depends on that token alone. Experts outside
+the held share add nothing (expert parallelism without its exchange: the
+other chips' shares add up to the rest of the layer).
 """
 from __future__ import annotations
 
@@ -15,6 +26,63 @@ from repro.configs.base import MoEConfig
 from repro.runtime.partitioning import constrain
 
 GROUP_SIZE = 512  # tokens per routing group (capacity is per-group)
+#: the dropless layer's counters, summed over layers into the stack's aux
+#: (not losses): (token, held expert) pairs computed, and the largest
+#: count of pairs on one held expert
+EXPERT_COUNTERS = ("expert_tokens", "expert_max_load")
+
+
+def route(x2, router, moe: MoEConfig):
+    """Router over all ``num_experts``: float32 softmax of the logits, top
+    k, renormalised when ``norm_topk_prob``. x2: (T, D) -> (probs (T, E),
+    gates (T, k), expert ids (T, k))."""
+    logits = jnp.dot(x2.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, idx = jax.lax.top_k(probs, moe.num_experts_per_token)
+    if moe.norm_topk_prob:
+        gates = gates / jnp.sum(gates, -1, keepdims=True)
+    return logits, probs, gates, idx
+
+
+def moe_ffn_dropless(x: jax.Array, p: dict, moe: MoEConfig, cdt,
+                     backend: str = "reference", interpret: bool = False):
+    """x: (B, S, D) -> (y float32, aux): this chip's experts' part of the
+    layer for every token, with the aux losses and ``EXPERT_COUNTERS``."""
+    from repro.kernels import ops as kops
+    B, S, D = x.shape
+    T = B * S
+    E, K = moe.num_experts, moe.num_experts_per_token
+    Eh, off = moe.experts_held, moe.held_offset
+    x2 = x.reshape(T, D)
+    with jax.named_scope("moe.route"):
+        logits, probs, gates, idx = route(x2, p["router"], moe)
+        local = idx - off
+        held = (local >= 0) & (local < Eh)
+        e = jnp.where(held, local, Eh).reshape(T * K)   # absent: sorts last
+        order = jnp.argsort(e, stable=True)
+        sizes = jnp.bincount(e, length=Eh + 1)[:Eh].astype(jnp.int32)
+        rows = x2.astype(cdt)[order // K]
+    with jax.named_scope("moe.experts"):
+        out = kops.grouped_ffn(
+            rows, p["wg"].astype(cdt), p["wi"].astype(cdt),
+            p["wo"].astype(cdt), sizes, interpret=interpret,
+            backend="pallas" if backend == "pallas" else "ref")
+        out = jnp.where((e[order] < Eh)[:, None], out, 0.0)
+        # back to (token, choice) order, then each token's gated sum in a
+        # fixed order: nothing of another token enters it
+        pairs = jnp.zeros_like(out).at[order].set(out).reshape(T, K, D)
+        w = jnp.where(held, gates, 0.0)
+        y = jnp.sum(pairs * w[..., None], axis=1)
+    frac = jnp.mean(jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32),
+                            axis=1), axis=0)
+    lb = E * jnp.sum(frac * jnp.mean(probs, axis=0)) / K
+    z = jnp.mean(jnp.square(jax.scipy.special.logsumexp(logits, axis=-1)))
+    aux = {"load_balance": moe.load_balance_loss * lb,
+           "router_z": moe.router_z_loss * z,
+           "expert_tokens": jnp.sum(sizes).astype(jnp.float32),
+           "expert_max_load": jnp.max(sizes).astype(jnp.float32)}
+    return y.reshape(B, S, D), aux
 
 
 def moe_ffn(x: jax.Array, p: dict, moe: MoEConfig, cdt):

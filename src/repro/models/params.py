@@ -81,12 +81,14 @@ def ffn_shapes(cfg: ModelConfig, layer_idx: int, dtype) -> dict:
         p["ffn"] = {"wi": _sd((D, F), dtype), "wg": _sd((D, F), dtype),
                     "wo": _sd((F, D), dtype)}
     if has_moe:
-        E, Fe = cfg.moe.num_experts, cfg.moe.d_ff
+        # the router scores every expert; the expert weights are the held
+        # share (all of them unless the config holds a share)
+        E, Eh, Fe = cfg.moe.num_experts, cfg.moe.experts_held, cfg.moe.d_ff
         p["moe"] = {
             "router": _sd((D, E), jnp.float32),   # router in fp32 (stability)
-            "wi": _sd((E, D, Fe), dtype),
-            "wg": _sd((E, D, Fe), dtype),
-            "wo": _sd((E, Fe, D), dtype),
+            "wi": _sd((Eh, D, Fe), dtype),
+            "wg": _sd((Eh, D, Fe), dtype),
+            "wo": _sd((Eh, Fe, D), dtype),
         }
     return p
 

@@ -45,7 +45,8 @@ from repro.runtime.spans import span
 from repro.serve.paged import (BlockAllocator, CacheExhausted,
                                RequestRejected, admit_kv, apply_page_moves,
                                copy_page, extract_kv, init_paged_cache,
-                               paged_cache_supported, reset_slot_state)
+                               paged_cache_supported, reset_slot_state,
+                               ring_order)
 
 
 @dataclasses.dataclass
@@ -144,6 +145,13 @@ class ServeEngine:
         self.paged = paged
         if kv_dtype is not None and not paged:
             raise ValueError("kv_dtype requires the paged cache layout")
+        #: sliding-window blocks: in a paged engine each keeps a per-slot
+        #: ring of ``ring_len`` positions (window + one prefill chunk,
+        #: ``serve.paged``); prefix sharing and migration refuse them
+        self.windows = cfg.windows
+        if self.windows and share_prefix:
+            raise ValueError(f"{cfg.name}: prefix sharing does not hold "
+                             "sliding-window blocks")
         self.kv_dtype = kv_dtype
         #: fused sampling: temperature/top-k Gumbel sampling runs inside
         #: the jitted decode step (kernels/sampling) and only token ids
@@ -162,6 +170,7 @@ class ServeEngine:
             self.alloc = BlockAllocator(self.num_pages, page_size)
             self.tables = np.zeros((slots, maxp), np.int32)
             self._dirty.add("tables")
+        self.ring_len = 0
         # prefix sharing keys on prompt tokens alone, so it is gated to
         # token-only frontends (vision patch rows precede the token rows
         # and differ per request)
@@ -170,17 +179,32 @@ class ServeEngine:
         # chunked prefill needs per-chunk attention continuation, which only
         # the attention-pattern stacks support (recurrent blocks would need
         # their chunk-boundary state threaded through)
+        # (a sliding-window block's chunks go through its staging ring,
+        # which only the paged layout places)
         chunkable = (all(b == "attn" for b in cfg.block_pattern)
                      and not cfg.is_encoder_decoder
-                     and cfg.frontend.kind == "none")
+                     and cfg.frontend.kind == "none"
+                     and (paged or not self.windows))
         self.prefill_chunk = prefill_chunk if chunkable else 0
+        if paged and self.windows:
+            # a multiple of the chunk (its rows never wrap) and of the page
+            # holding the largest window and one chunk
+            C = self.prefill_chunk or page_size
+            step = C * page_size // math.gcd(C, page_size)
+            self.ring_len = step * math.ceil(
+                (max(self.windows.values()) + C) / step)
+        #: the decode step also returns the dropless expert layer's
+        #: counters, read back with its token ids
+        self._counters = bool(cfg.moe is not None and cfg.moe.dropless
+                              and self.fused_sampling)
 
         from repro.train.step import (make_decode_step, make_prefill_chunk,
                                       make_serve_steps)
         prefill, _ = make_serve_steps(run, rules)
         self._prefill = jax.jit(prefill)
         self._decode = jax.jit(make_decode_step(
-            run, rules, paged=paged, fused=self.fused_sampling))
+            run, rules, paged=paged, fused=self.fused_sampling,
+            counters=self._counters))
         self._chunk = jax.jit(make_prefill_chunk(run, rules))
         self._cache = None                              # lazy batched cache
         #: the chip this engine's params and KV cache live on (its VF's
@@ -203,10 +227,10 @@ class ServeEngine:
             shape = dataclasses.replace(self.run.shape, seq_len=self.max_len,
                                         global_batch=self.slots)
             if self.paged:
-                self._cache = init_paged_cache(self.model, shape,
-                                               self.num_pages,
-                                               self.page_size,
-                                               kv_dtype=self.kv_dtype)
+                self._cache = init_paged_cache(
+                    self.model, shape, self.num_pages, self.page_size,
+                    kv_dtype=self.kv_dtype,
+                    ring_pages=self.ring_len // self.page_size)
             else:
                 self._cache = self.model.init_cache(shape)
             if self.device is not None:
@@ -345,6 +369,9 @@ class ServeEngine:
             if pages is not None:
                 self.alloc.free(req.rid)
             return False
+        if self.ring_len:
+            req_cache = ring_order(req_cache, npatch + plen, self.ring_len,
+                                   tuple(self.windows))
         self._place(slot, req, req_cache, npatch + plen, pages)
         self.last_token[slot] = tok
         return True
@@ -357,7 +384,8 @@ class ServeEngine:
         shape = dataclasses.replace(self.run.shape, seq_len=cap,
                                     global_batch=1)
         self._jobs[slot] = _PrefillJob(
-            req=req, slot=slot, cache=self.model.init_cache(shape),
+            req=req, slot=slot,
+            cache=self.model.init_cache(shape, ring=self.ring_len),
             plen=plen, pages=pages)
 
     def _advance_prefill(self):
@@ -392,11 +420,14 @@ class ServeEngine:
         self._place(slot, req, req_cache, job.plen, job.pages)
         self.last_token[slot] = tok
 
-    @staticmethod
-    def _slice_kv(cache: dict, L: int) -> dict:
+    def _slice_kv(self, cache: dict, L: int) -> dict:
+        """The prompt's rows of a chunked prefill's staging cache; a
+        sliding-window block's staging ring is kept whole."""
         def one(path, leaf):
             name = path[-1].key if hasattr(path[-1], "key") else ""
-            return leaf[:, :, :L] if name in ("k", "v") else leaf
+            if name not in ("k", "v") or path[0].key in self.windows:
+                return leaf
+            return leaf[:, :, :L]
         return jax.tree_util.tree_map_with_path(one, cache)
 
     def _place(self, slot: int, req: Request, req_cache, logical_len: int,
@@ -410,7 +441,8 @@ class ServeEngine:
                 self.stats["shared_page_hits"] += shared
                 self._cache = admit_kv(self._cache, req_cache, pages,
                                        self.page_size, slot,
-                                       skip_pages=shared)
+                                       skip_pages=shared,
+                                       ring_blocks=tuple(self.windows))
                 row = self.tables[slot]
                 row[:] = 0
                 row[:len(pages)] = pages
@@ -541,6 +573,15 @@ class ServeEngine:
         with span("engine.readback", self.stats):
             out = np.asarray(out)     # token ids (fused) or logits
         with span("engine.bookkeep", self.stats):
+            if self._counters:
+                out, counts = out[:self.slots], out[self.slots:]
+                self.stats["expert_tokens"] += int(counts[0])
+                self.stats["expert_max_load"] += int(counts[1])
+            if self.ring_len:
+                # positions one sliding layer holds for the decoding slots
+                self.stats["window_kv_positions"] += int(np.minimum(
+                    pos_new[act] + 1, self.ring_len).sum())
+                self.stats["window_kv_steps"] += 1
             self._dirty |= {"cache", "pos", "last_token"}
             for s in act:
                 req = self.active[s]
@@ -625,7 +666,8 @@ class ServeEngine:
             return {}
         moves = self.alloc.defragment()
         if moves and self._cache is not None:
-            self._cache = apply_page_moves(self._cache, moves)
+            self._cache = apply_page_moves(self._cache, moves,
+                                           tuple(self.windows))
             self._dirty |= {"cache", "tables"}
         for s, req in enumerate(self.active):
             if req is not None:
@@ -660,9 +702,9 @@ class ServeEngine:
     def peek_migratable(self, rid: Optional[int] = None) -> Optional[int]:
         """Pure query: the rid ``extract_request`` would pick — first
         active decoding slot in slot order (or ``rid`` if it is one).
-        None when nothing is migratable (dense engine, idle, or already
-        mid-migration)."""
-        if not self.paged:
+        None when nothing is migratable (dense engine, windowed stack,
+        idle, or already mid-migration)."""
+        if not self.paged or self.windows:
             return None
         frozen = set(self._migrating.values())
         for s in range(self.slots):
@@ -708,9 +750,10 @@ class ServeEngine:
         enough pages. Idempotent: re-admitting an owned rid is a no-op
         (recovery roll-forward replays)."""
         rid = payload["rid"]
-        if not self.paged:
+        if not self.paged or self.windows:
             raise RequestRejected(
-                f"request {rid}: migration target is not a paged engine")
+                f"request {rid}: migration target is not a paged engine "
+                "without sliding-window blocks")
         if self.owns_request(rid):
             return next(s for s, r in enumerate(self.active)
                         if r is not None and r.rid == rid)

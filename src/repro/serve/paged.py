@@ -35,6 +35,16 @@ keys guarantee.
 
 The attention-side consumer is ``kernels/paged_decode`` (block-table
 indirection, cost proportional to pages actually written).
+
+Sliding-window blocks (``ModelConfig.windows``) keep a second kind of pool:
+a per-slot ring. Slot s owns pages 1 + s*RP .. (s+1)*RP of its block's
+pool, RP pages of window + one prefill chunk positions, and logical page i
+lives in ring page i mod RP; its table is a function of the slot (see
+``models/model._ring_tables``), so the allocator knows nothing of it. A
+sliding layer thus holds window + chunk positions per request whatever the
+context, the decode kernel reads only the window's pages, and the ring
+pages never move (defragment, copy-on-write and migration are for the
+allocator's pool: prefix sharing and migration refuse windowed stacks).
 """
 from __future__ import annotations
 
@@ -429,10 +439,13 @@ def kv_dequantize(q, scale, dtype=jnp.float32):
 
 
 def init_paged_cache(model, shape, num_pages: int, page_size: int,
-                     kv_dtype: Optional[str] = None) -> dict:
+                     kv_dtype: Optional[str] = None,
+                     ring_pages: int = 0) -> dict:
     """Build the serve cache tree: attention k/v leaves become shared page
     pools (nper, P, page, K, hd); every other leaf (recurrent state) stays
-    dense per-slot (B, ...) exactly as ``init_cache`` makes it.
+    dense per-slot (B, ...) exactly as ``init_cache`` makes it. A
+    sliding-window block's pool is its per-slot ring instead:
+    (nper, 1 + B * ring_pages, page, K, hd).
 
     ``kv_dtype='int8'`` stores the pools quantized (per-(row,head)
     symmetric scales in fp32 ``k_scale``/``v_scale`` siblings, shape
@@ -447,12 +460,16 @@ def init_paged_cache(model, shape, num_pages: int, page_size: int,
                          "(None or 'int8')")
     # the dense template only sizes non-KV leaves, so keep its seq dim tiny
     base = model.init_cache(dataclasses.replace(shape, seq_len=1))
+    rings = tuple(model.cfg.windows)
+    if rings and (kv_dtype is not None or ring_pages < 1):
+        raise ValueError("sliding-window blocks need ring_pages and the "
+                         "bf16 pool")
 
     def one(path, leaf):
         if _is_kv(path):
-            nper, _, _, K, hd = leaf.shape
-            return jnp.zeros((nper, num_pages, page_size, K, hd),
-                             leaf.dtype)
+            nper, B, _, K, hd = leaf.shape
+            P = 1 + B * ring_pages if _in_ring(path, rings) else num_pages
+            return jnp.zeros((nper, P, page_size, K, hd), leaf.dtype)
         return leaf
     tree = jax.tree_util.tree_map_with_path(one, base)
     if kv_dtype == "int8":
@@ -483,8 +500,27 @@ def _path_key(path) -> tuple:
     return tuple(getattr(p, "key", getattr(p, "idx", None)) for p in path)
 
 
+def _in_ring(path, ring_blocks) -> bool:
+    """A KV leaf of a sliding-window block (its per-slot ring pool)."""
+    return bool(ring_blocks) and getattr(path[0], "key", None) in ring_blocks
+
+
+def ring_order(req_cache: dict, L: int, R: int, ring_blocks) -> dict:
+    """A whole-prompt prefill's dense (nper, 1, L, K, hd) KV of the
+    sliding-window blocks in ring order: the last R positions, position p
+    at row p mod R (what chunked prefill's staging ring holds)."""
+    def one(path, leaf):
+        if not (_is_kv(path) and _in_ring(path, ring_blocks)):
+            return leaf
+        if L <= R:
+            return jnp.pad(leaf, ((0, 0), (0, 0), (0, R - L), (0, 0),
+                                  (0, 0)))
+        return jnp.roll(leaf[:, :, L - R:], (L - R) % R, axis=2)
+    return jax.tree_util.tree_map_with_path(one, req_cache)
+
+
 def admit_kv(cache: dict, req_cache: dict, page_ids, page_size: int,
-             slot: int, skip_pages: int = 0) -> dict:
+             slot: int, skip_pages: int = 0, ring_blocks=()) -> dict:
     """Copy-on-admit: scatter a prefilled request's (nper, 1, L, K, hd)
     KV into its allocated pages; non-KV leaves (recurrent state) are
     written into batch ``slot`` densely. ``skip_pages`` leading pages of
@@ -502,16 +538,22 @@ def admit_kv(cache: dict, req_cache: dict, page_ids, page_size: int,
     count, skip) — the same shape family the prefill executables already
     warm — so an int8 admit costs one fused kernel, not an eager
     quantize-dispatch per cache leaf. ``slot`` rides in as a traced
-    scalar: slot churn never retraces."""
+    scalar: slot churn never retraces.
+
+    The ``ring_blocks``' KV arrives in ring order (``ring_order``), one
+    ring of the pool's ring length, and is copied whole into the slot's
+    ring pages."""
     skip = int(skip_pages)
     ids = jnp.asarray(page_ids, jnp.int32)[skip:]
     return _admit_kv_jit(cache, req_cache, ids, jnp.int32(slot),
-                         page_size=int(page_size), skip=skip)
+                         page_size=int(page_size), skip=skip,
+                         ring_blocks=tuple(ring_blocks))
 
 
-@functools.partial(jax.jit, static_argnames=("page_size", "skip"))
+@functools.partial(jax.jit, static_argnames=("page_size", "skip",
+                                             "ring_blocks"))
 def _admit_kv_jit(cache: dict, req_cache: dict, ids, slot, *,
-                  page_size: int, skip: int) -> dict:
+                  page_size: int, skip: int, ring_blocks=()) -> dict:
     n = int(ids.shape[0])
     flat, treedef = jax.tree_util.tree_flatten_with_path(cache)
     req = {_path_key(p): leaf
@@ -527,7 +569,14 @@ def _admit_kv_jit(cache: dict, req_cache: dict, ids, slot, *,
     out = []
     for path, pooled in flat:
         key = _path_key(path)
-        if _is_kv(path):
+        if _is_kv(path) and _in_ring(path, ring_blocks):
+            nper, _, R, K, hd = req[key].shape
+            rp = R // page_size
+            ring = req[key][:, 0].reshape(nper, rp, page_size, K, hd)
+            out.append(jax.lax.dynamic_update_slice(
+                pooled, ring.astype(pooled.dtype),
+                (0, 1 + slot * rp, 0, 0, 0)))
+        elif _is_kv(path):
             if n == 0:                 # whole prompt shared: nothing to copy
                 out.append(pooled)
                 continue
@@ -598,13 +647,17 @@ def copy_page(cache: dict, src: int, dst: int) -> dict:
     return jax.tree_util.tree_map_with_path(one, cache)
 
 
-def apply_page_moves(cache: dict, moves: dict[int, int]) -> dict:
+def apply_page_moves(cache: dict, moves: dict[int, int],
+                     ring_blocks=()) -> dict:
     """Apply a ``defragment`` move map to the physical page pools
-    (quantization scales ride along — their axis 1 is the same page id)."""
+    (quantization scales ride along — their axis 1 is the same page id);
+    the ``ring_blocks``' per-slot rings do not move."""
     if not moves:
         return cache
 
     def one(path, leaf):
+        if _in_ring(path, ring_blocks):
+            return leaf
         if _is_kv(path) or _is_kv_scale(path):
             g = permutation_of(moves, leaf.shape[1])
             return leaf[:, jnp.asarray(g)]

@@ -143,7 +143,8 @@ def make_serve_steps(run: RunConfig, rules: Optional[ShardingRules] = None):
 
 def make_decode_step(run: RunConfig,
                      rules: Optional[ShardingRules] = None, *,
-                     paged: bool = False, fused: bool = False):
+                     paged: bool = False, fused: bool = False,
+                     counters: bool = False):
     """Continuous-batching decode step with an active-slot mask; with
     ``paged`` the cache is the paged-KV page pool and a block table rides
     along (see ``Model.decode_step``).
@@ -153,7 +154,11 @@ def make_decode_step(run: RunConfig,
     token_counter)) and returns SAMPLED TOKEN IDS (B,) i32 instead of
     logits — temperature/top-k Gumbel sampling runs on-device
     (``kernels/sampling``), bit-identical to ``ServeEngine._sample``, and
-    the (B, V) logits never leave the device."""
+    the (B, V) logits never leave the device.
+
+    With ``counters`` (paged and fused, a dropless expert stack) the token
+    ids are followed by the stack's counters (``models.moe.
+    EXPERT_COUNTERS``, summed over layers), (B + 2,) int32: one readback."""
     model = build_model(run)
 
     def _sample_on_device(logits, temp, topk, keys):
@@ -163,7 +168,21 @@ def make_decode_step(run: RunConfig,
             interpret=run.interpret,
             backend="pallas" if run.kernels == "pallas" else "ref")
 
-    if paged and fused:
+    if counters:
+        assert paged and fused, "counters ride on the paged fused step"
+        from repro.models.moe import EXPERT_COUNTERS
+
+        def decode(params, cache, tokens, pos, tables, active,
+                   temp, topk, keys):
+            with sharding_scope(rules), jax.named_scope("decode"):
+                logits, cache, cnt = model.decode_step(
+                    params, cache, tokens, pos, tables=tables,
+                    active=active, counters=True)
+                toks = _sample_on_device(logits, temp, topk, keys)
+                extra = jnp.stack([cnt[k] for k in EXPERT_COUNTERS])
+                return jnp.concatenate(
+                    [toks, extra.astype(toks.dtype)]), cache
+    elif paged and fused:
         def decode(params, cache, tokens, pos, tables, active,
                    temp, topk, keys):
             with sharding_scope(rules), jax.named_scope("decode"):

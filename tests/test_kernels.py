@@ -201,3 +201,78 @@ def test_qdma_pack_preserves_zeros_and_extremes():
     xx = qdma_unpack(q, s, interpret=True)
     assert float(xx[0, 0]) == pytest.approx(1000.0, rel=1e-2)
     assert float(jnp.abs(xx[1:]).max()) == 0.0
+
+
+# ---------------------------------------------------------------------------
+# sliding windows and the grouped expert FFN
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("S,window,q_offset,k_offset,blocks", [
+    (256, 64, 0, 0, (64, 64)),         # whole prompt, window < block span
+    (128, 100, 0, 0, (32, 32)),        # window not a multiple of the block
+    (64, 48, 200, 104, (32, 32)),      # ring view: keys start before q
+    (64, 48, 16, -48, (32, 32)),       # ring view with negative positions
+])
+def test_flash_attention_window(S, window, q_offset, k_offset, blocks):
+    """Windowed flash attention (and its skipped key blocks) == the masked
+    oracle; keys carry their own offset, negative positions masked."""
+    ks = jax.random.split(jax.random.key(11), 3)
+    T = S if not k_offset else q_offset + S - k_offset
+    q = rand(ks[0], (1, S, 4, 64), jnp.float32)
+    k = rand(ks[1], (1, T, 2, 64), jnp.float32)
+    v = rand(ks[2], (1, T, 2, 64), jnp.float32)
+    out = flash_attention(q, k, v, causal=True, q_offset=q_offset,
+                          k_offset=k_offset, window=window,
+                          block_q=blocks[0], block_k=blocks[1],
+                          interpret=True)
+    want = ref.flash_attention_ref(q, k, v, causal=True, q_offset=q_offset,
+                                   window=window, k_offset=k_offset)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+
+
+@pytest.mark.parametrize("positions", [(5, 70, 127), (-1, 31, 64)])
+def test_paged_decode_window(positions):
+    """A windowed paged decode reads only its window's pages and equals
+    the oracle; with the window wider than the context it is the plain
+    decode."""
+    ks = jax.random.split(jax.random.key(12), 3)
+    page, NP, P, K, hd, W = 8, 16, 40, 2, 64, 20
+    q = rand(ks[0], (3, 1, 4, hd), jnp.float32)
+    kp = rand(ks[1], (P, page, K, hd), jnp.float32)
+    vp = rand(ks[2], (P, page, K, hd), jnp.float32)
+    tables = jnp.asarray(np.random.default_rng(1).integers(1, P, (3, NP)),
+                         jnp.int32)
+    pos = jnp.asarray(positions, jnp.int32)
+    out = paged_decode(q, kp, vp, tables, pos, window=W, interpret=True)
+    want = ref.paged_decode_ref(q, kp, vp, tables, pos, window=W)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    wide = paged_decode(q, kp, vp, tables, pos, window=NP * page,
+                        interpret=True)
+    plain = paged_decode(q, kp, vp, tables, pos, interpret=True)
+    np.testing.assert_allclose(np.asarray(wide), np.asarray(plain),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("sizes,block_m", [
+    ((10, 0, 15, 5), 16),            # an empty group, tiles shared
+    ((0, 0, 0, 0), 16),              # nothing routed here
+    ((40, 0, 0, 0), 128),            # one group, one tile
+    ((3, 3, 3, 3), 8),               # every tile holds two groups
+])
+def test_grouped_ffn_matches_ragged_dot(sizes, block_m):
+    """The grouped expert FFN kernel == ragged_dot SwiGLU on the rows the
+    groups own (rows past them are the caller's to mask)."""
+    from repro.kernels.grouped_ffn import grouped_ffn
+    ks = jax.random.split(jax.random.key(13), 4)
+    M, D, F, G = 48, 128, 256, 4
+    x = rand(ks[0], (M, D), jnp.bfloat16)
+    wg = (rand(ks[1], (G, D, F), jnp.float32) / 11).astype(jnp.bfloat16)
+    wi = (rand(ks[2], (G, D, F), jnp.float32) / 11).astype(jnp.bfloat16)
+    wo = (rand(ks[3], (G, F, D), jnp.float32) / 16).astype(jnp.bfloat16)
+    gs = jnp.asarray(sizes, jnp.int32)
+    n = sum(sizes)
+    out = grouped_ffn(x, wg, wi, wo, gs, block_m=block_m, interpret=True)
+    want = ref.grouped_ffn_ref(x, wg, wi, wo, gs)
+    np.testing.assert_allclose(np.asarray(out[:n]), np.asarray(want[:n]),
+                               atol=2e-3, rtol=2e-3)

@@ -1,5 +1,7 @@
 """The serve path's Pallas kernels compile for a TPU v5e at qwen3-0.6b
-widths (H=16, K=8, hd=128, page 16, V=151936).
+widths (H=16, K=8, hd=128, page 16, V=151936), and the sliding-window and
+grouped expert kernels at Mellum2's (H=32, K=4, window 1024, D=2304,
+expert width 896, 8 experts held).
 
 Nothing runs: each test compiles for a described, not attached, chip —
 what the TPU compiler refuses (block shapes off the (8, 128) tiling, too
@@ -93,3 +95,34 @@ def test_flash_attention_compiles_for_v5e(spec, q_len):
     args = (q, kv, kv) if q_len == MAX_LEN else (q, kv, kv,
                                                  spec((), jnp.int32))
     _assert_kernel(kops.flash_attention.lower(*args, backend="pallas"))
+
+
+@pytest.mark.parametrize("q_len", [256, 4096])
+def test_windowed_flash_attention_compiles_for_v5e(spec, q_len):
+    """A chunk against its staging ring (window 1024 + chunk 256) and a
+    whole windowed prompt, at Mellum2's heads."""
+    q = spec((1, q_len, 32, HD), jnp.bfloat16)
+    T = 1280 if q_len == 256 else q_len
+    kv = spec((1, T, 4, HD), jnp.bfloat16)
+    off = spec((), jnp.int32)
+    _assert_kernel(kops.flash_attention.lower(q, kv, kv, off, off,
+                                              window=1024,
+                                              backend="pallas"))
+
+
+def test_windowed_paged_decode_compiles_for_v5e(spec):
+    q = spec((16, 1, 32, HD), jnp.bfloat16)
+    pages = spec((1 + 16 * 80, PAGE, 4, HD), jnp.bfloat16)
+    _assert_kernel(kops.paged_decode.lower(
+        q, pages, pages, spec((16, 1024), jnp.int32), spec((16,), jnp.int32),
+        window=1024, backend="pallas"))
+
+
+@pytest.mark.parametrize("rows", [16 * 8, 256 * 8])
+def test_grouped_ffn_compiles_for_v5e(spec, rows):
+    """A decode step's and a prefill chunk's (token, expert) pairs."""
+    x = spec((rows, 2304), jnp.bfloat16)
+    w = spec((8, 2304, 896), jnp.bfloat16)
+    _assert_kernel(kops.grouped_ffn.lower(
+        x, w, w, spec((8, 896, 2304), jnp.bfloat16), spec((8,), jnp.int32),
+        backend="pallas"))
