@@ -13,9 +13,13 @@ cost is proportional to the tokens a sequence has actually written, not
 to the pool size or the table width. ``pos[b] < 0`` (an inactive batch
 slot) skips every tile and yields an exactly-zero output row.
 
-A block is one whole page, all K kv-heads wide: the pool is viewed as
-(P, page, K*hd), and the kernel body (``flash_decode.decode_kernel``)
-loops over the kv-heads.
+A block is one whole page, all K kv-heads wide, in the layout the pool
+stores it, (1, page, K, hd). The kernel body (``flash_decode.
+decode_kernel``) takes it as page * K rows of hd and scores every query
+head against all of them in one matmul, each head keeping its own
+kv-head's rows. Nothing views the pool in another layout: on the TPU
+(the pool tiled on its (K, hd) minor dims) that would copy the layer's
+whole pool on every call.
 
 A sliding ``window`` W > 0 bounds each row's reads from below as well:
 the grid's page axis spans the (W - 1) // page + 2 pages a window can
@@ -40,7 +44,7 @@ from repro.kernels.flash_decode import decode_kernel, decode_scratch
 def _paged_call(q, k_pages, v_pages, scales, tables, pos, interpret,
                 window=0):
     B, _, H, hd = q.shape
-    P, page, K, _ = k_pages.shape
+    page, K = k_pages.shape[1], k_pages.shape[2]
     NP = tables.shape[1]
     quant = scales is not None
     kern = functools.partial(decode_kernel, scale=1.0 / math.sqrt(hd),
@@ -55,15 +59,14 @@ def _paged_call(q, k_pages, v_pages, scales, tables, pos, interpret,
             pi = pi + jnp.maximum(pos_ref[b] - window + 1, 0) // page
         return tbl_ref[b, jnp.minimum(pi, last)]
 
-    kv_spec = pl.BlockSpec((1, page, K * hd),
-                           lambda b, pi, t, p: (page_of(b, pi, t, p), 0, 0))
+    kv_spec = pl.BlockSpec(
+        (1, page, K, hd),
+        lambda b, pi, t, p: (page_of(b, pi, t, p), 0, 0, 0))
     sc_spec = pl.BlockSpec((1, page, K),
                            lambda b, pi, t, p: (page_of(b, pi, t, p), 0, 0))
     in_specs = [pl.BlockSpec((1, H, hd), lambda b, pi, t, p: (b, 0, 0)),
                 kv_spec, kv_spec] + ([sc_spec, sc_spec] if quant else [])
-    args = [k_pages.reshape(P, page, K * hd),
-            v_pages.reshape(P, page, K * hd)] + (list(scales) if quant
-                                                 else [])
+    args = [k_pages, v_pages] + (list(scales) if quant else [])
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -8,7 +8,7 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode
-from repro.kernels.paged_decode import paged_decode
+from repro.kernels.paged_decode import paged_decode, paged_decode_quant
 from repro.kernels.qdma_pack import qdma_pack, qdma_unpack
 from repro.kernels.ssm_scan import ssm_scan
 
@@ -90,27 +90,42 @@ def test_flash_decode_matches_flash_attention_last_row():
 
 
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
 @pytest.mark.parametrize("B,H,K,hd,page,NP,P,positions", [
     (1, 4, 4, 64, 16, 4, 8, (63,)),          # MHA, full view valid
     (3, 4, 2, 64, 8, 4, 16, (5, -1, 31)),    # GQA, one inactive slot
     (2, 8, 2, 128, 32, 2, 5, (0, 40)),       # MQA-ish wide head
     (2, 2, 1, 64, 8, 8, 17, (-1, -1)),       # all slots inactive
+    (3, 16, 8, 128, 16, 4, 12, (15, -1, 48)),  # qwen3 heads; page ends
+    (2, 32, 4, 128, 16, 3, 7, (16, 47)),     # Mellum2 heads; page bounds
 ])
 def test_paged_decode_sweep(B, H, K, hd, page, NP, P, positions, dtype):
+    """The kernel reads each page as the pool stores it, (page, K, hd),
+    and equals the oracle; an int8 pool (``paged_decode_quant``, float32
+    queries) equals the oracle over the same quantized pool."""
     ks = jax.random.split(jax.random.key(4), 3)
-    q = rand(ks[0], (B, 1, H, hd), dtype)
-    kp = rand(ks[1], (P, page, K, hd), dtype)
-    vp = rand(ks[2], (P, page, K, hd), dtype)
+    quant = dtype == jnp.int8
+    fdt = jnp.float32 if quant else dtype
+    q = rand(ks[0], (B, 1, H, hd), fdt)
+    kp = rand(ks[1], (P, page, K, hd), fdt)
+    vp = rand(ks[2], (P, page, K, hd), fdt)
     rng = np.random.default_rng(0)
     # arbitrary page ids (reads may alias; page 0 stays reserved for writes)
     tables = jnp.asarray(rng.integers(1, P, (B, NP)), jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
-    out = paged_decode(q, kp, vp, tables, pos, interpret=True)
-    want = ref.paged_decode_ref(q, kp, vp, tables, pos)
+    if quant:
+        kq, k_scale = ref.kv_quant_ref(kp)
+        vq, v_scale = ref.kv_quant_ref(vp)
+        out = paged_decode_quant(q, kq, vq, k_scale, v_scale, tables, pos,
+                                 interpret=True)
+        want = ref.paged_decode_quant_ref(q, kq, vq, k_scale, v_scale,
+                                          tables, pos)
+    else:
+        out = paged_decode(q, kp, vp, tables, pos, interpret=True)
+        want = ref.paged_decode_ref(q, kp, vp, tables, pos)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32),
-        atol=TOL[dtype], rtol=TOL[dtype])
+        atol=TOL[fdt], rtol=TOL[fdt])
     # inactive slots produce exactly-zero rows in BOTH implementations
     for b, p in enumerate(positions):
         if p < 0:
@@ -230,18 +245,29 @@ def test_flash_attention_window(S, window, q_offset, k_offset, blocks):
                                rtol=2e-5)
 
 
-@pytest.mark.parametrize("positions", [(5, 70, 127), (-1, 31, 64)])
-def test_paged_decode_window(positions):
+@pytest.mark.parametrize("K,ring,positions", [
+    (2, 0, (5, 70, 127)),
+    (2, 0, (-1, 31, 64)),
+    (4, 4, (36, -1, 100)),       # windows that wrap round the ring
+    (8, 4, (7, 39, 56)),         # a clipped window; page bounds
+])
+def test_paged_decode_window(K, ring, positions):
     """A windowed paged decode reads only its window's pages and equals
-    the oracle; with the window wider than the context it is the plain
-    decode."""
+    the oracle, over random tables or per-slot rings of ``ring`` pages
+    (logical page i of slot b at 1 + b * ring + i % ring, as
+    ``serve.paged`` lays them out); with the window wider than the
+    context it is the plain decode."""
     ks = jax.random.split(jax.random.key(12), 3)
-    page, NP, P, K, hd, W = 8, 16, 40, 2, 64, 20
-    q = rand(ks[0], (3, 1, 4, hd), jnp.float32)
+    page, NP, P, hd, W = 8, 16, 40, 64, 20
+    q = rand(ks[0], (3, 1, 2 * K, hd), jnp.float32)
     kp = rand(ks[1], (P, page, K, hd), jnp.float32)
     vp = rand(ks[2], (P, page, K, hd), jnp.float32)
-    tables = jnp.asarray(np.random.default_rng(1).integers(1, P, (3, NP)),
-                         jnp.int32)
+    if ring:
+        tables = (1 + jnp.arange(3)[:, None] * ring
+                  + jnp.arange(NP)[None, :] % ring).astype(jnp.int32)
+    else:
+        tables = jnp.asarray(
+            np.random.default_rng(1).integers(1, P, (3, NP)), jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
     out = paged_decode(q, kp, vp, tables, pos, window=W, interpret=True)
     want = ref.paged_decode_ref(q, kp, vp, tables, pos, window=W)

@@ -1,7 +1,8 @@
 """The serve path's Pallas kernels compile for a TPU v5e at qwen3-0.6b
 widths (H=16, K=8, hd=128, page 16, V=151936), and the sliding-window and
 grouped expert kernels at Mellum2's (H=32, K=4, window 1024, D=2304,
-expert width 896, 8 experts held).
+expert width 896, 8 experts held). The whole paged decode step compiles
+at both, with no copy of a KV pool in another layout.
 
 Nothing runs: each test compiles for a described, not attached, chip —
 what the TPU compiler refuses (block shapes off the (8, 128) tiling, too
@@ -9,7 +10,9 @@ much VMEM) fails here instead of on the chip. The topology is described
 inside a fixture, so a process that cannot load the TPU library skips
 these tests and every worker still collects the same ones.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -126,3 +129,89 @@ def test_grouped_ffn_compiles_for_v5e(spec, rows):
     _assert_kernel(kops.grouped_ffn.lower(
         x, w, w, spec((8, 896, 2304), jnp.bfloat16), spec((8,), jnp.int32),
         backend="pallas"))
+
+
+# ---------------------------------------------------------------------------
+# the whole paged decode step: the KV pools are read where they lie
+# ---------------------------------------------------------------------------
+#: ops that write a pool-sized result in another layout
+RELAYOUT_OPS = ("reshape", "copy", "transpose")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([a-z][\w\-]*)\(")
+
+
+def pool_sized_ops(hlo: str, pages) -> list:
+    """(opcode, name) of each instruction of compiled HLO text whose result
+    has a dimension in ``pages``: the page count of a KV pool."""
+    found = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m and any(int(d) in pages
+                     for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(2))
+                     for d in dims.split(",")):
+            found.append((m.group(3), m.group(1)))
+    return found
+
+
+def _compile_decode_step(spec, cfg, max_len, pages, width, ring_pages=0):
+    """The serve engine's paged, fused decode step, for 16 slots of
+    ``max_len``, compiled for one described v5e chip."""
+    from repro.configs import PrecisionConfig, RunConfig
+    from repro.configs.base import ShapeConfig
+    from repro.models.model import build_model
+    from repro.serve.paged import init_paged_cache
+    from repro.train.step import make_decode_step
+    slots = 16
+    run = RunConfig(model=cfg, shape=ShapeConfig("serve", "decode", max_len,
+                                                 slots),
+                    precision=PrecisionConfig("bfloat16", "bfloat16",
+                                              "float32"),
+                    kernel_backend="pallas")
+    model = build_model(run)
+    cache = jax.eval_shape(lambda: init_paged_cache(
+        model, run.shape, pages, PAGE, ring_pages=ring_pages))
+    params, cache = (jax.tree.map(lambda s: spec(s.shape, s.dtype), t)
+                     for t in (model.param_shapes(), cache))
+    step = make_decode_step(run, paged=True, fused=True,
+                            counters=cfg.moe is not None)
+    return jax.jit(step).lower(
+        params, cache, spec((slots, 1), jnp.int32), spec((slots,), jnp.int32),
+        spec((slots, width), jnp.int32), spec((slots,), jnp.bool_),
+        spec((slots,), jnp.float32), spec((slots,), jnp.int32),
+        spec((slots, 3), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("model", ["qwen3-0.6b", "mellum2-ep8"])
+def test_paged_decode_step_reads_pools_in_place_for_v5e(spec, model):
+    """qwen3-0.6b at 2 layers (16 slots of 2,048, 2,049 pages, table width
+    64) and Mellum2's share at one sliding and one full block twice over
+    (6,657 full pages, rings of 80 pages a slot, width 1,024): the layer
+    scan stays, and ``paged_decode`` reads each layer's pools as stored.
+    Viewing them as (P, page, K*hd) copied both pools of every layer, each
+    step. The scan's slice of the layer's pools out of the stack, and its
+    write back, are still there."""
+    from repro.configs import ATTN
+    from repro.configs import mellum2_12b_a2_5b as mellum2
+    from repro.configs import qwen3_0_6b
+    if model == "qwen3-0.6b":
+        cfg = dataclasses.replace(qwen3_0_6b.full(), num_layers=2)
+        pages = {2049}
+        compiled = _compile_decode_step(spec, cfg, 2048, 2049, 64)
+    else:
+        cfg = dataclasses.replace(
+            mellum2.ep_share(8, 0), num_layers=4, block_pattern=(ATTN,) * 2,
+            attn_kinds=(mellum2.SLIDING, mellum2.FULL))
+        pages = {6657, 1 + 16 * 80}
+        compiled = _compile_decode_step(spec, cfg, 16384, 6657, 1024,
+                                        ring_pages=80)
+    hlo = compiled.as_text()
+    assert "paged_decode" in hlo
+    relaid = [op for op in pool_sized_ops(hlo, pages)
+              if op[0] in RELAYOUT_OPS]
+    assert not relaid, relaid
+    if model == "qwen3-0.6b":
+        # one pool-sized temporary is left, the scan's slice of a layer's
+        # pool; the relayout held two more. (Mellum2's temporaries also
+        # hold the held experts' weights, sliced out of the stack.)
+        k_pool = 2049 * PAGE * K * HD * 2
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 2 * k_pool, temp
