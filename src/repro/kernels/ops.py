@@ -60,15 +60,17 @@ def flash_decode(q, k, v, pos, *, window: int = 0, interpret: bool = False,
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret",
                                              "backend"))
-def paged_decode(q, k_pages, v_pages, tables, pos, *, window: int = 0,
-                 interpret: bool = False, backend: str = "auto"):
-    """Block-table-indirected decode over the paged KV pool (serve plane);
-    ``window`` > 0 reads only the pages of each row's window."""
+def paged_decode(q, k_pages, v_pages, tables, pos, layer, *,
+                 window: int = 0, interpret: bool = False,
+                 backend: str = "auto"):
+    """Block-table-indirected decode over layer ``layer`` of the stacked
+    paged KV pools (serve plane); ``window`` > 0 reads only the pages of
+    each row's window."""
     if not _kernel(backend, interpret):
         return _ref.paged_decode_ref(q, k_pages, v_pages, tables, pos,
-                                     window=window)
-    return _pd.paged_decode(q, k_pages, v_pages, tables, pos, window=window,
-                            interpret=interpret)
+                                     layer, window=window)
+    return _pd.paged_decode(q, k_pages, v_pages, tables, pos, layer,
+                            window=window, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "backend"))
@@ -82,15 +84,16 @@ def grouped_ffn(x, wg, wi, wo, group_sizes, *, interpret: bool = False,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "backend"))
-def paged_decode_quant(q, k_pages, v_pages, k_scale, v_scale, tables, pos, *,
-                       interpret: bool = False, backend: str = "auto"):
-    """paged_decode over an int8 page pool with per-(row,head) scales —
+def paged_decode_quant(q, k_pages, v_pages, k_scale, v_scale, tables, pos,
+                       layer, *, interpret: bool = False,
+                       backend: str = "auto"):
+    """paged_decode over int8 page pools with per-(row,head) scales —
     half the HBM bytes per decoded token, dequantized in-tile."""
     if not _kernel(backend, interpret):
-        return _ref.paged_decode_quant_ref(q, k_pages, v_pages,
-                                           k_scale, v_scale, tables, pos)
+        return _ref.paged_decode_quant_ref(q, k_pages, v_pages, k_scale,
+                                           v_scale, tables, pos, layer)
     return _pd.paged_decode_quant(q, k_pages, v_pages, k_scale, v_scale,
-                                  tables, pos, interpret=interpret)
+                                  tables, pos, layer, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("vocab_size", "interpret",

@@ -69,20 +69,31 @@ def flash_decode_ref(q, k, v, pos, window: int = 0):
 # ---------------------------------------------------------------------------
 # paged_decode (flash_decode over a block-table-indirected paged KV pool)
 # ---------------------------------------------------------------------------
-def paged_decode_ref(q, k_pages, v_pages, tables, pos, window: int = 0):
-    """q: (B,1,H,hd); k_pages/v_pages: (P,page,K,hd) — the shared page pool;
-    tables: (B,NP) int32 page ids forming each sequence's logical
-    (NP*page)-token view; pos: (B,) int32 — last valid logical index per
-    sequence (attend to <= pos; pos < 0 means no valid tokens and the
-    output row is exactly zero, matching the Pallas kernel's zero-init
-    accumulator when every tile is skipped); window > 0: and to
-    > pos - window."""
+def _layer_rows(pages, layer, tables):
+    """Each sequence's logical (NP*page)-row view of one layer's pool:
+    pages (L,P,page,...) gathered at [layer, tables] -> (B,NP*page,...)."""
+    rows = pages[layer, tables]
+    B, NP, page = rows.shape[:3]
+    return rows.reshape((B, NP * page) + rows.shape[3:])
+
+
+def paged_decode_ref(q, k_pages, v_pages, tables, pos, layer,
+                     window: int = 0):
+    """q: (B,1,H,hd); k_pages/v_pages: (L,P,page,K,hd) — the shared page
+    pools of L layers, stacked; layer: the one read; tables: (B,NP) int32
+    page ids forming each sequence's logical (NP*page)-token view; pos:
+    (B,) int32 — last valid logical index per sequence (attend to <= pos;
+    pos < 0 means no valid tokens and the output row is exactly zero,
+    matching the Pallas kernel's zero-init accumulator when every tile is
+    skipped); window > 0: and to > pos - window."""
+    return _paged_attend(q, _layer_rows(k_pages, layer, tables),
+                         _layer_rows(v_pages, layer, tables), pos, window)
+
+
+def _paged_attend(q, k, v, pos, window: int = 0):
     B, _, H, hd = q.shape
-    page, K = k_pages.shape[1], k_pages.shape[2]
-    T = tables.shape[1] * page
+    T, K = k.shape[1], k.shape[2]
     G = H // K
-    k = k_pages[tables].reshape(B, T, K, hd)
-    v = v_pages[tables].reshape(B, T, K, hd)
     qg = q.reshape(B, K, G, hd)
     logits = jnp.einsum("bkgh,btkh->bkgt", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) / math.sqrt(hd)
@@ -136,13 +147,15 @@ def kv_dequant_ref(q, scale, dtype=jnp.float32):
 
 
 def paged_decode_quant_ref(q, k_pages, v_pages, k_scale, v_scale,
-                           tables, pos):
-    """paged_decode_ref over an int8 page pool: k/v_pages (P,page,K,hd)
-    int8 with per-(row,head) scales (P,page,K) fp32, dequantized before
+                           tables, pos, layer):
+    """paged_decode_ref over int8 page pools: k/v_pages (L,P,page,K,hd)
+    int8 with per-(row,head) scales (L,P,page,K) fp32, dequantized before
     the fp32 attention math."""
-    k = kv_dequant_ref(k_pages, k_scale)
-    v = kv_dequant_ref(v_pages, v_scale)
-    return paged_decode_ref(q, k, v, tables, pos)
+    k = kv_dequant_ref(_layer_rows(k_pages, layer, tables),
+                       _layer_rows(k_scale, layer, tables))
+    v = kv_dequant_ref(_layer_rows(v_pages, layer, tables),
+                       _layer_rows(v_scale, layer, tables))
+    return _paged_attend(q, k, v, pos)
 
 
 # ---------------------------------------------------------------------------

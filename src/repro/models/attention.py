@@ -95,22 +95,24 @@ def decode_attention(q, k_cache, v_cache, pos, *, backend: str = "reference",
     return attention_ref(q, k_cache, v_cache, causal=False, kv_len=pos + 1)
 
 
-def paged_decode_attention(q, k_pages, v_pages, tables, pos, *,
+def paged_decode_attention(q, k_pages, v_pages, tables, pos, layer, *,
                            k_scale=None, v_scale=None, window: int = 0,
                            backend: str = "reference",
                            interpret: bool = False) -> jax.Array:
-    """Single-token decode over the paged KV pool. q: (B,1,H,hd);
-    k_pages/v_pages: (P,page,K,hd); tables: (B,NP) int32 page ids; pos:
-    (B,) int32 last valid logical index (attend <= pos; < 0 = inactive
-    slot, output row exactly zero). With ``k_scale``/``v_scale``
-    ((P,page,K) fp32) the pools are int8 and the quantized kernel
-    dequantizes in-tile. ``window`` > 0 reads only each row's window."""
+    """Single-token decode over the paged KV pools. q: (B,1,H,hd);
+    k_pages/v_pages: (L,P,page,K,hd), every layer's pool stacked; layer:
+    the one attended; tables: (B,NP) int32 page ids; pos: (B,) int32 last
+    valid logical index (attend <= pos; < 0 = inactive slot, output row
+    exactly zero). With ``k_scale``/``v_scale`` ((L,P,page,K) fp32) the
+    pools are int8 and the quantized kernel dequantizes in-tile.
+    ``window`` > 0 reads only each row's window."""
     from repro.kernels import ops as kops
     impl = "pallas" if backend == "pallas" else "ref"
     if k_scale is not None:
         assert not window, "the int8 pool has no windowed decode"
         return kops.paged_decode_quant(q, k_pages, v_pages, k_scale,
-                                       v_scale, tables, pos, backend=impl,
-                                       interpret=interpret)
-    return kops.paged_decode(q, k_pages, v_pages, tables, pos, window=window,
-                             backend=impl, interpret=interpret)
+                                       v_scale, tables, pos, layer,
+                                       backend=impl, interpret=interpret)
+    return kops.paged_decode(q, k_pages, v_pages, tables, pos, layer,
+                             window=window, backend=impl,
+                             interpret=interpret)

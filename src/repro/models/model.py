@@ -69,7 +69,7 @@ def _ring_chunk(cache, k, v, pos, window):
 
 def _attn_mixer(cfg: ModelConfig, p: dict, x, cdt, mode, cache, positions,
                 pos, backend, interpret, causal=True, tables=None,
-                active=None, akind=None):
+                active=None, akind=None, layer=None):
     B, S, D = x.shape
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     akind = akind or cfg.attn_kind(0)
@@ -92,21 +92,25 @@ def _attn_mixer(cfg: ModelConfig, p: dict, x, cdt, mode, cache, positions,
 
     new_cache = None
     if mode == "decode" and tables is not None:
-        # paged KV: the cache leaf is the shared page pool (P, page, K, hd);
-        # slot b's new token lands in page tables[b, pos//page] at offset
-        # pos%page. Inactive slots (active[b] False) are redirected to the
-        # reserved garbage page 0, so an idle slot's pages stay untouched
-        # and its attention (pos[b] = -1 -> zero valid tokens) reads none.
+        # paged KV: the cache leaves are the stacked page pools
+        # (nper, P, page, K, hd) that the layer scan carries, and ``layer``
+        # is this block's period: the new token's row is written where it
+        # lies, and the kernel reads the same stack at ``layer``. Slot b's
+        # new token lands in page tables[b, pos//page] at offset pos%page.
+        # Inactive slots (active[b] False) are redirected to the reserved
+        # garbage page 0, so an idle slot's pages stay untouched and its
+        # attention (pos[b] = -1 -> zero valid tokens) reads none.
         posa = jnp.asarray(pos)
-        page = cache["k"].shape[1]
+        page = cache["k"].shape[2]
         posw = jnp.maximum(posa, 0)
         rows = jnp.arange(B)
         if W:
-            tables = _ring_tables(tables, cache["k"].shape[0])
+            tables = _ring_tables(tables, cache["k"].shape[1])
         pids = tables[rows, posw // page]
         offs = posw % page
         if active is not None:
             pids = jnp.where(active, pids, 0)
+        at = (layer, pids, offs)
         if "k_scale" in cache:
             # int8 pool (kv_dtype='int8'): quantize the new token's row
             # on write — per-(slot,head) symmetric scale over hd — and
@@ -114,22 +118,21 @@ def _attn_mixer(cfg: ModelConfig, p: dict, x, cdt, mode, cache, positions,
             from repro.serve.paged import kv_quantize
             kq, ks = kv_quantize(k[:, 0])
             vq, vs = kv_quantize(v[:, 0])
-            kc = cache["k"].at[pids, offs].set(kq)
-            vc = cache["v"].at[pids, offs].set(vq)
-            ksc = cache["k_scale"].at[pids, offs].set(ks)
-            vsc = cache["v_scale"].at[pids, offs].set(vs)
-            o = paged_decode_attention(q, kc, vc, tables, posa,
-                                       k_scale=ksc, v_scale=vsc,
-                                       backend=backend, interpret=interpret)
-            new_cache = {"k": kc, "v": vc, "k_scale": ksc, "v_scale": vsc}
+            new_cache = {"k": cache["k"].at[at].set(kq),
+                         "v": cache["v"].at[at].set(vq),
+                         "k_scale": cache["k_scale"].at[at].set(ks),
+                         "v_scale": cache["v_scale"].at[at].set(vs)}
+            o = paged_decode_attention(
+                q, new_cache["k"], new_cache["v"], tables, posa, layer,
+                k_scale=new_cache["k_scale"], v_scale=new_cache["v_scale"],
+                backend=backend, interpret=interpret)
         else:
-            kc = cache["k"].at[pids, offs].set(
-                k[:, 0].astype(cache["k"].dtype))
-            vc = cache["v"].at[pids, offs].set(
-                v[:, 0].astype(cache["v"].dtype))
-            o = paged_decode_attention(q, kc, vc, tables, posa, window=W,
+            new_cache = {
+                "k": cache["k"].at[at].set(k[:, 0].astype(cache["k"].dtype)),
+                "v": cache["v"].at[at].set(v[:, 0].astype(cache["v"].dtype))}
+            o = paged_decode_attention(q, new_cache["k"], new_cache["v"],
+                                       tables, posa, layer, window=W,
                                        backend=backend, interpret=interpret)
-            new_cache = {"k": kc, "v": vc}
     elif mode == "decode":
         posa = jnp.asarray(pos)
         if posa.ndim == 0:       # uniform position: dynamic_update_slice
@@ -237,7 +240,7 @@ def _apply_ffn(cfg: ModelConfig, p: dict, x, cdt, backend="reference",
 
 def _apply_block(cfg, run: RunConfig, kind: str, p, x, mode, cache_j,
                  positions, pos, memory, causal=True, cross=False,
-                 tables=None, active=None, akind=None):
+                 tables=None, active=None, akind=None, layer=None):
     cdt = _dt(run.precision.compute)
     # the kernels are forward-only (no VJP): training differentiates the
     # reference, serving (prefill / chunk / decode) runs the kernels
@@ -247,7 +250,8 @@ def _apply_block(cfg, run: RunConfig, kind: str, p, x, mode, cache_j,
     if kind == ATTN:
         out, nc = _attn_mixer(cfg, p, x, cdt, mode, cache_j, positions, pos,
                               backend, interpret, causal=causal,
-                              tables=tables, active=active, akind=akind)
+                              tables=tables, active=active, akind=akind,
+                              layer=layer)
         x = x + out
         if nc:
             new_cache.update(nc)
@@ -300,6 +304,13 @@ def run_stack(cfg: ModelConfig, run: RunConfig, layers: dict, x, mode,
     cache: same structure (or None); returned updated for prefill/decode.
     tables/active: paged-KV block tables + active-slot mask (decode only;
     see ``Model.decode_step``) — layer-invariant, so threaded by closure.
+
+    A paged decode (``tables`` given) carries the whole stacked cache
+    through the stack with the period index: each block writes its new
+    rows into the stacked pools where they lie and the kernel reads the
+    stack at that index. Every other mode scans the cache as ``xs`` and
+    restacks it as ``ys``, which slices each period's cache out of the
+    stack and writes it back: a copy of every pool, each step.
     """
     pattern = (ATTN,) if is_encoder else cfg.block_pattern
     plen = len(pattern)
@@ -308,19 +319,31 @@ def run_stack(cfg: ModelConfig, run: RunConfig, layers: dict, x, mode,
     cross = cfg.is_encoder_decoder and not is_encoder
     with_cache = (mode in ("prefill", "prefill_chunk", "decode")
                   and not is_encoder)
+    carried = with_cache and mode == "decode" and tables is not None
 
-    def period_fn(x, aux_in, period_params, period_cache):
+    def period_fn(x, aux_in, period_params, period_cache, layer=None):
+        """``layer`` (carried caches only): ``period_cache`` is the whole
+        stack and ``layer`` this period's index in it."""
         aux_acc = dict(aux_in)
-        new_caches = {}
+        new_caches = dict(period_cache) if carried else {}
         for j in range(plen):
-            cj = period_cache.get(f"block{j}") if period_cache else None
+            name = f"block{j}"
+            cj = period_cache.get(name) if period_cache else None
+            # a recurrent block's per-slot state is small: sliced out of
+            # the stack and written back
+            sliced = carried and pattern[j] != ATTN
+            if sliced:
+                cj = jax.tree.map(lambda l: l[layer], cj)
             x, nc, aux = _apply_block(
-                cfg, run, pattern[j], period_params[f"block{j}"], x, mode,
+                cfg, run, pattern[j], period_params[name], x, mode,
                 cj, positions, pos, memory, causal=causal, cross=cross,
                 tables=tables, active=active,
-                akind=None if is_encoder else cfg.attn_kind(j))
+                akind=None if is_encoder else cfg.attn_kind(j), layer=layer)
             if nc is not None:
-                new_caches[f"block{j}"] = nc
+                if sliced:
+                    nc = jax.tree.map(lambda l, n: l.at[layer].set(n),
+                                      period_cache[name], nc)
+                new_caches[name] = nc
             for k_, v_ in aux.items():
                 aux_acc[k_] = aux_acc[k_] + v_
         return x, aux_acc, (new_caches if with_cache else None)
@@ -333,7 +356,21 @@ def run_stack(cfg: ModelConfig, run: RunConfig, layers: dict, x, mode,
         period_fn = jax.checkpoint(period_fn, policy=policy,
                                    static_argnums=())
 
-    if run.sharding.scan_layers and nper > 1:
+    scan = run.sharding.scan_layers and nper > 1
+    if carried and scan:
+        def body(carry, xs):
+            x, aux, c = carry
+            pp, i = xs
+            return period_fn(x, aux, pp, c, i), None
+        (x, aux, ncache), _ = jax.lax.scan(
+            body, (x, zero_aux(cfg), cache),
+            (layers, jnp.arange(nper, dtype=jnp.int32)))
+    elif carried:
+        aux, ncache = zero_aux(cfg), cache
+        for i in range(nper):
+            pp = jax.tree.map(lambda l: l[i], layers)
+            x, aux, ncache = period_fn(x, aux, pp, ncache, i)
+    elif scan:
         def body(carry, xs):
             x, aux = carry
             pp, pc = xs
@@ -462,8 +499,9 @@ class Model:
 
         tables: optional (B,NP) int32 paged-KV block tables. When given,
         attention-cache leaves are page pools (nper, P, page, K, hd) —
-        see ``repro.serve.paged`` — and ``pos`` is per-slot logical
-        position; page 0 is reserved as the garbage page.
+        see ``repro.serve.paged`` — updated where they lie (the layer
+        scan carries them), and ``pos`` is per-slot logical position;
+        page 0 is reserved as the garbage page.
 
         counters: also return the stack's counters (``EXPERT_COUNTERS``
         summed over layers), as a third value."""

@@ -102,6 +102,19 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
 
 
+def decode_program(run: RunConfig, rules=None, *, paged: bool, fused: bool,
+                   counters: bool = False):
+    """The engine's jitted decode step. A paged step writes its pools where
+    they lie (the layer scan carries them), so it takes the cache by
+    donation and returns it in the same buffers: no step holds a second
+    pool. The dense cache still goes through the scan's slices, where
+    donation would only add copies."""
+    from repro.train.step import make_decode_step
+    return jax.jit(make_decode_step(run, rules, paged=paged, fused=fused,
+                                    counters=counters),
+                   donate_argnums=(1,) if paged else ())
+
+
 class ServeEngine:
     def __init__(self, run: RunConfig, params, *, slots: int = 4,
                  max_len: int = 256, rules=None, paged: bool = False,
@@ -132,7 +145,9 @@ class ServeEngine:
         #: step (``step_ns``, ``admit_ns``, ``prefill_ns``, ``place_ns``,
         #: ``prepare_ns``, ``decode_ns``, ``readback_ns``, ``bookkeep_ns``:
         #: the ``engine.*`` spans, nested ones counted in their parents
-        #: too). Cumulative over the engine's lifetime.
+        #: too); ``kv_in_place``: decode and admission calls that
+        #: consumed their input pool by donation (``_count_in_place``).
+        #: Cumulative over the engine's lifetime.
         self.stats = collections.Counter()
         # per-step dirty set: which export_state keys changed since the
         # last export. Informational for drivers (and asserted in tests);
@@ -198,13 +213,12 @@ class ServeEngine:
         self._counters = bool(cfg.moe is not None and cfg.moe.dropless
                               and self.fused_sampling)
 
-        from repro.train.step import (make_decode_step, make_prefill_chunk,
-                                      make_serve_steps)
+        from repro.train.step import make_prefill_chunk, make_serve_steps
         prefill, _ = make_serve_steps(run, rules)
         self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(make_decode_step(
-            run, rules, paged=paged, fused=self.fused_sampling,
-            counters=self._counters))
+        self._decode = decode_program(run, rules, paged=paged,
+                                      fused=self.fused_sampling,
+                                      counters=self._counters)
         self._chunk = jax.jit(make_prefill_chunk(run, rules))
         self._cache = None                              # lazy batched cache
         #: the chip this engine's params and KV cache live on (its VF's
@@ -439,10 +453,12 @@ class ServeEngine:
             if self.paged:
                 shared = self.alloc.shared_count(req.rid)
                 self.stats["shared_page_hits"] += shared
-                self._cache = admit_kv(self._cache, req_cache, pages,
+                pool = self._cache
+                self._cache = admit_kv(pool, req_cache, pages,
                                        self.page_size, slot,
                                        skip_pages=shared,
                                        ring_blocks=tuple(self.windows))
+                self._count_in_place(pool)
                 row = self.tables[slot]
                 row[:] = 0
                 row[:len(pages)] = pages
@@ -571,6 +587,7 @@ class ServeEngine:
                          jnp.asarray(keys)]
         with span("engine.decode", self.stats, slots=len(act), width=W):
             out, self._cache = self._decode(*args)
+            self._count_in_place(args[1])
         with span("engine.readback", self.stats):
             out = np.asarray(out)     # token ids (fused) or logits
         with span("engine.bookkeep", self.stats):
@@ -599,6 +616,16 @@ class ServeEngine:
                     self.active[s] = None
                     self._reset_slot(s, rid=req.rid)
         return len(act)
+
+    def _count_in_place(self, pool) -> None:
+        """Count a call that consumed its input ``pool`` by donation (its
+        output lives in the same buffers), a host-side check with no
+        readback. A paged engine's calls should all count: a shortfall
+        means the pool was copied instead, e.g. on the CPU while a host
+        snapshot still views it."""
+        if self.paged and all(isinstance(x, jax.Array) and x.is_deleted()
+                              for x in jax.tree.leaves(pool)):
+            self.stats["kv_in_place"] += 1
 
     def _ensure_writable(self, slot: int) -> bool:
         """Make this step's KV write target (position ``pos+1``) safe for
@@ -788,9 +815,10 @@ class ServeEngine:
         shared = self.alloc.shared_count(rid)
         self.stats["shared_page_hits"] += shared
         self._ensure_cache()
-        self._cache = admit_kv(self._cache,
-                               jax.tree.map(jnp.asarray, state), pages,
+        pool = self._cache
+        self._cache = admit_kv(pool, jax.tree.map(jnp.asarray, state), pages,
                                self.page_size, slot, skip_pages=shared)
+        self._count_in_place(pool)
         row = self.tables[slot]
         row[:] = 0
         row[:len(pages)] = pages
@@ -902,12 +930,13 @@ class ServeEngine:
     def import_state(self, st: dict):
         if "params" in st:
             self.params = st["params"]
-        # restored cache leaves may be host numpy (zero-copy staging
-        # transport); admit_kv/reset_slot_state index with .at[], so
-        # re-materialize as jax arrays here rather than crashing on the
-        # first admission after an unpause
+        # restored cache leaves may be host numpy, or device arrays that
+        # share a host snapshot's memory (zero-copy staging transport on
+        # the CPU). Decode and admission donate the pool and write it in
+        # place, so the engine takes a copy of its own, once
         self._cache = (None if st["cache"] is None else
-                       jax.tree.map(jnp.asarray, st["cache"]))
+                       jax.tree.map(lambda a: jnp.array(a, copy=True),
+                                    st["cache"]))
         # restored host arrays may be read-only views (zero-copy staging
         # transport); the engine mutates these in place, so copy
         self.pos = np.array(st["pos"], np.int64)

@@ -538,7 +538,9 @@ def admit_kv(cache: dict, req_cache: dict, page_ids, page_size: int,
     count, skip) — the same shape family the prefill executables already
     warm — so an int8 admit costs one fused kernel, not an eager
     quantize-dispatch per cache leaf. ``slot`` rides in as a traced
-    scalar: slot churn never retraces.
+    scalar: slot churn never retraces. ``cache`` is donated: the rows
+    land in its buffers, which the returned tree reuses, and the caller
+    drops its reference to the old one.
 
     The ``ring_blocks``' KV arrives in ring order (``ring_order``), one
     ring of the pool's ring length, and is copied whole into the slot's
@@ -551,7 +553,8 @@ def admit_kv(cache: dict, req_cache: dict, page_ids, page_size: int,
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "skip",
-                                             "ring_blocks"))
+                                             "ring_blocks"),
+                   donate_argnums=(0,))
 def _admit_kv_jit(cache: dict, req_cache: dict, ids, slot, *,
                   page_size: int, skip: int, ring_blocks=()) -> dict:
     n = int(ids.shape[0])

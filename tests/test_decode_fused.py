@@ -97,12 +97,14 @@ def test_request_rejects_subnormal_temperature():
 # ===========================================================================
 # kernels: int8 paged decode parity, fused sampling bit-identity
 # ===========================================================================
-def _paged_inputs(key, B=3, NP=3, page=8, H=4, K=2, hd=16):
+def _paged_inputs(key, B=3, NP=3, page=8, H=4, K=2, hd=16, L=2):
+    """Queries, L layers' pools stacked, tables and positions; the tests
+    read the last layer."""
     ks = jax.random.split(key, 4)
     P = 1 + B * NP
     q = jax.random.normal(ks[0], (B, 1, H, hd), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, page, K, hd), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, page, K, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (L, P, page, K, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (L, P, page, K, hd), jnp.float32)
     tables = (1 + jnp.arange(B * NP, dtype=jnp.int32)).reshape(B, NP)
     pos = jnp.asarray([NP * page - 1, page + 3, -1], jnp.int32)[:B]
     return q, kp, vp, tables, pos
@@ -112,10 +114,10 @@ def test_paged_decode_int8_parity_with_fp():
     from repro.kernels import ops
     from repro.kernels.ref import kv_quant_ref
     q, kp, vp, tables, pos = _paged_inputs(jax.random.key(1))
-    want = ops.paged_decode(q, kp, vp, tables, pos, backend="ref")
+    want = ops.paged_decode(q, kp, vp, tables, pos, 1, backend="ref")
     kq, ksc = kv_quant_ref(kp)
     vq, vsc = kv_quant_ref(vp)
-    got = ops.paged_decode_quant(q, kq, vq, ksc, vsc, tables, pos,
+    got = ops.paged_decode_quant(q, kq, vq, ksc, vsc, tables, pos, 1,
                                  backend="ref")
     # int8 is lossy: bounded by the quantization step, not exact
     assert jnp.max(jnp.abs(got - want)) < 0.05
@@ -130,8 +132,8 @@ def test_paged_decode_quant_kernel_matches_ref():
     q, kp, vp, tables, pos = _paged_inputs(jax.random.key(2))
     kq, ksc = kv_quant_ref(kp)
     vq, vsc = kv_quant_ref(vp)
-    want = paged_decode_quant_ref(q, kq, vq, ksc, vsc, tables, pos)
-    got = paged_decode_quant(q, kq, vq, ksc, vsc, tables, pos,
+    want = paged_decode_quant_ref(q, kq, vq, ksc, vsc, tables, pos, 1)
+    got = paged_decode_quant(q, kq, vq, ksc, vsc, tables, pos, 1,
                              interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)

@@ -91,38 +91,48 @@ def test_flash_decode_matches_flash_attention_last_row():
 
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
-@pytest.mark.parametrize("B,H,K,hd,page,NP,P,positions", [
-    (1, 4, 4, 64, 16, 4, 8, (63,)),          # MHA, full view valid
-    (3, 4, 2, 64, 8, 4, 16, (5, -1, 31)),    # GQA, one inactive slot
-    (2, 8, 2, 128, 32, 2, 5, (0, 40)),       # MQA-ish wide head
-    (2, 2, 1, 64, 8, 8, 17, (-1, -1)),       # all slots inactive
-    (3, 16, 8, 128, 16, 4, 12, (15, -1, 48)),  # qwen3 heads; page ends
-    (2, 32, 4, 128, 16, 3, 7, (16, 47)),     # Mellum2 heads; page bounds
+@pytest.mark.parametrize("B,H,K,hd,page,NP,P,positions,nper,layer", [
+    (1, 4, 4, 64, 16, 4, 8, (63,), 1, 0),          # MHA, full view valid
+    (3, 4, 2, 64, 8, 4, 16, (5, -1, 31), 1, 0),    # GQA, one inactive slot
+    (2, 8, 2, 128, 32, 2, 5, (0, 40), 1, 0),       # MQA-ish wide head
+    (2, 2, 1, 64, 8, 8, 17, (-1, -1), 1, 0),       # all slots inactive
+    (3, 16, 8, 128, 16, 4, 12, (15, -1, 48), 1, 0),  # qwen3 heads; page ends
+    (2, 32, 4, 128, 16, 3, 7, (16, 47), 1, 0),     # Mellum2 heads; page bounds
+    (3, 16, 8, 128, 16, 4, 12, (15, -1, 48), 3, 1),  # stack: a middle layer
+    (2, 32, 4, 128, 16, 3, 7, (16, 47), 3, 2),     # stack: the last layer
 ])
-def test_paged_decode_sweep(B, H, K, hd, page, NP, P, positions, dtype):
-    """The kernel reads each page as the pool stores it, (page, K, hd),
-    and equals the oracle; an int8 pool (``paged_decode_quant``, float32
-    queries) equals the oracle over the same quantized pool."""
+def test_paged_decode_sweep(B, H, K, hd, page, NP, P, positions, nper, layer,
+                            dtype):
+    """The kernel reads each page of layer ``layer`` of the stacked pools
+    as the pool stores it, (page, K, hd), and equals the oracle on that
+    layer; an int8 pool (``paged_decode_quant``, float32 queries) equals
+    the oracle over the same quantized pool."""
     ks = jax.random.split(jax.random.key(4), 3)
     quant = dtype == jnp.int8
     fdt = jnp.float32 if quant else dtype
     q = rand(ks[0], (B, 1, H, hd), fdt)
-    kp = rand(ks[1], (P, page, K, hd), fdt)
-    vp = rand(ks[2], (P, page, K, hd), fdt)
+    kp = rand(ks[1], (nper, P, page, K, hd), fdt)
+    vp = rand(ks[2], (nper, P, page, K, hd), fdt)
     rng = np.random.default_rng(0)
     # arbitrary page ids (reads may alias; page 0 stays reserved for writes)
     tables = jnp.asarray(rng.integers(1, P, (B, NP)), jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
+    one = slice(layer, layer + 1)            # the layer read, alone
     if quant:
         kq, k_scale = ref.kv_quant_ref(kp)
         vq, v_scale = ref.kv_quant_ref(vp)
         out = paged_decode_quant(q, kq, vq, k_scale, v_scale, tables, pos,
-                                 interpret=True)
+                                 layer, interpret=True)
         want = ref.paged_decode_quant_ref(q, kq, vq, k_scale, v_scale,
-                                          tables, pos)
+                                          tables, pos, layer)
+        alone = ref.paged_decode_quant_ref(q, kq[one], vq[one],
+                                           k_scale[one], v_scale[one],
+                                           tables, pos, 0)
     else:
-        out = paged_decode(q, kp, vp, tables, pos, interpret=True)
-        want = ref.paged_decode_ref(q, kp, vp, tables, pos)
+        out = paged_decode(q, kp, vp, tables, pos, layer, interpret=True)
+        want = ref.paged_decode_ref(q, kp, vp, tables, pos, layer)
+        alone = ref.paged_decode_ref(q, kp[one], vp[one], tables, pos, 0)
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(alone))
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32),
         atol=TOL[fdt], rtol=TOL[fdt])
@@ -139,14 +149,14 @@ def test_paged_decode_matches_flash_decode_contiguous_view():
     ks = jax.random.split(jax.random.key(5), 3)
     page, NP, P, K, hd = 32, 4, 9, 2, 64
     q = rand(ks[0], (1, 1, 4, hd), jnp.float32)
-    kp = rand(ks[1], (P, page, K, hd), jnp.float32)
-    vp = rand(ks[2], (P, page, K, hd), jnp.float32)
+    kp = rand(ks[1], (1, P, page, K, hd), jnp.float32)
+    vp = rand(ks[2], (1, P, page, K, hd), jnp.float32)
     tables = jnp.asarray([[3, 1, 8, 5]], jnp.int32)
     pos = 77
-    out = paged_decode(q, kp, vp, tables, jnp.asarray([pos], jnp.int32),
+    out = paged_decode(q, kp, vp, tables, jnp.asarray([pos], jnp.int32), 0,
                        interpret=True)
-    k = kp[tables[0]].reshape(1, NP * page, K, hd)
-    v = vp[tables[0]].reshape(1, NP * page, K, hd)
+    k = kp[0, tables[0]].reshape(1, NP * page, K, hd)
+    v = vp[0, tables[0]].reshape(1, NP * page, K, hd)
     want = flash_decode(q, k, v, pos, block_k=page, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -245,23 +255,24 @@ def test_flash_attention_window(S, window, q_offset, k_offset, blocks):
                                rtol=2e-5)
 
 
-@pytest.mark.parametrize("K,ring,positions", [
-    (2, 0, (5, 70, 127)),
-    (2, 0, (-1, 31, 64)),
-    (4, 4, (36, -1, 100)),       # windows that wrap round the ring
-    (8, 4, (7, 39, 56)),         # a clipped window; page bounds
+@pytest.mark.parametrize("K,ring,positions,nper,layer", [
+    (2, 0, (5, 70, 127), 1, 0),
+    (2, 0, (-1, 31, 64), 1, 0),
+    (4, 4, (36, -1, 100), 1, 0),     # windows that wrap round the ring
+    (8, 4, (7, 39, 56), 1, 0),       # a clipped window; page bounds
+    (4, 4, (36, -1, 100), 3, 1),     # the ring of a middle layer
 ])
-def test_paged_decode_window(K, ring, positions):
-    """A windowed paged decode reads only its window's pages and equals
-    the oracle, over random tables or per-slot rings of ``ring`` pages
-    (logical page i of slot b at 1 + b * ring + i % ring, as
-    ``serve.paged`` lays them out); with the window wider than the
-    context it is the plain decode."""
+def test_paged_decode_window(K, ring, positions, nper, layer):
+    """A windowed paged decode reads only its window's pages of layer
+    ``layer`` and equals the oracle, over random tables or per-slot rings
+    of ``ring`` pages (logical page i of slot b at 1 + b * ring + i %
+    ring, as ``serve.paged`` lays them out); with the window wider than
+    the context it is the plain decode."""
     ks = jax.random.split(jax.random.key(12), 3)
     page, NP, P, hd, W = 8, 16, 40, 64, 20
     q = rand(ks[0], (3, 1, 2 * K, hd), jnp.float32)
-    kp = rand(ks[1], (P, page, K, hd), jnp.float32)
-    vp = rand(ks[2], (P, page, K, hd), jnp.float32)
+    kp = rand(ks[1], (nper, P, page, K, hd), jnp.float32)
+    vp = rand(ks[2], (nper, P, page, K, hd), jnp.float32)
     if ring:
         tables = (1 + jnp.arange(3)[:, None] * ring
                   + jnp.arange(NP)[None, :] % ring).astype(jnp.int32)
@@ -269,13 +280,14 @@ def test_paged_decode_window(K, ring, positions):
         tables = jnp.asarray(
             np.random.default_rng(1).integers(1, P, (3, NP)), jnp.int32)
     pos = jnp.asarray(positions, jnp.int32)
-    out = paged_decode(q, kp, vp, tables, pos, window=W, interpret=True)
-    want = ref.paged_decode_ref(q, kp, vp, tables, pos, window=W)
+    out = paged_decode(q, kp, vp, tables, pos, layer, window=W,
+                       interpret=True)
+    want = ref.paged_decode_ref(q, kp, vp, tables, pos, layer, window=W)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5,
                                rtol=2e-5)
-    wide = paged_decode(q, kp, vp, tables, pos, window=NP * page,
+    wide = paged_decode(q, kp, vp, tables, pos, layer, window=NP * page,
                         interpret=True)
-    plain = paged_decode(q, kp, vp, tables, pos, interpret=True)
+    plain = paged_decode(q, kp, vp, tables, pos, layer, interpret=True)
     np.testing.assert_allclose(np.asarray(wide), np.asarray(plain),
                                atol=2e-5, rtol=2e-5)
 

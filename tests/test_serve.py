@@ -338,6 +338,69 @@ def test_mid_run_pause_roundtrip_token_identical(setup):
     assert serve() == serve(pause_at=2)
 
 
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "jamba-1.5-large-398b"])
+def test_paged_pool_updated_in_place(arch, monkeypatch):
+    """A paged engine writes its pool where it lies: the layer scan
+    carries the stacked pool (a hybrid stack's recurrent state is sliced
+    out of it and written back), and decode and admission donate it.
+    Every decode and admit call consumes its input pool (``kv_in_place``),
+    the scan emits the tokens of the unrolled stack, and a pause ->
+    unpause -> step cycle leaves the snapshot's host payload as it was
+    staged (the restored pool shares no memory with it)."""
+    import repro.serve.engine as engine_mod
+    run = make_run_config(arch, "decode_32k", smoke=True)
+    params = build_model(run).init(jax.random.key(0))
+    prompts = [np.arange(4) % 100, (np.arange(11) * 3) % 100,
+               (np.arange(5) * 5 + 2) % 100, (np.arange(9) * 7 + 1) % 100]
+    admits = []
+    admit = engine_mod.admit_kv
+    monkeypatch.setattr(engine_mod, "admit_kv",
+                        lambda *a, **kw: admits.append(1) or admit(*a, **kw))
+
+    def serve(run):
+        eng = ServeEngine(run, params, slots=2, max_len=48, paged=True,
+                          page_size=8, prefill_chunk=4, fused_sampling=True)
+        decodes = []
+        step = eng._decode
+        eng._decode = lambda *a: decodes.append(1) or step(*a)
+        admits.clear()
+        reqs = [Request(rid=i, prompt=p, max_new_tokens=6,
+                        temperature=0.8 * (i % 2), top_k=16)
+                for i, p in enumerate(prompts)]
+        for r in reqs:
+            eng.submit(r)
+        assert eng.run_until_idle().drained
+        assert all(r.done and not r.error for r in reqs)
+        assert decodes and len(admits) == len(prompts)
+        assert eng.stats["kv_in_place"] == len(decodes) + len(admits)
+        return [r.out for r in reqs]
+
+    unrolled = dataclasses.replace(run, sharding=dataclasses.replace(
+        run.sharding, scan_layers=False))
+    assert serve(run) == serve(unrolled)
+
+    fleet = ServeFleet(run, params, num_engines=1, devices=token_devices(1),
+                       slots=2, max_len=48, paged=True, page_size=8,
+                       workdir=tempfile.mkdtemp())
+    for i, p in enumerate(prompts):
+        fleet.submit(Request(rid=i, prompt=p, max_new_tokens=12))
+    for _ in range(4):
+        fleet.step()
+    fleet.pause_live("serve0")
+    payload = fleet.mgr.snapshots["serve0"].payload["cache"]
+    staged = [np.array(x, copy=True) for x in jax.tree.leaves(payload)]
+    fleet.unpause("serve0")
+    eng = fleet.tenants["serve0"].engine
+    for a, b in zip(jax.tree.leaves(eng._cache), jax.tree.leaves(payload)):
+        assert a.unsafe_buffer_pointer() != np.asarray(b).ctypes.data
+    before = eng.stats["kv_in_place"]
+    for _ in range(3):
+        fleet.step()
+    assert eng.stats["kv_in_place"] > before
+    for a, b in zip(staged, jax.tree.leaves(payload)):
+        assert np.array_equal(a, np.asarray(b)), "snapshot bytes changed"
+
+
 # ===========================================================================
 # fleet: engines as tenants under the SVFF manager
 # ===========================================================================

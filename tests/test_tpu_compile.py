@@ -2,7 +2,7 @@
 widths (H=16, K=8, hd=128, page 16, V=151936), and the sliding-window and
 grouped expert kernels at Mellum2's (H=32, K=4, window 1024, D=2304,
 expert width 896, 8 experts held). The whole paged decode step compiles
-at both, with no copy of a KV pool in another layout.
+at both, and it and an admission update the KV pools in place.
 
 Nothing runs: each test compiles for a described, not attached, chip —
 what the TPU compiler refuses (block shapes off the (8, 128) tiling, too
@@ -69,16 +69,19 @@ def _assert_kernel(lowered):
 
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
 def test_paged_decode_compiles_for_v5e(spec, kv_dtype):
+    """Over the pools of 28 layers stacked, at a traced layer index."""
     q = spec((B, 1, H, HD), jnp.bfloat16)
-    pages = spec((P, PAGE, K, HD), jnp.dtype(kv_dtype))
+    pages = spec((28, P, PAGE, K, HD), jnp.dtype(kv_dtype))
     tables, pos = spec((B, NP), jnp.int32), spec((B,), jnp.int32)
+    layer = spec((), jnp.int32)
     if kv_dtype == "int8":
-        scale = spec((P, PAGE, K), jnp.float32)
+        scale = spec((28, P, PAGE, K), jnp.float32)
         lowered = kops.paged_decode_quant.lower(
-            q, pages, pages, scale, scale, tables, pos, backend="pallas")
+            q, pages, pages, scale, scale, tables, pos, layer,
+            backend="pallas")
     else:
         lowered = kops.paged_decode.lower(q, pages, pages, tables, pos,
-                                          backend="pallas")
+                                          layer, backend="pallas")
     _assert_kernel(lowered)
 
 
@@ -115,10 +118,10 @@ def test_windowed_flash_attention_compiles_for_v5e(spec, q_len):
 
 def test_windowed_paged_decode_compiles_for_v5e(spec):
     q = spec((16, 1, 32, HD), jnp.bfloat16)
-    pages = spec((1 + 16 * 80, PAGE, 4, HD), jnp.bfloat16)
+    pages = spec((7, 1 + 16 * 80, PAGE, 4, HD), jnp.bfloat16)
     _assert_kernel(kops.paged_decode.lower(
         q, pages, pages, spec((16, 1024), jnp.int32), spec((16,), jnp.int32),
-        window=1024, backend="pallas"))
+        spec((), jnp.int32), window=1024, backend="pallas"))
 
 
 @pytest.mark.parametrize("rows", [16 * 8, 256 * 8])
@@ -132,10 +135,12 @@ def test_grouped_ffn_compiles_for_v5e(spec, rows):
 
 
 # ---------------------------------------------------------------------------
-# the whole paged decode step: the KV pools are read where they lie
+# the whole paged decode step and admission: the KV pools stay in place
 # ---------------------------------------------------------------------------
-#: ops that write a pool-sized result in another layout
-RELAYOUT_OPS = ("reshape", "copy", "transpose")
+#: ops that may not have a pool-sized result: a copy of a pool in another
+#: layout, or a layer's pool sliced out of the stack and written back
+COPY_OPS = ("reshape", "copy", "transpose", "dynamic-slice",
+            "dynamic-update-slice")
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([a-z][\w\-]*)\(")
 
 
@@ -152,32 +157,66 @@ def pool_sized_ops(hlo: str, pages) -> list:
     return found
 
 
-def _compile_decode_step(spec, cfg, max_len, pages, width, ring_pages=0):
-    """The serve engine's paged, fused decode step, for 16 slots of
-    ``max_len``, compiled for one described v5e chip."""
+def pool_copies(hlo: str, pages) -> list:
+    """The pool-sized ops of ``COPY_OPS``, and the fusions named after
+    them (``dynamic-slice_bitcast_fusion``, ...)."""
+    return [(op, name) for op, name in pool_sized_ops(hlo, pages)
+            if op in COPY_OPS or (op == "fusion" and any(
+                c in name for c in COPY_OPS))]
+
+
+def assert_in_place(compiled, pages, layer_pool: int, pools: int):
+    """No pool-sized copy, temporaries under one layer's pool, and the
+    output aliasing at least the pools' bytes of the donated input."""
+    hlo = compiled.as_text()
+    copies = pool_copies(hlo, pages)
+    assert not copies, copies
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_pool, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= pools, (mem.alias_size_in_bytes,
+                                              pools)
+
+
+def _serve_run(cfg, max_len):
     from repro.configs import PrecisionConfig, RunConfig
     from repro.configs.base import ShapeConfig
+    return RunConfig(model=cfg, shape=ShapeConfig("serve", "decode", max_len,
+                                                  16),
+                     precision=PrecisionConfig("bfloat16", "bfloat16",
+                                               "float32"),
+                     kernel_backend="pallas")
+
+
+def _pool_specs(spec, run, pages, ring_pages=0):
     from repro.models.model import build_model
     from repro.serve.paged import init_paged_cache
-    from repro.train.step import make_decode_step
-    slots = 16
-    run = RunConfig(model=cfg, shape=ShapeConfig("serve", "decode", max_len,
-                                                 slots),
-                    precision=PrecisionConfig("bfloat16", "bfloat16",
-                                              "float32"),
-                    kernel_backend="pallas")
     model = build_model(run)
     cache = jax.eval_shape(lambda: init_paged_cache(
         model, run.shape, pages, PAGE, ring_pages=ring_pages))
-    params, cache = (jax.tree.map(lambda s: spec(s.shape, s.dtype), t)
-                     for t in (model.param_shapes(), cache))
-    step = make_decode_step(run, paged=True, fused=True,
-                            counters=cfg.moe is not None)
-    return jax.jit(step).lower(
+    return model, jax.tree.map(lambda s: spec(s.shape, s.dtype), cache)
+
+
+def _compile_decode_step(spec, cfg, max_len, pages, width, ring_pages=0):
+    """The serve engine's paged, fused decode program, built by the
+    engine's own factory (with its donation), for 16 slots of
+    ``max_len``, compiled for one described v5e chip."""
+    from repro.serve.engine import decode_program
+    slots = 16
+    run = _serve_run(cfg, max_len)
+    model, cache = _pool_specs(spec, run, pages, ring_pages)
+    params = jax.tree.map(lambda s: spec(s.shape, s.dtype),
+                          model.param_shapes())
+    step = decode_program(run, paged=True, fused=True,
+                          counters=cfg.moe is not None)
+    return step.lower(
         params, cache, spec((slots, 1), jnp.int32), spec((slots,), jnp.int32),
         spec((slots, width), jnp.int32), spec((slots,), jnp.bool_),
         spec((slots,), jnp.float32), spec((slots,), jnp.int32),
-        spec((slots, 3), jnp.int32)).compile()
+        spec((slots, 3), jnp.int32)).compile(), cache
+
+
+def _pools_bytes(cache) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
 
 
 @pytest.mark.parametrize("model", ["qwen3-0.6b", "mellum2-ep8"])
@@ -185,33 +224,46 @@ def test_paged_decode_step_reads_pools_in_place_for_v5e(spec, model):
     """qwen3-0.6b at 2 layers (16 slots of 2,048, 2,049 pages, table width
     64) and Mellum2's share at one sliding and one full block twice over
     (6,657 full pages, rings of 80 pages a slot, width 1,024): the layer
-    scan stays, and ``paged_decode`` reads each layer's pools as stored.
-    Viewing them as (P, page, K*hd) copied both pools of every layer, each
-    step. The scan's slice of the layer's pools out of the stack, and its
-    write back, are still there."""
+    scan carries the stacked pools, writes each layer's new rows where
+    they lie, and ``paged_decode`` reads the stack at the layer's index.
+    So no pool is sliced out of the stack and written back, none is
+    viewed in another layout, the temporaries stay under one layer's K
+    pool, and the donated pools come back in the same buffers."""
     from repro.configs import ATTN
     from repro.configs import mellum2_12b_a2_5b as mellum2
     from repro.configs import qwen3_0_6b
+    # the page counts of a pool, and of the stack of 2 that the kernel
+    # reads as one pool
     if model == "qwen3-0.6b":
         cfg = dataclasses.replace(qwen3_0_6b.full(), num_layers=2)
-        pages = {2049}
-        compiled = _compile_decode_step(spec, cfg, 2048, 2049, 64)
+        pages = {2049, 2 * 2049}
+        k_pool = 2049 * PAGE * K * HD * 2
+        compiled, cache = _compile_decode_step(spec, cfg, 2048, 2049, 64)
     else:
         cfg = dataclasses.replace(
             mellum2.ep_share(8, 0), num_layers=4, block_pattern=(ATTN,) * 2,
             attn_kinds=(mellum2.SLIDING, mellum2.FULL))
-        pages = {6657, 1 + 16 * 80}
-        compiled = _compile_decode_step(spec, cfg, 16384, 6657, 1024,
-                                        ring_pages=80)
-    hlo = compiled.as_text()
-    assert "paged_decode" in hlo
-    relaid = [op for op in pool_sized_ops(hlo, pages)
-              if op[0] in RELAYOUT_OPS]
-    assert not relaid, relaid
-    if model == "qwen3-0.6b":
-        # one pool-sized temporary is left, the scan's slice of a layer's
-        # pool; the relayout held two more. (Mellum2's temporaries also
-        # hold the held experts' weights, sliced out of the stack.)
-        k_pool = 2049 * PAGE * K * HD * 2
-        temp = compiled.memory_analysis().temp_size_in_bytes
-        assert temp < 2 * k_pool, temp
+        pages = {6657, 2 * 6657, 1 + 16 * 80, 2 * (1 + 16 * 80)}
+        k_pool = 6657 * PAGE * 4 * HD * 2
+        compiled, cache = _compile_decode_step(spec, cfg, 16384, 6657, 1024,
+                                               ring_pages=80)
+    assert "paged_decode" in compiled.as_text()
+    assert_in_place(compiled, pages, k_pool, _pools_bytes(cache))
+
+
+def test_admit_kv_writes_pools_in_place_for_v5e(spec):
+    """One admission of 64 pages (a 1,024-row prompt) into qwen3-0.6b's
+    pools at 2 layers (2,049 pages): the rows are scattered into the
+    donated pools, which come back in the same buffers."""
+    from repro.configs import qwen3_0_6b
+    from repro.serve.paged import _admit_kv_jit
+    cfg = dataclasses.replace(qwen3_0_6b.full(), num_layers=2)
+    run = _serve_run(cfg, 2048)
+    _, cache = _pool_specs(spec, run, 2049)
+    req = {"block0": {n: spec((2, 1, 64 * PAGE, K, HD), jnp.bfloat16)
+                      for n in ("k", "v")}}
+    compiled = _admit_kv_jit.lower(
+        cache, req, spec((64,), jnp.int32), spec((), jnp.int32),
+        page_size=PAGE, skip=0).compile()
+    assert_in_place(compiled, {2049, 2 * 2049}, 2049 * PAGE * K * HD * 2,
+                    _pools_bytes(cache))
